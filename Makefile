@@ -21,6 +21,12 @@
 #   make test        ASAN native tests + the python suite.
 #   make check       the PR gate, reproduced locally: make lint + the
 #                    tier-1 pytest command (ROADMAP.md "Tier-1 verify").
+#   make smoke       chip_smoke.py: the served path, once, on the TPU
+#                    (Server <- gRPC <- TPU-shm -> fused batcher at
+#                    resnet50 @ 224, LmEngine streams, the Pallas
+#                    kernels compiled).  Needs the chip and refuses
+#                    anything else; one process, because a chip belongs
+#                    to one process at a time.
 #   make prof        continuous-profiler demo: spin an in-process
 #                    engine, run the cnn headline workload, print the
 #                    time-attribution table (python -m client_tpu.profview
@@ -49,7 +55,7 @@ NATIVE_OUT := client_tpu/utils/shared_memory
 TPUSHM_OUT := client_tpu/utils/tpu_shared_memory
 
 .PHONY: all protos native cpp clean test asan java java-bindings lint \
-        lint-sarif lint-strict check soak chaos prof
+        lint-sarif lint-strict check soak chaos prof smoke
 
 lint:
 	python -m client_tpu.analysis client_tpu tests
@@ -71,6 +77,11 @@ check: lint
 	JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' \
 	    --continue-on-collection-errors -p no:cacheprovider \
 	    -p no:xdist -p no:randomly
+
+# The quickest proof that the system still starts on the chip.  No
+# JAX_PLATFORMS here: the script must see whatever the machine has.
+smoke:
+	python chip_smoke.py
 
 # Where the engine's time goes, in one command: an in-process engine
 # runs the cnn headline workload and profview renders the

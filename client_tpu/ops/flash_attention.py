@@ -41,8 +41,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from client_tpu._jax_compat import CompilerParams as _CompilerParams
-
 _NEG = -1e30  # -inf stand-in that keeps exp() NaN-free
 
 
@@ -140,10 +138,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
 def _sds(shape, dtype, like):
     """ShapeDtypeStruct carrying the varying-mesh-axes of ``like`` so
     pallas_call outputs type-check under shard_map's check_vma."""
-    vma = tuple(jax.typeof(like).vma) if hasattr(jax, "typeof") else None
-    if vma:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=frozenset(vma))
-    return jax.ShapeDtypeStruct(shape, dtype)
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 def _fa_forward(q, k, v, scale, block_q, block_k, causal, interpret):
@@ -175,7 +170,7 @@ def _fa_forward(q, k, v, scale, block_q, block_k, causal, interpret):
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
@@ -276,7 +271,7 @@ def _fa_backward(q, k, v, out, lse, g, g_lse, scale, block_q, block_k,
                   rowspec],
         out_specs=qspec,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
@@ -303,7 +298,7 @@ def _fa_backward(q, k, v, out, lse, g, g_lse, scale, block_q, block_k,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,

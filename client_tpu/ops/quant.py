@@ -29,8 +29,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from client_tpu._jax_compat import CompilerParams as _CompilerParams
-
 
 def quantize_int8(w):
     """Per-output-channel symmetric int8 quantization of a [K, N] weight.
@@ -70,6 +68,19 @@ def _int8_mm_kernel(x_ref, wq_ref, s_ref, o_ref, acc_ref, *, n_k):
         o_ref[...] = (acc_ref[:] * s_ref[...]).astype(o_ref.dtype)
 
 
+def _tile(dim, cap, align):
+    """Block size for one weight dim: the whole dim when it fits under
+    *cap* (a block spanning its dim needs no alignment), else the largest
+    multiple of *align* under *cap* that divides it; None for a ragged
+    dim."""
+    if dim <= cap:
+        return dim
+    for tile in range(cap // align * align, 0, -align):
+        if dim % tile == 0:
+            return tile
+    return None
+
+
 def int8_matmul(x, qw, block_m=128, block_n=128, block_k=512,
                 interpret=None):
     """``x @ (q * s)`` with int8 weight tiles streamed through VMEM.
@@ -90,16 +101,19 @@ def int8_matmul(x, qw, block_m=128, block_n=128, block_k=512,
         m *= d
     x2 = x.reshape(m, k)
 
-    # tile sizes: sublane/lane-aligned, clamped to padded dims
-    bm = min(block_m, max(8, -(-m // 8) * 8))
-    bn = min(block_n, n)
-    bk = min(block_k, k)
+    # M pads up to the activation dtype's sublane count (f32 8, bf16 16):
+    # a decode tick brings M = lanes <= 8 in bf16, which is half a tile
+    sublane = 32 // x.dtype.itemsize
+    bm = min(block_m, -(-m // sublane) * sublane)
+    bn = _tile(n, block_n, 128)
+    bk = _tile(k, block_k, 128)
     pad_m = (-m) % bm
-    if n % bn or k % bk:
-        # ragged weight dims: dequantized jnp fallback (rare — projection
-        # widths are MXU-shaped multiples in every shipped config)
+    if bn is None or bk is None:
+        # ragged weight dim (the byte-vocab lm_head, N = 258): dequantized
+        # jnp path.  Every projection of a shipped config tiles, and
+        # chip_smoke.py asserts the kernel is what those shapes lower to.
         w = q.astype(x.dtype) * s.astype(x.dtype)
-        return (x2[:m] @ w).reshape(*lead, n)
+        return (x2 @ w).reshape(*lead, n)
     if pad_m:
         x2 = jnp.pad(x2, ((0, pad_m), (0, 0)))
 
@@ -115,7 +129,7 @@ def int8_matmul(x, qw, block_m=128, block_n=128, block_k=512,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

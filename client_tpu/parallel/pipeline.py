@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from client_tpu._jax_compat import shard_map as _shard_map
-
 
 def stack_stage_params(layers, n_stages):
     """[L] list of identical per-layer pytrees -> pytree with leading
@@ -134,7 +132,7 @@ def pipeline_apply(stage_fn, stage_params, x, mesh, n_microbatches,
         # broadcasts them to every stage, making the result replicated
         return lax.psum(outputs, axis)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(param_specs, x_spec),

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from client_tpu._jax_compat import shard_map as _shard_map
-
 _NEG = -1e30  # stand-in for -inf that keeps exp() NaN-free
 
 
@@ -231,7 +229,7 @@ def ring_attention_sharded(q, k, v, mesh, causal=True, scale=None,
     # checker rejects — disable it only there; compiled TPU runs keep the
     # checker for both impls.
     interpret = jax.default_backend() != "tpu"
-    fn = _shard_map(
+    fn = jax.shard_map(
         lambda a, b_, c: ring_attention(a, b_, c, "sp", causal, scale, impl),
         mesh=mesh,
         in_specs=(spec, spec, spec),

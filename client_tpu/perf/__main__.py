@@ -863,6 +863,17 @@ def main(argv=None):
             write_csv(args.filename, results, verbose=args.verbose)
             print(f"wrote {args.filename}")
         if args.json_export:
+            # the device this process's JAX holds (TPU-shm regions, an
+            # in-process engine), as JAX reports it; null for a client
+            # that never opened one — a rate is read against its device
+            from client_tpu.serve.metrics import initialized_devices
+
+            devices = initialized_devices()
+            json_extra["device"] = {
+                "platform": devices[0].platform,
+                "kind": devices[0].device_kind,
+                "count": len(devices),
+            } if devices else None
             write_json(args.json_export, results, extra=json_extra)
             print(f"wrote {args.json_export}")
         return 0 if results and all(r.error_count == 0 for r in results) else 1
@@ -885,4 +896,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from client_tpu._compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

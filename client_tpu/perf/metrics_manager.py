@@ -95,9 +95,10 @@ class DeviceUtilizationProbe:
     Per sample: queue delay in us, and a busy flag (latency >
     busy_factor × idle baseline).  A window of samples summarizes as
     ``ctpu_probe_utilization_pct`` = busy percent — an *estimate*: probes
-    are point samples, so short kernels can slip between them, and on a
-    high-RTT tunneled device the link jitter widens the baseline band
-    (busy_factor is deliberately 2x).
+    are point samples, so short kernels can slip between them (busy_factor
+    is deliberately 2x).  The probe opens a JAX backend in the perf
+    process: on a host whose chip belongs to one process it only works when
+    perf and the server share that process (``--hermetic``).
     """
 
     def __init__(self, busy_factor=2.0, baseline_samples=8):

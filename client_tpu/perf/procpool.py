@@ -14,11 +14,14 @@ TPU-shm loads use **region-by-name referencing**: the coordinator (which
 owns jax/device access) creates and registers the HBM regions; workers build
 requests that reference those regions by name and never initialize a device
 backend — exactly how a fleet of remote clients would drive a TPU serving
-host.  Linux CLOCK_MONOTONIC is system-wide, so worker-reported window
-timestamps merge directly.
+host, and a requirement on a host whose chip belongs to one process: each
+worker pins ``JAX_PLATFORMS=cpu`` and reports the backends it ended up with
+(``ProcPoolResult.worker_backends``, all empty).  Linux CLOCK_MONOTONIC is
+system-wide, so worker-reported window timestamps merge directly.
 """
 
 import multiprocessing
+import os
 import time
 
 import numpy as np
@@ -109,6 +112,9 @@ def export_region_specs(data_manager, inputs_meta, loader):
 def _worker_main(conn, url, model_name, concurrency, warmup_s, window_s, spec):
     """One load process: build the object graph, wait for 'go', run the
     window, report records.  Never touches a device backend."""
+    # the chip belongs to the coordinator or the server; should anything
+    # below ever import jax, it must not reach for it
+    os.environ["JAX_PLATFORMS"] = "cpu"
     try:
         from client_tpu.perf import (
             BackendKind,
@@ -165,8 +171,13 @@ def _worker_main(conn, url, model_name, concurrency, warmup_s, window_s, spec):
         records = manager.swap_timestamps()
         sent = manager.get_and_reset_num_sent()
         ok = [r for r in records if r.ok]
+        from client_tpu.serve.metrics import initialized_devices
+
         conn.send(
             {
+                "backends": sorted(
+                    {d.platform for d in initialized_devices()}
+                ),
                 "ok": len(ok),
                 "errors": len(records) - len(ok),
                 "sent": sent,
@@ -200,6 +211,7 @@ class ProcPoolResult:
         self.window_s = 0.0
         self.processes = 0
         self.concurrency = 0
+        self.worker_backends = []  # per worker: JAX platforms it opened
 
 
 def run_completion_multiproc(url, model_name, *, processes, concurrency,
@@ -263,6 +275,7 @@ def run_completion_multiproc(url, model_name, *, processes, concurrency,
         out = ProcPoolResult()
         out.processes = processes
         out.concurrency = per * processes
+        out.worker_backends = [r["backends"] for r in results]
         t0 = min(r["t0"] for r in results)
         elapsed = (t_close - t0) / 1e9
         out.window_s = elapsed

@@ -168,4 +168,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from client_tpu._compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -8,9 +8,18 @@ both close through.  One wait covers a whole backlog: the observer blocks on
 request threads is not dispatch order, and multi-device models have no
 single stream), then fires the callbacks.  Host-only results complete
 immediately on the caller thread.
+
+It is also the only place a device-side failure AFTER the response can be
+seen: TPU-shm requests are acknowledged at dispatch, so when the device
+work later fails nobody is left waiting on it.  The observer logs the error
+and hands it to the watch's ``on_error`` (the model's failure statistics)
+before running the completion callback, which still always runs.
 """
 
+import logging
 import threading
+
+_log = logging.getLogger(__name__)
 
 
 def _completion_arrays(result, out=None):
@@ -33,12 +42,14 @@ class CompletionObserver:
     def __init__(self, name="completion-observer"):
         self._name = name
         self._cv = threading.Condition()
-        self._backlog = []  # (arrays, callback)
+        self._backlog = []  # (arrays, callback, on_error)
         self._closed = False
         self._thread = None
 
-    def watch(self, result, callback):
-        """Run *callback* once every device array in *result* has completed.
+    def watch(self, result, callback, on_error=None):
+        """Run *callback* once every device array in *result* has completed
+        — or failed: then the error is logged and *on_error(exc)* runs
+        first.
 
         Host results (nothing to wait on) run the callback inline.  Watches
         arriving after close() — e.g. a batcher thread that outlived its
@@ -56,20 +67,34 @@ class CompletionObserver:
                         target=self._loop, name=self._name, daemon=True
                     )
                     self._thread.start()
-                self._backlog.append((arrays, callback))
+                self._backlog.append((arrays, callback, on_error))
                 self._cv.notify()
                 return
-        self._settle(arrays)
+        self._report(self._settle(arrays), on_error)
         callback()
 
     @staticmethod
     def _settle(arrays):
+        """Wait for *arrays*; the exception their device work raised, or
+        None (failed results still complete)."""
         try:
             import jax
 
             jax.block_until_ready(arrays)
-        except Exception:  # noqa: BLE001 - failed results still complete
-            pass
+        except Exception as exc:  # noqa: BLE001 - reported by the caller
+            return exc
+        return None
+
+    def _report(self, exc, on_error):
+        if exc is None:
+            return
+        _log.error("%s: device work failed after dispatch: %r",
+                   self._name, exc)
+        if on_error is not None:
+            try:
+                on_error(exc)
+            except Exception:  # noqa: BLE001 - the callback must still run
+                _log.exception("%s: on_error raised", self._name)
 
     def _loop(self):
         # one guard per pass (the BG-THREAD-CRASH shape): a raising
@@ -92,9 +117,13 @@ class CompletionObserver:
             if not self._backlog:
                 return False
             batch, self._backlog = self._backlog, []
-        self._settle([arrays for arrays, _ in batch])
-        for _, callback in batch:
+        failed = self._settle([arrays for arrays, _, _ in batch]) is not None
+        for arrays, callback, on_error in batch:
             try:
+                if failed:
+                    # one wait covered the backlog; only a failed one is
+                    # repeated per item, to find whose work it was
+                    self._report(self._settle(arrays), on_error)
                 callback()
             except Exception:  # noqa: BLE001 - siblings must still run
                 pass

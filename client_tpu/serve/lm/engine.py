@@ -1343,8 +1343,7 @@ class LmEngine:
         self._tokens, self._keys = self._adopt(
             self._tokens, self._keys, jnp.int32(job.slot), tok, job.key
         )
-        if hasattr(tok, "copy_to_host_async"):
-            tok.copy_to_host_async()
+        tok.copy_to_host_async()
         self._inflight.append((tok, snapshot))
 
     def _export_prefix(self, export):
@@ -1482,8 +1481,7 @@ class LmEngine:
         )
         self.kv.pools["k"] = pool_k
         self.kv.pools["v"] = pool_v
-        if hasattr(self._tokens, "copy_to_host_async"):
-            self._tokens.copy_to_host_async()
+        self._tokens.copy_to_host_async()
         self._inflight.append((self._tokens, tuple(active)))
         self._log_tick("decode", t0, tuple(i for i, _ in active))
         return True

@@ -174,8 +174,8 @@ PROF_HELP = {
     "ctpu_prof_phase_seconds_total":
         "Cumulative seconds attributed to each profiled phase",
     "ctpu_prof_mfu_pct":
-        "Model FLOP utilization over measured device time (vs "
-        "device_peak_tflops; cpu_fallback peak off-TPU)",
+        "Model FLOP utilization over measured device time (vs the "
+        "chip's published bf16 peak; absent off-TPU)",
     "ctpu_prof_compute_share_pct":
         "Share of measured device time attributed to each model",
 }
@@ -498,20 +498,29 @@ class _FamilyBuffer:
             lines.extend(samples)
 
 
-def _device_lines(buf):
-    # Only report devices when jax is already loaded: a server actually
-    # serving jax models has it imported; forcing the import (and backend
-    # init — seconds) inside the /metrics handler would stall the first
-    # scrape of every numpy-only server past typical scraper timeouts.
+def initialized_devices():
+    """``jax.devices()`` if this process has already initialised a JAX
+    backend, else ``[]``.
+
+    Asking must never be the call that opens the chip: a TPU belongs to
+    one process at a time, and a numpy-only replica or a load worker
+    that looked would take it from the server that needs it, or fail
+    against the one that holds it.  It would also stall the first
+    /metrics scrape of every numpy-only server on a backend start-up."""
     import sys
 
     jax = sys.modules.get("jax")
     if jax is None:
-        return
-    try:
-        devices = jax.devices()
-    except Exception:
-        return
+        return []
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return []
+    return jax.devices()
+
+
+def _device_lines(buf):
+    devices = initialized_devices()
     declared = False
     for d in devices:
         try:

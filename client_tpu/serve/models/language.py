@@ -169,7 +169,7 @@ def lm_streaming_model(name="lm_streaming", runner=None):
                 "TEXT": np.array([piece], dtype=np.object_),
             }
 
-    return Model(
+    model = Model(
         name,
         inputs=[
             TensorSpec("TOKENS", "INT32", [-1]),
@@ -182,6 +182,11 @@ def lm_streaming_model(name="lm_streaming", runner=None):
         fn=fn,
         decoupled=True,
     )
+    # which program this name resolved to (the serial _LmRunner or the
+    # engine's BatchedLmRunner): lm_streaming_int8 differs by backend, and
+    # chip_smoke.py prints what it exercised
+    model.runner = runner
+    return model
 
 
 def lm_streaming_batched_model(name="lm_streaming_batched", runner=None,

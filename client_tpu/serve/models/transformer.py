@@ -603,8 +603,7 @@ def generate(params, cfg, prompt, max_new_tokens, temperature=0.0, key=None,
         else:
             token = jnp.argmax(logits, axis=-1)
         token = token.astype(jnp.int32)
-        if hasattr(token, "copy_to_host_async"):
-            token.copy_to_host_async()
+        token.copy_to_host_async()
         pending.append(token)
         if i + 1 < max_new_tokens:
             logits, cache = decode_fn(params, token, cache=cache)

@@ -18,9 +18,10 @@ the hot paths and rolled up on demand:
 Rollups attribute windowed wall time into per-phase shares, and the
 measured device time + per-model FLOP figures produce compute-share and
 MFU series (``ctpu_prof_*`` gauges/counters in serve/metrics.py's
-catalog).  :func:`device_peak_tflops` supplies the MFU denominator —
-the advertised TPU bf16 peak, or a measured host GEMM peak off-TPU
-(``cpu_fallback``) so attribution ratios are non-null everywhere.
+catalog).  :func:`device_peak_tflops` supplies the MFU denominator: the
+published bf16 peak of the TPU this process runs on.  Off-TPU there is
+no MFU — a host ratio under a device metric's name is how a run with no
+chip attached once passed for a benchmark.
 
 Surfaces: ``GET /v2/debug/prof`` (rollup JSON),
 ``python -m client_tpu.profview`` (attribution tables), flight-recorder
@@ -52,18 +53,18 @@ __all__ = [
     "NULL_TICK",
     "ATTRIBUTION_GROUPS",
     "device_peak_tflops",
-    "host_peak_tflops",
     "attribute_phases",
 ]
 
-# Advertised dense bf16 peaks by TPU device kind (the MFU denominator;
-# bench.py delegates here so the table has one home).
-_TPU_PEAKS = (
-    ("v5 lite", 197.0), ("v5e", 197.0),
-    ("v5p", 459.0), ("v5", 459.0),
-    ("v6", 918.0),                      # Trillium
-    ("v4", 275.0), ("v3", 123.0),
-)
+# Published dense bf16 peak per chip in TFLOP/s, keyed by the exact
+# ``device_kind`` JAX reports (the MFU denominator; bench.py delegates
+# here so the table has one home).  A TPU kind that is not listed is an
+# error, never a neighbour's peak: add the row, with its source, the
+# first time the program runs on that chip.
+_TPU_PEAK_BF16_TFLOPS = {
+    # TPU v5e — Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16
+    "TPU v5 lite": 197.0,
+}
 
 # Phase -> attribution bucket for the dispatch/compute/host/idle split
 # (bench's prof block, profview's summary row).  On the CPU test
@@ -80,55 +81,30 @@ ATTRIBUTION_GROUPS = {
     "idle": ("idle",),
 }
 
-_peak_cache = None
-_peak_lock = threading.Lock()
-
-
-def host_peak_tflops(n=384, reps=3):
-    """Measured host GEMM peak in TFLOP/s (best of *reps* numpy matmuls
-    of an n x n fp32 problem) — the off-TPU MFU denominator.  A probe,
-    not an advertised figure: BLAS-backed numpy lands within a small
-    factor of the host's real dense peak, which is all an attribution
-    *ratio* needs."""
-    import numpy as np
-
-    a = np.ones((n, n), np.float32)
-    b = np.ones((n, n), np.float32)
-    a @ b  # warm the BLAS path outside the timed reps
-    best = float("inf")
-    for _ in range(max(1, int(reps))):
-        t0 = time.perf_counter()
-        a @ b
-        best = min(best, time.perf_counter() - t0)
-    flops = 2.0 * n * n * n
-    return max(flops / max(best, 1e-9) / 1e12, 1e-6)
-
-
 def device_peak_tflops():
-    """(peak_tflops, peak_kind) of the local accelerator.
+    """``(peak_tflops, device_kind)`` of the accelerator this process
+    already holds.
 
-    TPU kinds map to their advertised dense bf16 peaks; anything else
-    (the CPU test platform, an unrecognized device) falls back to the
-    measured host GEMM peak tagged ``"cpu_fallback"`` so MFU figures
-    are non-null everywhere.  Cached: the probe runs once per process.
-    """
-    global _peak_cache
-    with _peak_lock:
-        if _peak_cache is not None:
-            return _peak_cache
-        kind = ""
-        try:
-            import jax
+    ``(None, None)`` while the process has initialised no JAX backend —
+    asking must never be the call that opens the chip (see
+    :func:`metrics.initialized_devices`) — and ``(None, kind)`` on any
+    platform but a TPU, so off-TPU every MFU figure is absent rather
+    than a host ratio.  A TPU whose ``device_kind`` the table does not
+    list raises."""
+    from client_tpu.serve.metrics import initialized_devices
 
-            kind = getattr(jax.devices()[0], "device_kind", "").lower()
-        except Exception:
-            pass
-        for pat, peak in _TPU_PEAKS:
-            if pat in kind:
-                _peak_cache = (peak, "tpu")
-                return _peak_cache
-        _peak_cache = (round(host_peak_tflops(), 4), "cpu_fallback")
-        return _peak_cache
+    devices = initialized_devices()
+    if not devices:
+        return None, None
+    kind = devices[0].device_kind
+    if devices[0].platform != "tpu":
+        return None, kind
+    if kind not in _TPU_PEAK_BF16_TFLOPS:
+        raise ValueError(
+            f"no published bf16 peak for TPU device_kind {kind!r}: add "
+            "it, with its source, to serve/prof.py:_TPU_PEAK_BF16_TFLOPS"
+        )
+    return _TPU_PEAK_BF16_TFLOPS[kind], kind
 
 
 def attribute_phases(phases, wall_s=None):
@@ -478,6 +454,7 @@ class PhaseProfiler:
                 help_=PROF_HELP["ctpu_prof_phase_seconds_total"],
             )
         total_device = sum(v[0] for v in models.values())
+        peak = device_peak_tflops()[0]
         for model, (dev, _items, total_flops) in models.items():
             if total_device > 0.0:
                 registry.set(
@@ -486,8 +463,7 @@ class PhaseProfiler:
                     round(100.0 * dev / total_device, 3),
                     help_=PROF_HELP["ctpu_prof_compute_share_pct"],
                 )
-            if total_flops and dev > 0.0:
-                peak, _kind = device_peak_tflops()
+            if peak and total_flops and dev > 0.0:
                 registry.set(
                     "ctpu_prof_mfu_pct",
                     {"engine": engine, "model": model},
@@ -570,7 +546,7 @@ class PhaseProfiler:
                 phases.items(), key=lambda kv: -kv[1]
             )
         }
-        peak, peak_kind = device_peak_tflops()
+        peak, device_kind = device_peak_tflops()
         total_device = sum(v[0] for v in models.values())
         with self._lock:
             flops_by_model = {
@@ -587,7 +563,7 @@ class PhaseProfiler:
                 ),
             }
             flops = flops_by_model.get(model)
-            if flops and device_s > 0.0:
+            if peak and flops and device_s > 0.0:
                 # lifetime FLOP/s over lifetime device time: the ring
                 # window carries items but not flops per record
                 with self._lock:
@@ -608,7 +584,7 @@ class PhaseProfiler:
             "models": model_rows,
             "attribution": attribute_phases(phases, wall_s=wall),
             "peak_tflops": peak,
-            "peak_kind": peak_kind,
+            "device_kind": device_kind,
         }
 
     def report(self, window_s=None):

@@ -152,7 +152,9 @@ class SloWatchdog:
         self.max_keys = int(max_keys)
         self._lock = threading.Lock()
         self._keys = OrderedDict()  # (model, tenant) -> _Key
-        self._last_dump = 0.0
+        # -inf, not 0.0: time.monotonic() counts from boot, so on a host up
+        # less than dump_interval_s a zero here swallowed the first dump
+        self._last_dump = float("-inf")
         self.breaches = 0
 
     def objective_for(self, model):

@@ -195,7 +195,19 @@ class TpuRegion:
 
     def _device(self):
         jax = _jax()
-        devs = jax.devices()
+        try:
+            devs = jax.devices()
+        except RuntimeError as e:
+            # a TPU belongs to one process at a time: against a standalone
+            # server that already holds the chip, this is where a client
+            # process finds out
+            raise InferenceServerException(
+                f"TPU region '{self.name}': this process cannot open a JAX "
+                f"device ({e}). If a server on this host holds the chip, "
+                "run the client with JAX_PLATFORMS=cpu (the region then "
+                "stages in host memory and reaches the server through its "
+                "host window) or serve in-process (--hermetic)."
+            ) from e
         if self.device_id >= len(devs):
             raise InferenceServerException(
                 f"TPU device {self.device_id} not present ({len(devs)} devices)"
@@ -257,10 +269,8 @@ class TpuRegion:
 
         The D2H transfer of dirty slots happens OUTSIDE the region lock:
         concurrent readers (e.g. perf-harness completion-sync workers all
-        polling the same output region) each pay their own link RTT in
-        parallel instead of serializing behind one lock-held transfer — on a
-        tunneled device that is the difference between N×RTT and ~1×RTT for
-        N concurrent syncs."""
+        polling the same output region) each wait for their own transfer in
+        parallel instead of serializing behind one lock-held transfer."""
         if offset < 0 or nbytes < 0 or offset + nbytes > self.byte_size:
             raise InferenceServerException(
                 f"read of {nbytes} bytes at offset {offset} overruns TPU "

@@ -5,8 +5,11 @@ the serialized raw handle to the server, run zero-copy infer with
 inputs/outputs resident in device memory, read results back.
 
 In-process (--hermetic) the server resolves the regions broker-side with no
-host copies; against an out-of-process same-host server the region carries a
-staging mirror.
+host copies; against an out-of-process same-host server the region reaches
+the server through its host window.  A TPU belongs to one process at a time:
+when that server holds the chip, run this client with JAX_PLATFORMS=cpu (its
+region then stages in host memory); without it the client fails with a
+message saying so (verified on a v5e, PR 21).
 """
 
 import argparse

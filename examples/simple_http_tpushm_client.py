@@ -1,7 +1,9 @@
 #!/usr/bin/env python
 """TPU device-buffer shared memory over HTTP — the framework's CUDA-shm
 analog (reference simple_http_cudashm_client.py): tensors live in HBM
-regions, requests carry only region references."""
+regions, requests carry only region references.  Against a standalone
+server that holds the chip, run with JAX_PLATFORMS=cpu (host-window face)
+or --hermetic: a TPU belongs to one process at a time."""
 
 import argparse
 import os

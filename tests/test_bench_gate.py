@@ -105,10 +105,10 @@ def test_prof_block_attributes_only_ticked_engines(bench):
         {"engine": "serve", "ticks": 12, "attribution": split},
         {"engine": "lm", "ticks": 0, "attribution": None},
     ]}
-    block = bench._prof_block(report, 0.4, "cpu_fallback")
+    block = bench._prof_block(report, 0.4, "TPU v5 lite")
     assert block["cnn224"] == split
     assert block["lm"] is None          # no ticks -> no made-up split
     assert block["wire"] is None
     assert block["prof_overhead_pct"] == 0.4
-    assert block["peak_kind"] == "cpu_fallback"
+    assert block["peak_kind"] == "TPU v5 lite"
     assert abs(sum(split.values()) - 100.0) < 0.5

@@ -420,6 +420,48 @@ def test_batcher_error_propagates_per_request():
     engine.close()
 
 
+def test_device_failure_after_ack_reaches_log_and_stats(caplog):
+    """TPU-shm acks at dispatch, so a device failure after the ack is seen
+    only by the completion observer: it must log the error and count it
+    in the model's failure statistics, for the failing watch alone, and
+    still run every completion callback."""
+    from client_tpu.serve._completion import CompletionObserver
+    from client_tpu.serve.model_runtime import ModelStats
+
+    class Result:
+        def __init__(self, error=None):
+            self.error = error
+
+        def block_until_ready(self):
+            if self.error is not None:
+                raise self.error
+            return self
+
+    stats_ok, stats_bad = ModelStats(), ModelStats()
+    done = []
+    both = threading.Event()
+
+    def finish(tag):
+        done.append(tag)
+        if len(done) == 2:
+            both.set()
+
+    obs = CompletionObserver(name="batcher-m-watch")
+    with caplog.at_level("ERROR", logger="client_tpu.serve._completion"):
+        obs.watch(Result(), lambda: finish("ok"),
+                  on_error=lambda exc: stats_ok.record_device_failure())
+        obs.watch(Result(RuntimeError("HBM fell over")),
+                  lambda: finish("bad"),
+                  on_error=lambda exc: stats_bad.record_device_failure(3))
+        assert both.wait(timeout=10)
+        obs.close()
+    assert sorted(done) == ["bad", "ok"]
+    assert stats_ok.fail_count == 0
+    assert stats_bad.fail_count == 3
+    assert "batcher-m-watch" in caplog.text
+    assert "HBM fell over" in caplog.text
+
+
 def test_unload_closes_batcher_and_reload_works():
     record = []
     engine = InferenceEngine(models=[_echo_model(record)])

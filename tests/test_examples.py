@@ -67,6 +67,8 @@ def server():
 
 
 def _run_example(name, url):
+    # JAX_PLATFORMS=cpu in the child is also how an out-of-process TPU-shm
+    # example runs beside a server that holds the chip (host-window face)
     proc = subprocess.run(
         [sys.executable, os.path.join(_EXAMPLES, name), "-u", url],
         capture_output=True, text=True, timeout=120,

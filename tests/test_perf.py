@@ -957,6 +957,8 @@ class TestProcPool:
             assert res.completed_requests > 0
             assert res.throughput > 0
             assert 50 in res.percentiles_us
+            # a load worker never opens a device: the chip is the server's
+            assert res.worker_backends == [[], []]
 
     def test_multiproc_worker_error_reported(self):
         from client_tpu.perf.procpool import run_completion_multiproc

@@ -175,17 +175,63 @@ class TestMetricsExport:
         assert "ctpu_prof_phase_seconds_total" in text
         assert "ctpu_prof_compute_share_pct" in text
 
-    def test_mfu_uses_measured_peak(self):
+    def test_no_mfu_off_tpu(self):
+        import jax
+
+        jax.devices()  # the profiler only looks at a backend already up
         reg = Registry()
         p = PhaseProfiler(name="t", registry=reg)
         p.commit("unary", 1e-3, phases={"compute": 1e-3},
                  model="m", items=1, flops_per_item=1e6)
         p.flush_metrics()
-        peak, kind = device_peak_tflops()
-        assert peak > 0 and kind in ("tpu", "cpu_fallback")
+        assert device_peak_tflops() == (None, "cpu")
         roll = p.rollup(window_s=0)
-        assert roll["peak_kind"] == kind
-        assert roll["models"]["m"]["mfu_pct"] > 0
+        assert roll["peak_tflops"] is None
+        assert roll["device_kind"] == "cpu"
+        assert "mfu_pct" not in roll["models"]["m"]
+        lines = []
+        reg.render_into(lines)
+        assert not any("ctpu_prof_mfu_pct" in line for line in lines)
+
+    def test_peak_is_exact_key_and_unlisted_tpu_raises(self, monkeypatch):
+        from client_tpu.serve import metrics
+
+        class Dev:
+            platform = "tpu"
+
+            def __init__(self, kind):
+                self.device_kind = kind
+
+        monkeypatch.setattr(
+            metrics, "initialized_devices", lambda: [Dev("TPU v5 lite")]
+        )
+        assert device_peak_tflops() == (197.0, "TPU v5 lite")
+        p = PhaseProfiler(name="t")
+        p.commit("unary", 1e-3, phases={"compute": 1e-3},
+                 model="m", items=1, flops_per_item=1e6)
+        assert p.rollup(window_s=0)["models"]["m"]["mfu_pct"] > 0
+        # a substring match would hand this kind a neighbour's peak
+        monkeypatch.setattr(
+            metrics, "initialized_devices", lambda: [Dev("TPU v5 lite pod")]
+        )
+        with pytest.raises(ValueError, match="TPU v5 lite pod"):
+            device_peak_tflops()
+
+    def test_peak_lookup_never_initialises_a_backend(self, monkeypatch):
+        import jax
+        from jax._src import xla_bridge
+
+        from client_tpu.serve.metrics import initialized_devices
+
+        def opened():
+            raise AssertionError("jax.devices() would open the chip")
+
+        monkeypatch.setattr(
+            xla_bridge, "backends_are_initialized", lambda: False
+        )
+        monkeypatch.setattr(jax, "devices", opened)
+        assert initialized_devices() == []
+        assert device_peak_tflops() == (None, None)
 
     def test_prof_prefix_is_whitelisted(self):
         from client_tpu.perf.metrics_manager import MetricsManager
