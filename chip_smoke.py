@@ -47,6 +47,7 @@ import sys
 import tempfile
 import threading
 import time
+import urllib.request
 
 import numpy as np
 
@@ -190,7 +191,81 @@ def _model_stats(client, model_name):
         "fail": stats.inference_stats.fail.count,
         "executions": stats.execution_count,
         "rows": stats.inference_count,
+        "compute_infer_ns": stats.inference_stats.compute_infer.ns,
     }
+
+
+def _batcher(server, model_name):
+    engine = server.engine
+    return engine._batcher_for(engine.get_model(model_name))
+
+
+def _blocked_step_s(server, model_name, part, repeats=5):
+    """The batcher's own fused program on one request's rows, dispatched
+    and blocked on: (the dispatch call, the wait for its result), the
+    medians of ``repeats``, in seconds."""
+    import jax
+
+    fused = _batcher(server, model_name)._fused_jit()
+    parts = {name: (rows,) for name, rows in part.items()}
+    jax.block_until_ready(fused(parts))
+    calls, waits = [], []
+    for _ in range(repeats):
+        t0 = time.monotonic()
+        out = fused(parts)
+        t1 = time.monotonic()
+        jax.block_until_ready(out)
+        calls.append(t1 - t0)
+        waits.append(time.monotonic() - t1)
+    return sorted(calls)[repeats // 2], sorted(waits)[repeats // 2]
+
+
+def _device_time_checks(server, control, model_name, platform, facts,
+                        send_one, part, steps=8):
+    """What the program calls device time, against the device.  The
+    phase's traffic has just run at a concurrency at which no step waits
+    behind another: ``/v2/debug/prof`` must read the model an ``mfu_pct``
+    in (0, 100].  Then ``steps`` requests one after the other, each a step
+    of its own on the same rows: the statistics endpoint's
+    ``compute_infer_ns`` a step must agree within a tenth with that step
+    dispatched here and blocked on.  Device time runs from the return of
+    the dispatch call (the batcher's ``t_in``; the call may compile) to
+    completion, so the blocked step is timed from there too, and the call
+    is reported beside it: of a step this small it is a tenth and more.
+    Off the TPU there is no MFU, and the host's clock decides nothing
+    about a device: the path runs, the bounds are the chip's."""
+    with urllib.request.urlopen(
+            f"http://{server.http_address}/v2/debug/prof?window=0") as r:
+        report = json.load(r)
+    row = next(e for e in report["engines"]
+               if e["engine"] == "serve")["models"][model_name]
+    check(row["device_s"] > 0, f"no device time for {model_name}: {row}")
+    before = _model_stats(control, model_name)
+    for _ in range(steps):
+        send_one()
+    # an execution's time is added when the observer sees it complete,
+    # just before the batcher counts it out of flight
+    _wait_for(lambda: _batcher(server, model_name)._inflight == 0,
+              "the observer to see the last step complete")
+    after = _model_stats(control, model_name)
+    check(after["executions"] - before["executions"] == steps,
+          f"{steps} requests in turn were not {steps} steps")
+    reported_s = ((after["compute_infer_ns"] - before["compute_infer_ns"])
+                  / steps / 1e9)
+    call_s, blocked_s = _blocked_step_s(server, model_name, part)
+    facts["device_time"] = {
+        "mfu_pct": row.get("mfu_pct"), "device_s": row["device_s"],
+        "compute_infer_ms_a_step": round(1e3 * reported_s, 4),
+        "blocked_step_ms": round(1e3 * blocked_s, 4),
+        "dispatch_call_ms": round(1e3 * call_s, 4),
+    }
+    check(reported_s > 0 and blocked_s > 0, facts["device_time"])
+    if platform == "tpu":
+        check(0 < row.get("mfu_pct", 0) <= 100,
+              f"mfu_pct of {model_name} outside (0, 100]: {row}")
+        check(abs(reported_s / blocked_s - 1) <= 0.10,
+              f"compute_infer_ns a step against a blocked step: "
+              f"{facts['device_time']}")
 
 
 def vision_phase(server, model_name, reference, image_size, platform, facts,
@@ -281,6 +356,17 @@ def vision_phase(server, model_name, reference, image_size, platform, facts,
             # fewer executions than requests: the fused batcher path
             # (dynamic_batcher._fused_group_fn) served several at once
             check(delta["executions"] < requests, f"no fused batch: {delta}")
+
+            (in_name, in_h), (out_name, out_h) = regions[0]
+
+            def send_one():
+                inputs, outputs = request_io(in_name, out_name)
+                control.infer(model_name, inputs, outputs=outputs)
+                tpushm.get_contents_as_numpy(out_h, "FP32", [rows, N_CLASSES])
+
+            _device_time_checks(
+                server, control, model_name, platform, facts, send_one,
+                {"INPUT0": tpushm.get_contents_as_jax(in_h)})
 
             # load workers in OTHER processes reference worker 0's regions
             # by name; they must never open the chip this process holds

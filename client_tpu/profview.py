@@ -13,9 +13,10 @@ single engine rollup) or a flight-recorder JSON-lines dump whose
     python -m client_tpu.profview --live          # self-contained demo
 
 Per engine it prints tick counts by kind, the ranked per-phase table
-(seconds + percentage of covered time), the dispatch/compute/host/idle
-attribution split, and per-model device share / MFU — the table the
-38%-idle-link question is answered from.
+(seconds + percentage of covered time), the
+compute/dispatch/device_wait/host/idle attribution split, and per-model
+device share / MFU — the table the 38%-idle-link question is answered
+from.
 
 ``--live`` spins an in-process engine (the cnn224 headline model), runs
 a short unary workload through it, and renders its own report — the
@@ -71,6 +72,7 @@ def rollup_from_ticks(ticks):
             model = record.get("model")
             if model is not None:
                 entry = models.setdefault(str(model), [0.0, 0])
+                entry[0] += float(record.get("device_s", 0.0))
                 entry[1] += int(record.get("items", 0))
         covered = sum(phases.values())
         rollups.append({
@@ -90,7 +92,7 @@ def rollup_from_ticks(ticks):
                 )
             },
             "models": {
-                m: {"device_s": 0.0, "items": v[1],
+                m: {"device_s": round(v[0], 6), "items": v[1],
                     "compute_share_pct": 0.0}
                 for m, v in sorted(models.items())
             },
@@ -152,8 +154,8 @@ def render_engine(rollup, out):
             "  attribution: "
             + " | ".join(
                 f"{key[:-4]} {attribution[key]:.1f}%"
-                for key in ("compute_pct", "dispatch_pct", "host_pct",
-                            "idle_pct")
+                for key in ("compute_pct", "dispatch_pct",
+                            "device_wait_pct", "host_pct", "idle_pct")
                 if key in attribution
             )
             + "\n"

@@ -14,6 +14,7 @@ host-resident (wire) inputs.  Shared-memory requests keep the direct
 zero-copy path — batching them would force device→host materialization.
 """
 
+import functools
 import sys
 import threading
 import time
@@ -22,6 +23,7 @@ from collections import deque
 import numpy as np
 
 from client_tpu.serve._completion import CompletionObserver
+from client_tpu.serve.prof import annotation
 from client_tpu.utils import InferenceServerException
 
 
@@ -532,7 +534,8 @@ class ModelBatcher:
         # for device groups), so the H2D stream keeps flowing while earlier
         # batches' completions are in flight.
         while True:
-            group = self._gather()
+            with annotation("batch.gather"):
+                group = self._gather()
             if group is None:
                 return
             device = group[0].signature[0]
@@ -541,34 +544,47 @@ class ModelBatcher:
             # keeps filling meanwhile, and _topup folds those arrivals into
             # this batch — depth and batch size grow together under load.
             sem.acquire()
-            self._topup(group)
-            dispatched = self._dispatch(group)
+            with annotation("batch.dispatch"):
+                self._topup(group)
+                dispatched = self._dispatch(group)
             if dispatched is None:
                 sem.release()
                 continue
             with self._cond:
                 self._inflight += 1
             if device:
-                arrays = self._handoff_device(*dispatched)
+                with annotation("batch.handoff"):
+                    arrays = self._handoff_device(*dispatched)
                 if arrays is None:  # handoff failed; group already notified
                     if self._busy is not None:
                         self._busy.end()
                     self._finish_one(sem)
                 else:
-                    n_acked = len(dispatched[0])  # the group, acked above
+                    acked, _, rows, t0, t_in = dispatched  # acked above
                     self._observer.watch(
-                        arrays, lambda s=sem: self._device_done(s),
-                        on_error=lambda exc, n=n_acked: (
+                        arrays,
+                        functools.partial(
+                            self._device_done, sem, rows, t0, t_in
+                        ),
+                        on_error=lambda exc, n=len(acked): (
                             self.stats.record_device_failure(n)
                         ),
+                        t_dispatch_ns=t_in,
                     )
             else:
                 self._submit_host(dispatched)
 
-    def _device_done(self, sem):
-        """Observer callback: a device batch's results actually landed."""
+    def _device_done(self, sem, rows, t0, t_in, _t_done, device_ns,
+                     queue_ns):
+        """Observer callback: a device batch's results actually landed.
+        Its device time is known only now (serve/_completion.py), so this
+        is where compute_infer_ns and the profiler's tick are recorded;
+        the counts went in at hand-off, with the ack."""
         if self._busy is not None:
             self._busy.end()
+        self.stats.record_device_time(device_ns or 0)
+        self._prof_commit(rows, t0, t_in, device_ns or 0, 0,
+                          queue_ns=queue_ns or 0)
         self._finish_one(sem)
 
     def _finish_one(self, sem):
@@ -704,23 +720,29 @@ class ModelBatcher:
                 group, first, rows, self._max_arity(first)
             )
 
-    def _prof_commit(self, rows, t0, t_in, infer_ns, output_ns):
+    def _prof_commit(self, rows, t0, t_in, infer_ns, output_ns, queue_ns=0):
         """Fold one completed group into the engine's continuous
         profiler (serve/prof.py) as a "batch" tick, reusing the
-        timestamps record_batched already took.  Queue wait is omitted:
+        timestamps the statistics already took.  ``compute`` is the time
+        the device worked on the group and ``device_queue`` (device groups
+        only) the time it waited on the device behind the groups
+        dispatched before it.  The batcher's own queue wait is omitted:
         it overlaps other groups' device time, so summing it would
         double-count the wall."""
         prof = self.prof
         if prof is None:
             return
+        phases = {
+            "host": (t_in - t0) / 1e9,
+            "compute": infer_ns / 1e9,
+            "render": output_ns / 1e9,
+        }
+        if queue_ns:
+            phases["device_queue"] = queue_ns / 1e9
         prof.commit(
             "batch",
-            (t_in - t0 + infer_ns + output_ns) / 1e9,
-            phases={
-                "host": (t_in - t0) / 1e9,
-                "compute": infer_ns / 1e9,
-                "render": output_ns / 1e9,
-            },
+            (t_in - t0 + queue_ns + infer_ns + output_ns) / 1e9,
+            phases=phases,
             model=self.model.name,
             items=rows,
             flops_per_item=self.model.flops_per_item,
@@ -795,8 +817,8 @@ class ModelBatcher:
         """Hand a device group's results to its waiters at DISPATCH time
         (ack == dispatch, the TPU-shm contract) — splitting is lazy device
         ops, no transfer.  Returns the arrays the watcher should observe for
-        completion (busy span + semaphore close there), or None on failure
-        (the group is already notified)."""
+        completion (busy span, semaphore and device time close there), or
+        None on failure (the group is already notified)."""
         try:
             w_done = time.time_ns()
             if isinstance(result, tuple) and result[0] == "fused":
@@ -840,16 +862,16 @@ class ModelBatcher:
                 watch = result
             with self._cond:
                 self._active.difference_update(group)
-            t1 = time.monotonic_ns()
+            # counts and queue time with the ack; compute_infer_ns follows
+            # at completion (_device_done): here the device has hardly begun
             self.stats.record_batched(
                 rows=rows,
-                infer_ns=t1 - t_in,
+                infer_ns=0,
                 input_ns=t_in - t0,
                 output_ns=0,
                 queue_ns=sum(t_in - p.t_enq for p in group),
                 queue_ns_each=[t_in - p.t_enq for p in group],
             )
-            self._prof_commit(rows, t0, t_in, t1 - t_in, 0)
             return watch
         except Exception as e:  # noqa: BLE001 - failure propagates per-request
             self._fail(group, e)

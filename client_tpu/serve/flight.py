@@ -5,8 +5,8 @@ Postmortems must not depend on having had tracing enabled or a scraper
 attached when the anomaly happened.  Each server keeps one
 :class:`FlightRecorder` (``engine.flight``) fed continuously and cheaply:
 
-- every completed trace span (request timelines, LM tick spans, fleet
-  peer spans) via the tracer's ``on_complete`` hook,
+- every completed trace span (request timelines, fleet peer spans) via
+  the tracer's ``on_complete`` hook,
 - discrete events the subsystems note directly — preemptions and engine
   wedges (serve/lm/engine.py), SLO breaches (serve/slo.py), chaos
   invariant failures (testing/chaos.py), breaker/peer errors.

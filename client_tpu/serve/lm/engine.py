@@ -66,6 +66,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from client_tpu.serve._completion import CompletionObserver
 from client_tpu.serve.lm.kv import KvBlockPool
 from client_tpu.serve.lm.policy import (
     LaneAutoscaler,
@@ -370,7 +371,8 @@ class _Handle:
     _CANCELLED, or (slot, gen) once streaming."""
 
     __slots__ = ("prompt", "prompt_len", "max_tokens", "queue", "tenant",
-                 "temperature", "top_k", "seed", "placed", "remote_kv")
+                 "temperature", "top_k", "seed", "placed", "remote_kv",
+                 "t_submit", "t_admit")
 
     def __init__(self, prompt, max_tokens, q, tenant, temperature, top_k,
                  seed):
@@ -387,6 +389,11 @@ class _Handle:
         # tier on the submit caller's thread (never under _cv); _admit
         # adopts whatever still beats the local trie at admission time
         self.remote_kv = None
+        # time.monotonic() at submit() and at admission: the tick_trace()
+        # entry of the chunk that ends the prompt carries them beside the
+        # first token's completion and delivery
+        self.t_submit = time.monotonic()
+        self.t_admit = None
 
 
 class _PrefillJob:
@@ -467,7 +474,7 @@ class LmEngine:
     def __init__(self, params, cfg, max_slots=8, lane_counts=None,
                  block_size=16, pool_tokens=None, prefill_chunk=None,
                  min_bucket=16, readback_depth=8, eos_id=None,
-                 check_prompt=None, registry=None, tracer=None,
+                 check_prompt=None, registry=None,
                  tenant_lane_share=0.75, scale_up_after=3,
                  scale_down_after=50, tick_log_len=8192,
                  prefix_cache=True, min_prefix_blocks=1,
@@ -489,7 +496,6 @@ class LmEngine:
         self.eos_id = eos_id
         self.check_prompt = check_prompt  # optional prompt validator
         self.registry = registry
-        self.tracer = tracer
         # flight recorder (serve/flight.py; bound by the model binder):
         # preemptions and a wedged scheduler loop land in the server's
         # postmortem ring — a wedge also dumps it automatically
@@ -516,8 +522,14 @@ class LmEngine:
             down_after=scale_down_after,
         )
         self._tick_log = deque(maxlen=int(tick_log_len))
+        # dispatched, not yet read back: (tokens, lanes' (slot, gen), the
+        # tick_trace() entry if these are a prompt's first token)
         self._inflight = deque()
         self._thread = None  # started lazily on the first submit
+        # every tick's result is watched to completion: its instant gives
+        # the tick's device time (serve/_completion.py)
+        self._observer = CompletionObserver(name="lm-engine-watch")
+        self._device_s = 0.0  # completed, not yet in the profiler (_cv)
 
         # continuous profiler (serve/prof.py): each scheduler pass is one
         # tick with schedule/dispatch/device-wait/delivery phase spans;
@@ -629,10 +641,17 @@ class LmEngine:
             }
 
     def tick_trace(self):
-        """Recent per-tick records ({kind, t0, t1, lanes, n_lanes}) —
-        the fairness/jitter evidence tests and ops read."""
+        """Recent per-tick records, oldest first, as they stand now:
+        ``kind``, ``t0``/``t1`` (around the dispatch), ``lanes``,
+        ``n_lanes`` — the fairness/jitter evidence tests and ops read —
+        and, once the tick's device work has completed, ``t_done`` and
+        ``device_s`` (serve/_completion.py; a ``draft`` runs on the host
+        and has neither).  The ``prefill_chunk`` that ends a prompt also
+        carries the first token's wait: ``t_submit``, ``t_admit`` and,
+        once the token is on its stream's queue, ``t_delivered``.  All on
+        ``time.monotonic()``'s clock."""
         with self._cv:
-            return list(self._tick_log)
+            return [dict(entry) for entry in self._tick_log]
 
     def prefix_stats(self):
         """Prefix-cache counters ({} when the cache is disabled or the
@@ -801,6 +820,7 @@ class LmEngine:
             self._cv.notify_all()
         if self._thread is not None:
             self._thread.join(timeout=30)
+        self._observer.close()
 
     # -- locked helpers ----------------------------------------------------
 
@@ -1101,6 +1121,7 @@ class LmEngine:
                 )
                 self._lane_gauges_locked()
                 return
+            handle.t_admit = time.monotonic()
             needed = self.kv.blocks_for(
                 handle.prompt_len + handle.max_tokens
             )
@@ -1257,7 +1278,9 @@ class LmEngine:
         self.kv.pools["k"] = pool_k
         self.kv.pools["v"] = pool_v
         job.chunk_idx += 1
-        self._log_tick("prefill_chunk", t0, (job.slot,))
+        # every chunk samples a token, which nothing donates onward: the
+        # chunk's device work is complete when it is
+        entry = self._log_tick("prefill_chunk", t0, (job.slot,), tok)
         if self.registry is not None:
             self.registry.inc(
                 "ctpu_lm_prefill_chunks_total",
@@ -1344,7 +1367,10 @@ class LmEngine:
             self._tokens, self._keys, jnp.int32(job.slot), tok, job.key
         )
         tok.copy_to_host_async()
-        self._inflight.append((tok, snapshot))
+        with self._cv:
+            entry["t_submit"] = handle.t_submit
+            entry["t_admit"] = handle.t_admit
+        self._inflight.append((tok, snapshot, entry))
 
     def _export_prefix(self, export):
         """Publish freshly prefilled full prompt blocks into the fleet
@@ -1482,8 +1508,10 @@ class LmEngine:
         self.kv.pools["k"] = pool_k
         self.kv.pools["v"] = pool_v
         self._tokens.copy_to_host_async()
-        self._inflight.append((self._tokens, tuple(active)))
-        self._log_tick("decode", t0, tuple(i for i, _ in active))
+        self._inflight.append((self._tokens, tuple(active), None))
+        self._log_tick(
+            "decode", t0, tuple(i for i, _ in active), self._tokens
+        )
         return True
 
     def _verify_for(self, n, w):
@@ -1563,8 +1591,8 @@ class LmEngine:
                 cands.append((i, lane.gen, lane.spec, hist, room))
         if not cands:
             return False
-        # drafting is pure host work, outside the lock; its own phase +
-        # tick-span so profview prices draft against verify and decode
+        # drafting is pure host work, outside the lock; its own phase and
+        # tick_trace() entry, so profview prices it against verify/decode
         t_draft = time.monotonic()
         proposals = {}
         with ptick.phase("draft"):
@@ -1632,9 +1660,9 @@ class LmEngine:
             )
             self.kv.pools["k"] = pool_k
             self.kv.pools["v"] = pool_v
+        self._log_tick("verify", t0, tuple(i for i, _ in active), out)
         with ptick.phase("device_wait"):
             vals = np.asarray(out)  # [2, n]: accepted count, correction
-        self._log_tick("verify", t0, tuple(i for i, _ in active))
         self._deliver_verified(ptick, active, vals, props, counts)
         return True
 
@@ -1710,22 +1738,49 @@ class LmEngine:
                     ),
                     help_=LM_SPEC_HELP["ctpu_lm_spec_acceptance_rate"],
                 )
-        if delivered:
-            ptick.compute("lm", delivered, self._flops_per_token)
+        self._account(ptick, delivered)
 
-    def _log_tick(self, kind, t0, slots):
-        t1 = time.monotonic()
+    def _account(self, ptick, delivered):
+        """Fold the tokens a pass delivered, and the device time that
+        completed since the last call, into the pass's profiler tick."""
         with self._cv:
-            self._tick_log.append({
-                "kind": kind, "t0": t0, "t1": t1, "lanes": slots,
-                "n_lanes": self._scaler.n_lanes,
-            })
-        tracer = self.tracer
-        if tracer is not None:
-            tracer.tick_span(kind, t0, t1)
+            device_s, self._device_s = self._device_s, 0.0
+        if delivered or device_s:
+            ptick.compute("lm", delivered, self._flops_per_token,
+                          device_s=device_s)
+
+    def _log_tick(self, kind, t0, slots, result=None):
+        """Append one tick_trace() entry and return it.  *result* is an
+        output of the program the tick dispatched at ``t0``: the
+        completion observer fills in ``t_done`` and ``device_s`` when it
+        lands."""
+        entry = {
+            "kind": kind, "t0": t0, "t1": time.monotonic(), "lanes": slots,
+        }
+        with self._cv:
+            entry["n_lanes"] = self._scaler.n_lanes
+            self._tick_log.append(entry)
+        if result is not None:
+            self._observer.watch(
+                result, functools.partial(self._tick_done, entry),
+                t_dispatch_ns=int(t0 * 1e9),
+            )
+        return entry
+
+    def _tick_done(self, entry, t_done_ns, device_ns, _queue_ns):
+        """Observer callback: the tick's device work has completed.  The
+        scheduler's own read-back of a first token may have seen that
+        before the observer's thread was given the interpreter: then the
+        delivery bounds the completion."""
+        t_done, device_s = t_done_ns / 1e9, device_ns / 1e9
+        with self._cv:
+            late_s = max(t_done - entry.get("t_delivered", t_done), 0.0)
+            entry["t_done"] = t_done - late_s
+            entry["device_s"] = device_s = max(device_s - late_s, 0.0)
+            self._device_s += device_s
 
     def _drain_one(self, ptick=NULL_TICK):
-        tokens_dev, snapshot = self._inflight.popleft()
+        tokens_dev, snapshot, first = self._inflight.popleft()
         with ptick.phase("device_wait"):
             # the host-side materialization is where async dispatch pays:
             # this np.asarray blocks until the tick's device work lands
@@ -1741,6 +1796,10 @@ class LmEngine:
                 token = (
                     int(vals[slot_idx]) if vals.size > 1 else int(vals[0])
                 )
+                if first is not None:
+                    # stamped before the put: the consumer may run, and
+                    # send the token off, before this thread runs again
+                    first["t_delivered"] = time.monotonic()
                 lane.queue.put(token)
                 lane.produced += 1
                 lane.tokens.append(token)  # recompute-replay history
@@ -1756,8 +1815,7 @@ class LmEngine:
                 )
                 if done:
                     self._retire_lane_locked(lane)
-        if delivered:
-            ptick.compute("lm", delivered, self._flops_per_token)
+        self._account(ptick, delivered)
 
     # -- preemption / swap -------------------------------------------------
 

@@ -440,6 +440,14 @@ class ModelStats:
                 self.queue_us.observe(q_ns / 1000)
             self.last_inference_ms = int(time.time() * 1000)
 
+    def record_device_time(self, infer_ns):
+        """The device time of one execution whose counts are already in:
+        a TPU-shm execution is counted when it is acknowledged, at
+        dispatch, and its compute time is known only when the completion
+        observer (serve/_completion.py) sees its results."""
+        with self.lock:
+            self.compute_infer_ns += infer_ns
+
     def record_request_success(self, total_ns):
         """One successful request served through the batched path.  Failures
         on that path are counted by ``record(False, ...)`` in execute()'s
@@ -1725,6 +1733,9 @@ class InferenceEngine:
             # the observer at device completion (async results) or right
             # after rendering (host results already materialized) — duty
             # cycle measures device occupancy, not dispatch-issue time.
+            # compute_infer_ns and the profiler's compute phase close there
+            # too: the device time the observer measured for an async
+            # result, the run time of model.fn for a host one.
             self.busy.begin()
             watched = False
             try:
@@ -1735,33 +1746,42 @@ class InferenceEngine:
                 rendered = self._render_response(
                     model, model_version, request, result
                 )
+                t1 = time.monotonic_ns()
+                batch = _batch_of(model, request)
+
+                def done(_t_done, device_ns, _queue_ns):
+                    self.busy.end()
+                    infer_ns = (
+                        t_inf1 - t_in1 if device_ns is None else device_ns
+                    )
+                    stats.record_device_time(infer_ns)
+                    # pre-measured splits (same timestamps stats used) fold
+                    # into the continuous profiler without another clock
+                    self.prof.commit(
+                        "unary", (t_in1 - t0 + infer_ns + t1 - t_inf1) / 1e9,
+                        phases={
+                            "host": (t_in1 - t_in0) / 1e9,
+                            "compute": infer_ns / 1e9,
+                            "render": (t1 - t_inf1) / 1e9,
+                        },
+                        model=model.name,
+                        items=batch,
+                        flops_per_item=model.flops_per_item,
+                    )
+
                 self._busy_observer.watch(
-                    result, self.busy.end,
+                    result, done,
                     on_error=lambda exc: stats.record_device_failure(),
+                    t_dispatch_ns=t_in1,
                 )
                 watched = True
             finally:
                 if not watched:
                     self.busy.end()
-            t1 = time.monotonic_ns()
             if trace is not None:
                 trace.event("COMPUTE_END")
             stats.record(
-                True, t1 - t0, t_inf1 - t_in1, t_in1 - t_in0, t1 - t_inf1,
-                batch=_batch_of(model, request),
-            )
-            # pre-measured splits (same timestamps stats used) fold into
-            # the continuous profiler without touching another clock
-            self.prof.commit(
-                "unary", (t1 - t0) / 1e9,
-                phases={
-                    "host": (t_in1 - t_in0) / 1e9,
-                    "compute": (t_inf1 - t_in1) / 1e9,
-                    "render": (t1 - t_inf1) / 1e9,
-                },
-                model=model.name,
-                items=_batch_of(model, request),
-                flops_per_item=model.flops_per_item,
+                True, t1 - t0, 0, t_in1 - t_in0, t1 - t_inf1, batch=batch,
             )
             if context is not None:
                 # applied-step accounting + durable snapshot replication

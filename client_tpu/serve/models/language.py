@@ -237,12 +237,10 @@ def lm_streaming_batched_model(name="lm_streaming_batched", runner=None,
     def bind(engine):
         """Late-bind the owning InferenceEngine's observability + QoS
         (add_model calls this): lane/KV/prefix gauges land in the
-        server's /metrics registry, per-tick spans ride its tracer, and
-        tenant decode-lane quotas + preemption priority classes come
+        server's /metrics registry, and tenant decode-lane quotas + preemption priority classes come
         from the front door's TenantQoS."""
         sched = batched.scheduler
         sched.set_registry(engine.metrics)
-        sched.tracer = engine.tracer
         sched.flight = getattr(engine, "flight", None)
         if getattr(engine, "prof", None) is not None:
             # the scheduler's per-tick profiler joins the server's so

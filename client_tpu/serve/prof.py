@@ -43,6 +43,7 @@ headline path.
 """
 
 import collections
+import sys
 import threading
 import time
 
@@ -54,6 +55,7 @@ __all__ = [
     "ATTRIBUTION_GROUPS",
     "device_peak_tflops",
     "attribute_phases",
+    "annotation",
 ]
 
 # Published dense bf16 peak per chip in TFLOP/s, keyed by the exact
@@ -66,20 +68,23 @@ _TPU_PEAK_BF16_TFLOPS = {
     "TPU v5 lite": 197.0,
 }
 
-# Phase -> attribution bucket for the dispatch/compute/host/idle split
-# (bench's prof block, profview's summary row).  On the CPU test
-# platform jitted "dispatch" blocks until the computation finishes, so
-# the dispatch-site phases are device work, not launch overhead — they
-# group under compute; the device_wait phase (readback/np.asarray) is
-# where async TPU dispatch actually pays.
+# Phase -> attribution bucket for the compute/dispatch/device_wait/host/
+# idle split (bench's prof block, profview's summary row).  ``compute``
+# is device time as the completion observer measured it
+# (serve/_completion.py), or a host model's own run time; the
+# dispatch-site phases are launch overhead; ``device_wait`` (the host
+# blocked on a read-back) and ``device_queue`` (a dispatched step behind
+# the steps before it) are waits, and no one's work.
 ATTRIBUTION_GROUPS = {
-    "compute": ("compute", "decode_dispatch", "prefill_dispatch",
-                "verify_dispatch", "device_wait"),
-    "dispatch": ("schedule", "preempt", "resume", "execute"),
+    "compute": ("compute",),
+    "dispatch": ("schedule", "preempt", "resume", "execute",
+                 "decode_dispatch", "prefill_dispatch", "verify_dispatch"),
+    "device_wait": ("device_wait", "device_queue"),
     "host": ("host", "render", "deliver", "sample", "serialize",
              "deserialize", "send", "wait", "draft"),
     "idle": ("idle",),
 }
+
 
 def device_peak_tflops():
     """``(peak_tflops, device_kind)`` of the accelerator this process
@@ -107,15 +112,28 @@ def device_peak_tflops():
     return _TPU_PEAK_BF16_TFLOPS[kind], kind
 
 
+def annotation(name):
+    """A ``jax.profiler.TraceAnnotation`` named *name*: a span on the
+    host plane of the profiler's trace, on the same clock as the
+    device's events, so that an idle gap of the device can be named by
+    the phase the host was in.  Inert while no profiler session runs,
+    and a no-op in a process that has not imported jax (asking must not
+    be what imports it) or in which another thread is still importing
+    it."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    make = getattr(profiler, "TraceAnnotation", None)
+    return _NULL_PHASE if make is None else make(name)
+
+
 def attribute_phases(phases, wall_s=None):
-    """Fold a {phase: seconds} dict into the dispatch/compute/host/idle
-    share split (percentages summing to ~100).
+    """Fold a {phase: seconds} dict into the compute/dispatch/
+    device_wait/host/idle share split (percentages summing to ~100).
 
     *wall_s* is the window the phases were measured over; time it
     covers beyond the summed phases counts as idle.  Concurrent
     execution can sum past the wall — shares then normalize over the
     summed total (idle 0)."""
-    groups = {"compute": 0.0, "dispatch": 0.0, "host": 0.0, "idle": 0.0}
+    groups = dict.fromkeys(ATTRIBUTION_GROUPS, 0.0)
     for name, seconds in (phases or {}).items():
         for group, members in ATTRIBUTION_GROUPS.items():
             if name in members:
@@ -137,20 +155,25 @@ def attribute_phases(phases, wall_s=None):
 
 class _Phase:
     """One ``with tick.phase(name):`` bracket — accumulates elapsed
-    seconds into the owning tick's phase dict on exit."""
+    seconds into the owning tick's phase dict on exit, and spans the
+    same interval on the profiler's trace as ``<profiler>.<phase>``
+    (:func:`annotation`)."""
 
-    __slots__ = ("_tick", "_name", "_t0")
+    __slots__ = ("_tick", "_name", "_t0", "_span")
 
     def __init__(self, tick, name):
         self._tick = tick
         self._name = name
 
     def __enter__(self):
+        self._span = annotation(f"{self._tick.prof.name}.{self._name}")
+        self._span.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         self._tick.add(self._name, time.perf_counter() - self._t0)
+        self._span.__exit__(*exc)
         return False
 
 
@@ -173,7 +196,7 @@ class _Tick:
     ``with``)."""
 
     __slots__ = ("prof", "kind", "t0", "phases", "meta", "_items",
-                 "_flops", "_model")
+                 "_flops", "_model", "_device_s")
 
     def __init__(self, prof, kind):
         self.prof = prof
@@ -183,6 +206,7 @@ class _Tick:
         self._items = 0
         self._flops = 0.0
         self._model = None
+        self._device_s = None
         self.t0 = time.perf_counter()
 
     def phase(self, name):
@@ -199,13 +223,18 @@ class _Tick:
         path reuses its existing monotonic timestamps this way)."""
         self.phases[name] = self.phases.get(name, 0.0) + seconds
 
-    def compute(self, model, items, flops_per_item=None):
-        """Count device work delivered this tick (MFU numerator): the
-        device seconds come from the tick's own compute-group phases."""
+    def compute(self, model, items, flops_per_item=None, device_s=None):
+        """Count device work delivered this tick (MFU numerator).  The
+        device seconds are the tick's ``compute`` phase, unless the
+        caller has them from the completion observer and passes
+        *device_s*: the LM scheduler's passes overlap the device's work,
+        so there device time is no phase of the pass."""
         self._model = model
         self._items += int(items)
         if flops_per_item:
             self._flops += float(flops_per_item) * int(items)
+        if device_s is not None:
+            self._device_s = (self._device_s or 0.0) + device_s
 
     def note(self, **meta):
         if self.meta is None:
@@ -238,7 +267,7 @@ class _NullTick:
     def add(self, name, seconds):
         pass
 
-    def compute(self, model, items, flops_per_item=None):
+    def compute(self, model, items, flops_per_item=None, device_s=None):
         pass
 
     def note(self, **meta):
@@ -255,9 +284,6 @@ class _NullTick:
 
 
 NULL_TICK = _NullTick()
-
-# compute-group phase names (device seconds of one tick) — derived once
-_DEVICE_PHASES = frozenset(ATTRIBUTION_GROUPS["compute"])
 
 
 @witness_shared("_lock")
@@ -349,23 +375,24 @@ class PhaseProfiler:
             items=tick._items,
             flops=tick._flops,
             meta=tick.meta,
+            device_s=tick._device_s,
         )
 
     def commit(self, kind, dur_s, phases=None, model=None, items=0,
-               flops=0.0, flops_per_item=None, meta=None):
+               flops=0.0, flops_per_item=None, meta=None, device_s=None):
         """Fold one pre-measured tick into the ring and rollup state —
         the zero-extra-clock path the unary engine and frontends use.
         ``flops_per_item`` is a convenience for callers that count items
-        but carry per-item FLOP figures."""
+        but carry per-item FLOP figures.  *device_s* is the tick's
+        device time where it is no phase of the tick (see
+        ``_Tick.compute``); left out, it is the ``compute`` phase."""
         if not self._armed:
             return
         phases = phases or {}
         if flops_per_item and items:
             flops = float(flops) + float(flops_per_item) * int(items)
-        device_s = 0.0
-        for name, seconds in phases.items():
-            if name in _DEVICE_PHASES:
-                device_s += seconds
+        if device_s is None:
+            device_s = phases.get("compute", 0.0)
         record = {
             "ts": time.time(),
             "kind": str(kind),
@@ -376,6 +403,8 @@ class PhaseProfiler:
             record["model"] = str(model)
         if items:
             record["items"] = int(items)
+        if device_s:
+            record["device_s"] = device_s
         if meta:
             record.update(meta)
         flush = None
@@ -503,7 +532,7 @@ class PhaseProfiler:
         default window; 0/negative = everything in the ring); *kinds*
         optionally filters tick kinds.  Returns phase totals with
         percentages, tick counts by kind, per-model device share / MFU,
-        and the dispatch/compute/host/idle split."""
+        and the compute/dispatch/device_wait/host/idle split."""
         if window_s is None:
             window_s = self.window_s
         cutoff = time.time() - window_s if window_s > 0 else None
@@ -525,15 +554,12 @@ class PhaseProfiler:
             kind_counts[record["kind"]] = (
                 kind_counts.get(record["kind"], 0) + n
             )
-            device_s = 0.0
             for name, seconds in record["phases"].items():
                 phases[name] = phases.get(name, 0.0) + seconds
-                if name in _DEVICE_PHASES:
-                    device_s += seconds
             model = record.get("model")
             if model is not None:
                 entry = models.setdefault(model, [0.0, 0])
-                entry[0] += device_s
+                entry[0] += record.get("device_s", 0.0)
                 entry[1] += record.get("items", 0)
         covered = sum(phases.values())
         phase_rows = {

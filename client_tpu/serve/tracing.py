@@ -196,14 +196,12 @@ class Tracer:
         self._seq = 0
         self._pending_flush = []
         self.completed = collections.deque(maxlen=max_traces)
-        # scheduler tick spans live apart from request traces: they fire
-        # hundreds of times a second and must not evict request spans
-        self._tick_seen = 0
-        self.tick_completed = collections.deque(maxlen=max_traces)
         # fleet peer-RPC child spans (client side of prefix/cache/seq
         # lookups, durability pushes, anti-entropy) and the peer-server
-        # side's serve spans — bounded apart from request spans for the
-        # same reason as ticks
+        # side's serve spans — bounded apart from request spans, which a
+        # background push must not evict, and subsampled on trace_rate
+        # with a counter of their own
+        self._peer_seen = 0
         self.peer_completed = collections.deque(maxlen=max_traces)
         # completion hook (the engine points it at the flight recorder so
         # every finished span lands in the postmortem ring even when no
@@ -263,7 +261,7 @@ class Tracer:
         self._complete_into(trace, self.completed)
 
     def _complete_into(self, trace, store):
-        """Shared completion tail for request and tick spans: append to
+        """Shared completion tail for request and peer spans: append to
         *store* and batch-flush to the trace file per log_frequency."""
         trace_file = self._settings.get("trace_file") or ""
         log_frequency = max(
@@ -285,39 +283,6 @@ class Tracer:
             except Exception:
                 pass  # observability must never fail the request path
 
-    def tick_span(self, kind, t0, t1):
-        """One continuous-batching scheduler tick as a completed COMPUTE
-        span under the synthetic model name ``__lm_<kind>__`` (kinds:
-        ``decode``, ``prefill_chunk``).  ``t0``/``t1`` are monotonic
-        seconds; the span is stamped onto the wall clock ending now, so
-        tick spans interleave with request spans in the exported trace
-        file — the per-tick jitter/fairness evidence the LM engine's
-        head-of-line and starvation proofs read.
-
-        Ticks subsample on ``trace_rate`` with their OWN counter and land
-        in ``tick_completed``: decode ticks fire hundreds of times per
-        second, so sharing the request path's ``trace_count`` budget or
-        its bounded ``completed`` deque would exhaust the budget (and
-        evict every real request trace) within seconds."""
-        if not self.enabled():
-            return
-        rate = max(self._int_setting(self._settings, "trace_rate", 1), 1)
-        with self._lock:
-            seen = self._tick_seen
-            self._tick_seen += 1
-            if seen % rate:
-                return
-            self._seq += 1
-            seq = self._seq
-        span = RequestTrace(
-            gen_trace_id(), gen_span_id(),
-            model_name=f"__lm_{kind}__", seq=seq,
-        )
-        now = time.time_ns()
-        span.event("COMPUTE_START", now - int((t1 - t0) * 1e9))
-        span.event("COMPUTE_END", now)
-        self._complete_into(span, self.tick_completed)
-
     def _span_seq(self):
         with self._lock:
             self._seq += 1
@@ -332,10 +297,10 @@ class Tracer:
         thread's active request trace, so a peer fetch shows inside the
         originating request's timeline.  Off-request callers (the
         anti-entropy thread) get a standalone span with its own trace id,
-        subsampled on ``trace_rate`` with the tick counter so background
-        pushes never drain the request budget.  Yields the span (or None
-        when nothing records); callers stamp result tags onto
-        ``span.tags`` before the block exits."""
+        subsampled on ``trace_rate`` with a counter of its own so
+        background pushes never drain the request budget.  Yields the
+        span (or None when nothing records); callers stamp result tags
+        onto ``span.tags`` before the block exits."""
         parent = current_trace()
         if parent is not None:
             span = RequestTrace(
@@ -349,8 +314,8 @@ class Tracer:
                 self._int_setting(self._settings, "trace_rate", 1), 1
             )
             with self._lock:
-                seen = self._tick_seen
-                self._tick_seen += 1
+                seen = self._peer_seen
+                self._peer_seen += 1
             if seen % rate:
                 span = None
             else:

@@ -91,10 +91,6 @@ def _is_peer(record):
     return str(record.get("model_name", "")).startswith("__peer_")
 
 
-def _is_tick(record):
-    return str(record.get("model_name", "")).startswith("__lm_")
-
-
 def join_traces(records):
     """Group span records by trace id -> ``{trace_id: [records]}`` with
     each trace's records sorted by first timestamp.  Records with no
@@ -157,8 +153,6 @@ def critical_path(spans):
                 or _interval(record, "COMPUTE_START", "COMPUTE_END")
             )
             continue
-        if _is_tick(record):
-            continue  # scheduler ticks are engine-wide, not per-request
         # server request span
         queue_ns += _interval(record, "QUEUE_START", "QUEUE_END")
         compute_ns += _interval(record, "COMPUTE_START", "COMPUTE_END")
@@ -215,7 +209,7 @@ def trace_summary(trace_id, spans):
     models = sorted({
         str(r.get("model_name"))
         for r in spans
-        if r.get("model_name") and not _is_peer(r) and not _is_tick(r)
+        if r.get("model_name") and not _is_peer(r)
     })
     sources = sorted({str(r.get("source", "?")) for r in spans})
     return {

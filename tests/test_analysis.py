@@ -1333,7 +1333,7 @@ def test_witness_installed_scopes_to_client_tpu():
 
         obs = CompletionObserver()
         ran = []
-        obs.watch({}, lambda: ran.append(1))  # host result: inline
+        obs.watch({}, lambda *instant: ran.append(1))  # host: inline
         obs.close()
     assert ran == [1]
     w.assert_acyclic()

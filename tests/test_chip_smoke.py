@@ -74,6 +74,13 @@ def test_vision_phase_runs_tpu_shm_fused_and_every_transport(served):
     assert facts["server_statistics"]["executions"] < 8  # a fused batch ran
     assert "JAX backends opened: [[], []]" in facts["load_workers"]
     assert facts["first_s"] > 0
+    # the device-time checks ran: the program's time for a step and the
+    # blocked step beside it (their agreement is asserted on the chip),
+    # and no MFU off the TPU
+    timed = facts["device_time"]
+    assert timed["compute_infer_ms_a_step"] > 0
+    assert timed["blocked_step_ms"] > 0 and timed["device_s"] > 0
+    assert timed["mfu_pct"] is None
 
 
 def test_perf_phase_reports_throughput_and_names_the_device(served):

@@ -448,10 +448,10 @@ def test_device_failure_after_ack_reaches_log_and_stats(caplog):
 
     obs = CompletionObserver(name="batcher-m-watch")
     with caplog.at_level("ERROR", logger="client_tpu.serve._completion"):
-        obs.watch(Result(), lambda: finish("ok"),
+        obs.watch(Result(), lambda *instant: finish("ok"),
                   on_error=lambda exc: stats_ok.record_device_failure())
         obs.watch(Result(RuntimeError("HBM fell over")),
-                  lambda: finish("bad"),
+                  lambda *instant: finish("bad"),
                   on_error=lambda exc: stats_bad.record_device_failure(3))
         assert both.wait(timeout=10)
         obs.close()

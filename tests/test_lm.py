@@ -859,33 +859,23 @@ def test_parked_stream_migrates_through_fleet_tier(params):
 
 # -- engine metrics / spans ------------------------------------------------
 
-def test_engine_metrics_and_tick_spans(params):
-    from client_tpu.serve.tracing import Tracer
-
+def test_engine_metrics_and_tick_kinds(params):
     reg = Registry()
-    settings = {"trace_level": ["TIMESTAMPS"], "trace_rate": "1",
-                "trace_count": "1", "trace_file": "", "log_frequency": "0"}
-    tracer = Tracer(settings)
     eng = LmEngine(params, CFG, max_slots=2, lane_counts=(2,),
                    block_size=8, prefill_chunk=16, min_bucket=4,
-                   registry=reg, tracer=tracer)
+                   registry=reg)
     try:
         _collect(eng.submit([1, 2, 3], 6)[0])
         assert reg.get("ctpu_lm_tokens_total") == 6
         assert reg.get("ctpu_lm_prefill_chunks_total") >= 1
         assert reg.get("ctpu_lm_lanes") == 2
-        kinds = {t.model_name for t in tracer.tick_completed}
-        assert "__lm_decode__" in kinds
-        assert "__lm_prefill_chunk__" in kinds
-        for t in tracer.tick_completed:
-            names = [e["name"] for e in t.timestamps]
-            assert names == ["COMPUTE_START", "COMPUTE_END"]
-        # tick spans never touch the request-trace budget or deque: a
-        # decode loop must not starve/evict real request traces
-        assert not any(
-            t.model_name.startswith("__lm_") for t in tracer.completed
-        )
-        assert tracer.sample(model_name="req") is not None
+        ticks = eng.tick_trace()
+        kinds = {t["kind"] for t in ticks}
+        assert "decode" in kinds
+        assert "prefill_chunk" in kinds
+        for t in ticks:
+            assert t["t0"] <= t["t1"]
+            assert set(t) >= {"kind", "t0", "t1", "lanes", "n_lanes"}
     finally:
         eng.close()
 
@@ -1076,21 +1066,16 @@ def test_spec_adversarial_drafter_backs_off_and_never_slower(params):
 
 
 def test_spec_tick_kinds_metrics_and_gauge(params):
-    from client_tpu.serve.tracing import Tracer
-
     reg = Registry()
-    settings = {"trace_level": ["TIMESTAMPS"], "trace_rate": "1",
-                "trace_count": "1", "trace_file": "", "log_frequency": "0"}
-    tracer = Tracer(settings)
     eng = LmEngine(params, CFG, max_slots=2, lane_counts=(2,),
                    block_size=8, prefill_chunk=16, min_bucket=4,
-                   speculative={"k": 4}, registry=reg, tracer=tracer)
+                   speculative={"k": 4}, registry=reg)
     try:
         _collect(eng.submit([5, 6] * 6, 24)[0])
-        kinds = {t.model_name for t in tracer.tick_completed}
-        assert "__lm_verify__" in kinds
-        assert "__lm_draft__" in kinds
-        assert "__lm_prefill_chunk__" in kinds
+        kinds = {t["kind"] for t in eng.tick_trace()}
+        assert "verify" in kinds
+        assert "draft" in kinds
+        assert "prefill_chunk" in kinds
         proposed = reg.get("ctpu_lm_spec_proposed_tokens_total")
         accepted = reg.get("ctpu_lm_spec_accepted_tokens_total") or 0
         rejected = reg.get("ctpu_lm_spec_rejected_tokens_total") or 0

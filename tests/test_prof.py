@@ -4,7 +4,8 @@ Covers:
 - ring semantics: bounded capacity, idle-run coalescing, disarmed
   no-op handles, and thread-safety under the Eraser race witness,
 - reconciliation: bracketed phase sums stay within the tick wall time
-  and the dispatch/compute/host/idle attribution sums to ~100,
+  and the compute/dispatch/device_wait/host/idle attribution sums to
+  ~100,
 - the ctpu_prof_* series reaching a Registry through the batched
   flush path (and the metrics-manager prefix whitelist),
 - the server surfaces: GET /v2/debug/prof, prof_tick records in
@@ -133,12 +134,17 @@ class TestReconciliation:
         assert roll["covered_s"] >= 0.8 * roll["wall_s"]
 
     def test_attribution_sums_to_100(self):
+        # launching a program is dispatch and blocking on its result a
+        # wait: only the device time the completion observer measured
+        # (or a host model's own run time) is compute
         split = attribute_phases(
-            {"compute": 0.6, "schedule": 0.1, "host": 0.2},
+            {"compute": 0.4, "schedule": 0.05, "decode_dispatch": 0.05,
+             "device_wait": 0.15, "device_queue": 0.05, "host": 0.2},
             wall_s=1.0,  # 0.1s uncovered -> idle
         )
-        assert split["compute_pct"] == pytest.approx(60.0, abs=0.1)
+        assert split["compute_pct"] == pytest.approx(40.0, abs=0.1)
         assert split["dispatch_pct"] == pytest.approx(10.0, abs=0.1)
+        assert split["device_wait_pct"] == pytest.approx(20.0, abs=0.1)
         assert split["host_pct"] == pytest.approx(20.0, abs=0.1)
         assert split["idle_pct"] == pytest.approx(10.0, abs=0.1)
         assert sum(split.values()) == pytest.approx(100.0, abs=0.5)

@@ -3,7 +3,7 @@ the SLO watchdog (the observability PR's new surfaces).
 
 Covers:
 - join_traces/critical_path on synthetic multi-source records (client,
-  server, peer, tick) — attribution math pinned against hand-computed
+  server, peer) — attribution math pinned against hand-computed
   figures, overlap-safe server merging;
 - the CLI: text timelines, ``--format json`` one-object-per-trace,
   ``--trace`` selection, bad-file exit code;
