@@ -135,9 +135,11 @@ def _rope(x, positions, theta):
 
 
 def _repeat_kv(x, n_rep):
+    """[B,T,n_kv,hd] -> [B,T,n_kv*n_rep,hd] for the full-sequence kernels,
+    which take one K/V head a query head.  ``paged_attention`` contracts a
+    KV-head group at a time, and calls it for wide prefill chunks only."""
     if n_rep == 1:
         return x
-    b, t, h, d = x.shape
     return jnp.repeat(x, n_rep, axis=2)
 
 
@@ -367,22 +369,57 @@ def paged_attention(q, pool_k, pool_v, tables, pos, cfg, block_size):
     ``init_cache`` [B, max_seq, ...] layout pins max_seq rows per lane
     forever; the paged layout pools HBM across lanes and a lane holds
     only ceil((prompt+budget)/block_size) blocks.
+
+    The queries of a KV-head group are contracted against that group's
+    gathered keys and values directly: ``q`` as [B,T,n_kv,n_rep,hd]
+    against K [B,S,n_kv,hd] (S = table_width * block_size) is ONE
+    ``dot_general`` with batch dimensions (lane, KV head), n_rep x T
+    query rows a group, scores [B,n_kv,n_rep,T,S] accumulated in
+    float32 from operands at their stored width; mask, scale and
+    softmax stay float32; the weighted sum is the mirror contraction
+    over V [B,S,n_kv,hd] with the probabilities cast to V's type.  So
+    the gathered K and V are read once each as stored: no copy at
+    n_heads, none in float32 (at Mistral-7B widths and 16 lanes those
+    were 1 GB written a layer a tick: PERF.md section 6, PR 28).  With
+    n_rep 1 (MHA) a group is one head and the same code runs.
+
+    A chunk of 2 * hd query rows or more (a prefill chunk, never a
+    decode or verify tick) takes the per-head form over ``_repeat_kv``
+    instead.  There the float32 scores [B,H,T,S] outweigh the repeated
+    K and V (hd : 2T in bytes), and XLA fuses that form's scores, row
+    maximum, exponential and row sum into one pass over them, where the
+    grouped form's get a pass more (a 512-wide chunk on the v5e: 29.8
+    ms against 33.1, PERF.md section 6, PR 28).  ``_repeat_kv`` is
+    otherwise for the full-sequence path (``_attention_block``) and the
+    contiguous-cache ``decode_step``: tests/test_paged_attention.py
+    holds the decode tick's program to that.
     """
-    b = q.shape[0]
+    b, t = q.shape[:2]
     hd = cfg.head_dim
-    n_rep = cfg.n_heads // cfg.n_kv_heads
+    n_kv = cfg.n_kv_heads
+    n_rep = cfg.n_heads // n_kv
     s_len = tables.shape[-1] * block_size
-    kk = pool_k[tables].reshape(b, s_len, cfg.n_kv_heads, hd)
-    vv = pool_v[tables].reshape(b, s_len, cfg.n_kv_heads, hd)
-    kk = _repeat_kv(kk, n_rep)
-    vv = _repeat_kv(vv, n_rep)
-    s = jnp.einsum(
-        "bqhd,bkhd->bhqk", q, kk, preferred_element_type=jnp.float32
-    ) * (hd ** -0.5)
+    kk = pool_k[tables].reshape(b, s_len, n_kv, hd)
+    vv = pool_v[tables].reshape(b, s_len, n_kv, hd)
     valid = jnp.arange(s_len)[None, None, :] <= pos[:, :, None]  # [B,T,S]
-    s = jnp.where(valid[:, None], s, -1e30)
+    if t >= 2 * hd:
+        # spelled out, not folded into the grouped einsums as n_rep 1: with
+        # their size-1 axes XLA no longer fuses this softmax into one pass
+        kk, vv = _repeat_kv(kk, n_rep), _repeat_kv(vv, n_rep)
+        s = jnp.einsum(
+            "bqhd,bkhd->bhqk", q, kk, preferred_element_type=jnp.float32
+        ) * (hd ** -0.5)
+        s = jnp.where(valid[:, None], s, -1e30)
+        p = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p.astype(vv.dtype), vv)
+    qg = q.reshape(b, t, n_kv, n_rep, hd)
+    s = jnp.einsum(
+        "btgrd,bsgd->bgrts", qg, kk, preferred_element_type=jnp.float32
+    ) * (hd ** -0.5)
+    s = jnp.where(valid[:, None, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(vv.dtype), vv)
+    out = jnp.einsum("bgrts,bsgd->btgrd", p.astype(vv.dtype), vv)
+    return out.reshape(b, t, cfg.n_heads, hd)
 
 
 def lm_flops_per_token(cfg, context=0):
