@@ -66,6 +66,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from client_tpu.ops.sampling import TOPK_CAP as _TOPK_CAP
+from client_tpu.ops.sampling import select_token as _select_token
 from client_tpu.serve._completion import CompletionObserver
 from client_tpu.serve.lm.kv import KvBlockPool
 from client_tpu.serve.lm.policy import (
@@ -79,6 +81,7 @@ from client_tpu.serve.lm.policy import (
 from client_tpu.serve.lm.prefix import PrefixCache
 from client_tpu.serve.lm.spec import LaneSpec, SpecConfig
 from client_tpu.serve.metrics import FLEET_HELP, LM_PREFIX_HELP, LM_SPEC_HELP
+from client_tpu.serve.models import sambay
 from client_tpu.serve.models.transformer import (
     _ffn_block,
     _mm,
@@ -87,7 +90,7 @@ from client_tpu.serve.models.transformer import (
     lm_flops_per_token,
     paged_attention,
 )
-from client_tpu.serve.prof import NULL_TICK, PhaseProfiler
+from client_tpu.serve.prof import NULL_TICK, PhaseProfiler, annotation
 
 # sentinel object closing a stream's token queue
 _CLOSE = object()
@@ -97,29 +100,10 @@ _CLOSE = object()
 # reservation and closes the queue
 _CANCELLED = object()
 
-# static cap for the per-lane top-k filter (per-lane k is dynamic below it)
-_TOPK_CAP = 64
-
 _LANE_HELP = {
     "ctpu_lm_lanes": "Configured decode lane count (autoscaled)",
     "ctpu_lm_active_lanes": "Decode lanes currently streaming",
 }
-
-
-def _select_token(logits, key, temperature, top_k):
-    """One lane's token choice on device: argmax when temperature == 0,
-    else temperature softmax sampling over the top-k filtered logits
-    (top_k <= 0 = unfiltered)."""
-    greedy = jnp.argmax(logits)
-    kmax = min(_TOPK_CAP, logits.shape[-1])
-    vals = lax.top_k(logits, kmax)[0]
-    thresh = vals[jnp.clip(top_k - 1, 0, kmax - 1)]
-    keep = (top_k <= 0) | (logits >= thresh)
-    filtered = jnp.where(keep, logits, -jnp.inf)
-    sampled = jax.random.categorical(
-        key, filtered / jnp.maximum(temperature, 1e-6)
-    )
-    return jnp.where(temperature > 0.0, sampled, greedy).astype(jnp.int32)
 
 
 def _decode_tick(params, tokens_full, pool_k, pool_v, tables, lens,
@@ -342,6 +326,121 @@ def _adopt(tokens, keys, slot, tok, key):
     return tokens.at[slot].set(tok), keys.at[slot].set(key)
 
 
+class _DecoderPrograms:
+    """What the engine dispatches for a model family, behind one interface:
+    ``prefill`` runs one (1, C) chunk of a lane's prompt, ``tick`` one
+    (n, 1) decode step; both take the ``KvBlockPool`` and leave the arrays
+    their program returned in it.  This one is the decoder of identical
+    layers (``TransformerConfig``): a lane is its blocks, so the lane
+    arguments (``slot``, ``fresh``, ``live``: host values, which only a
+    family that uses them sends to the device) have nothing to act on."""
+
+    # why a lane's cache cannot be rebuilt from its blocks ("" = it can):
+    # the engine switches off what assumes it can
+    recurrent = ""
+
+    def __init__(self, cfg, block_size, donate_pools):
+        self.cfg, self.block_size = cfg, block_size
+        # donate the KV pool buffers (args 2/3 of the programs): the
+        # functional .at[].set update would otherwise materialize a full
+        # copy of every per-layer block pool on EACH dispatch — ~2x the
+        # dominant HBM allocation and a whole-pool copy per token.  The
+        # pool is reassigned from the outputs immediately, so the donated
+        # inputs are never touched again.
+        self.donate = (2, 3) if donate_pools else ()
+        self.flops_per_token = lm_flops_per_token(cfg)
+        self.window = None  # positions a window layer keeps, if any
+        self.prefill_jit = jax.jit(
+            functools.partial(_prefill_chunk, cfg=cfg, block_size=block_size),
+            donate_argnums=self.donate,
+        )
+
+    def prefill(self, params, kv, chunk, table, slot, start, prompt_len,
+                fresh, key, temperature, top_k):
+        tok, kv.pools["k"], kv.pools["v"], key = self.prefill_jit(
+            params, chunk, kv.pools["k"], kv.pools["v"], table, start,
+            prompt_len, key, temperature, top_k,
+        )
+        return tok, key
+
+    def make_tick(self, n):
+        return jax.jit(
+            functools.partial(
+                _decode_tick, cfg=self.cfg, n=n, block_size=self.block_size,
+            ),
+            donate_argnums=self.donate,
+        )
+
+    def tick(self, fn, params, kv, tokens, tables, lens, live, temps, topks,
+             keys):
+        tokens, kv.pools["k"], kv.pools["v"], keys = fn(
+            params, tokens, kv.pools["k"], kv.pools["v"], tables, lens,
+            temps, topks, keys,
+        )
+        return tokens, keys
+
+
+class _SambaYPrograms:
+    """The SambaY family (``models/sambay.py``) behind the interface of
+    ``_DecoderPrograms``: the same two shapes over one paged layer and the
+    lanes' fixed state, which is donated with the pools.  The programs are
+    jitted under their own names."""
+
+    recurrent = (
+        "lanes carry recurrent state (Mamba layers, window rings) that "
+        "K/V blocks alone do not rebuild"
+    )
+
+    def __init__(self, cfg, block_size, donate_pools):
+        self.cfg, self.block_size = cfg, block_size
+        self.donate = (2, 3, 4) if donate_pools else ()
+        self.flops_per_token = sambay.lm_flops_per_token(cfg)
+        self.window = cfg.window
+        self._static = dict(cfg=cfg, block_size=block_size)
+        self.prefill_jit = jax.jit(
+            sambay.sambay_prefill_chunk,
+            static_argnames=("cfg", "block_size"), donate_argnums=self.donate,
+        )
+        self._tick_jit = jax.jit(
+            sambay.sambay_decode_tick,
+            static_argnames=("cfg", "n", "block_size"),
+            donate_argnums=self.donate,
+        )
+
+    def prefill(self, params, kv, chunk, table, slot, start, prompt_len,
+                fresh, key, temperature, top_k):
+        with annotation("lm.sambay_prefill_chunk"):
+            tok, kv.pools["k"], kv.pools["v"], kv.lane_state, key = (
+                self.prefill_jit(
+                    params, chunk, kv.pools["k"], kv.pools["v"],
+                    kv.lane_state, table, jnp.int32(slot), start,
+                    prompt_len, jnp.bool_(fresh), key, temperature, top_k,
+                    **self._static,
+                )
+            )
+        return tok, key
+
+    def make_tick(self, n):
+        return functools.partial(self._tick_jit, n=n, **self._static)
+
+    def tick(self, fn, params, kv, tokens, tables, lens, live, temps, topks,
+             keys):
+        with annotation("lm.sambay_decode_tick"):
+            tokens, kv.pools["k"], kv.pools["v"], kv.lane_state, keys = fn(
+                params, tokens, kv.pools["k"], kv.pools["v"], kv.lane_state,
+                tables, lens, jnp.asarray(live), temps, topks, keys,
+            )
+        return tokens, keys
+
+
+def _programs_for(cfg, block_size):
+    """The family's programs, from the configuration's type alone."""
+    family = (_SambaYPrograms if isinstance(cfg, sambay.SambaYConfig)
+              else _DecoderPrograms)
+    # CPU (the test platform) has no donation support; jit would just warn
+    return family(cfg, block_size, jax.default_backend() != "cpu")
+
+
 class _Lane:
     __slots__ = ("gen", "active", "queue", "remaining", "produced",
                  "length", "limit", "tenant", "temperature", "top_k",
@@ -539,7 +638,24 @@ class LmEngine:
         # tick — only that thread ever touches it.
         self.prof = PhaseProfiler(name="lm", registry=registry)
         self._ptick = NULL_TICK
-        self._flops_per_token = lm_flops_per_token(cfg)
+        # the family's programs, from the configuration alone.  Where its
+        # lanes carry state that blocks do not rebuild, what assumes a
+        # lane IS its blocks is switched off here, with the reason in the
+        # stats: prefix adoption and fleet export (a block chain without
+        # the state at its end is no cache), the host swap (preemption
+        # falls back to recompute-replay, which rebuilds the state), and
+        # speculative verify (its rewind is a pointer into the lane's
+        # blocks; a recurrent state has none).
+        self._programs = _programs_for(cfg, self.block_size)
+        self._flops_per_token = self._programs.flops_per_token
+        self._recurrent = self._programs.recurrent
+        if self._recurrent and speculative is not None:
+            raise ValueError(
+                "speculative decoding is not available for this model: "
+                + self._recurrent
+            )
+        if self._recurrent:
+            prefix_cache, swap_block_limit = False, 0
 
         # prefix cache + preemption state
         self._prefix_enabled = bool(prefix_cache)
@@ -567,22 +683,7 @@ class LmEngine:
         self.prefix = None
         self._tokens = None
         self._keys = None
-        # donate the KV pool buffers (args 2/3 of both programs): the
-        # functional .at[].set update would otherwise materialize a full
-        # copy of every per-layer block pool on EACH dispatch — ~2x the
-        # dominant HBM allocation and a whole-pool copy per token.  The
-        # caller reassigns self.kv.pools from the outputs immediately, so
-        # the donated inputs are never touched again.  CPU (the test
-        # platform) has no donation support; jit would just warn.
-        self._donate = (
-            (2, 3) if jax.default_backend() != "cpu" else ()
-        )
-        self._prefill = jax.jit(
-            functools.partial(
-                _prefill_chunk, cfg=cfg, block_size=self.block_size
-            ),
-            donate_argnums=self._donate,
-        )
+        self._prefill = self._programs.prefill  # one chunk's dispatch
         self._adopt = jax.jit(_adopt)
         self._tick_jits = {}
 
@@ -603,7 +704,7 @@ class LmEngine:
     def prefill_executables(self):
         """Compiled prefill-chunk executable count (<= len(self.buckets)
         by construction — chunk widths come from the bucket set)."""
-        size = getattr(self._prefill, "_cache_size", None)
+        size = getattr(self._programs.prefill_jit, "_cache_size", None)
         return size() if callable(size) else None
 
     def decode_executables(self):
@@ -628,8 +729,12 @@ class LmEngine:
         return total
 
     def spec_stats(self):
-        """Speculative-decoding counters ({} when speculation is off)."""
+        """Speculative-decoding counters ({} when speculation is off;
+        ``enabled`` False with the ``reason`` for a model that cannot have
+        it)."""
         with self._cv:
+            if self._recurrent:
+                return {"enabled": False, "reason": self._recurrent}
             if self._spec is None:
                 return {}
             prop, acc = self._spec_proposed, self._spec_accepted
@@ -649,27 +754,40 @@ class LmEngine:
         and has neither).  The ``prefill_chunk`` that ends a prompt also
         carries the first token's wait: ``t_submit``, ``t_admit`` and,
         once the token is on its stream's queue, ``t_delivered``.  All on
-        ``time.monotonic()``'s clock."""
+        ``time.monotonic()``'s clock.  Every entry that dispatched device
+        work also counts what the program met, from the engine's own
+        lengths: ``context_tokens`` (the lanes' real lengths, summed: a
+        decode or verify tick's before its write, a chunk's lane after
+        it), ``window_tokens`` for a model with window layers (the sum of
+        ``min(length, window)``), and on a ``prefill_chunk`` its bucket
+        ``width``, the real ``tokens`` in it and its ``start``."""
         with self._cv:
             return [dict(entry) for entry in self._tick_log]
 
     def prefix_stats(self):
         """Prefix-cache counters ({} when the cache is disabled or the
-        engine never started)."""
+        engine never started; ``enabled`` False with the ``reason`` for a
+        model whose lanes cannot adopt blocks)."""
         with self._cv:
+            if self._recurrent:
+                return {"enabled": False, "reason": self._recurrent}
             return {} if self.prefix is None else self.prefix.stats()
 
     def preempt_stats(self):
         """Preemption/swap counters: preemptions, completed resumes with
         their swap-out -> reactivation latencies, streams still parked."""
         with self._cv:
-            return {
+            stats = {
                 "preemptions": self._preemptions,
                 "resumes": len(self._resume_ms),
                 "resume_ms": list(self._resume_ms),
                 "swapped_streams": len(self._swapped),
                 "swapped_blocks": self._swapped_blocks,
             }
+            if self._recurrent:
+                # no host swap: a preempted lane resumes by recompute
+                stats["swap"] = "off, recompute only: " + self._recurrent
+            return stats
 
     def set_registry(self, registry):
         """Late-bind the serving metrics registry (add_model wiring)."""
@@ -693,10 +811,13 @@ class LmEngine:
         """Fleet prefix-tier counters: peer lookups issued at submit and
         KV blocks installed from peers (zeros when no tier is bound)."""
         with self._cv:
-            return {
+            stats = {
                 "remote_lookups": self._fleet_lookups,
                 "remote_blocks": self._fleet_blocks,
             }
+            if self._recurrent:
+                stats["prefix_export"] = "off: " + self._recurrent
+            return stats
 
     def pressure(self):
         """Autoscaling signal: queued submissions + parked (swapped)
@@ -834,6 +955,7 @@ class LmEngine:
             ),
             block_size=self.block_size,
             registry=self.registry,
+            lanes=self.max_slots,
         )
         if self._prefix_enabled:
             self.prefix = PrefixCache(
@@ -1269,18 +1391,21 @@ class LmEngine:
             pad_id=0,
         )
         t0 = time.monotonic()
-        tok, pool_k, pool_v, job.key = self._prefill(
-            self.params, jnp.asarray(chunk), self.kv.pools["k"],
-            self.kv.pools["v"], jnp.asarray(job.table),
-            jnp.int32(start), jnp.int32(handle.prompt_len), job.key,
+        # the job's first chunk starts the lane's fixed state from zero
+        tok, job.key = self._prefill(
+            self.params, self.kv, jnp.asarray(chunk),
+            jnp.asarray(job.table), job.slot, jnp.int32(start),
+            jnp.int32(handle.prompt_len), job.chunk_idx == 0, job.key,
             jnp.float32(handle.temperature), jnp.int32(handle.top_k),
         )
-        self.kv.pools["k"] = pool_k
-        self.kv.pools["v"] = pool_v
         job.chunk_idx += 1
         # every chunk samples a token, which nothing donates onward: the
         # chunk's device work is complete when it is
-        entry = self._log_tick("prefill_chunk", t0, (job.slot,), tok)
+        tokens = min(start + width, handle.prompt_len) - start
+        entry = self._log_tick(
+            "prefill_chunk", t0, (job.slot,), tok, [start + tokens],
+            width=width, tokens=tokens, start=start,
+        )
         if self.registry is not None:
             self.registry.inc(
                 "ctpu_lm_prefill_chunks_total",
@@ -1289,8 +1414,7 @@ class LmEngine:
             # real (non-pad) prompt tokens this chunk computed — the
             # denominator side of the prefix-cache savings accounting
             self.registry.inc(
-                "ctpu_lm_prefill_tokens_total", None,
-                value=min(start + width, handle.prompt_len) - start,
+                "ctpu_lm_prefill_tokens_total", None, value=tokens,
                 help_=LM_PREFIX_HELP["ctpu_lm_prefill_tokens_total"],
             )
         if job.chunk_idx < len(job.plan):
@@ -1307,7 +1431,8 @@ class LmEngine:
             lane.active = True
             lane.table[:] = job.table
             lane.blocks, job.blocks = job.blocks, None
-            if resume is None and self.fleet is not None:
+            if (resume is None and self.fleet is not None
+                    and not self._recurrent):
                 nfull = handle.prompt_len // self.block_size
                 if nfull:
                     export = (
@@ -1447,14 +1572,7 @@ class LmEngine:
         with self._cv:
             fn = self._tick_jits.get(n)
             if fn is None:
-                fn = jax.jit(
-                    functools.partial(
-                        _decode_tick, cfg=self.cfg, n=n,
-                        block_size=self.block_size,
-                    ),
-                    donate_argnums=self._donate,
-                )
-                self._tick_jits[n] = fn
+                fn = self._tick_jits[n] = self._programs.make_tick(n)
         return fn
 
     def _decode_pass(self):
@@ -1499,18 +1617,19 @@ class LmEngine:
                 self._lanes[i].length += 1  # this tick writes position len
             self._lane_gauges_locked(active_count=len(active))
         t0 = time.monotonic()
-        fn = self._tick_for(n)
-        self._tokens, pool_k, pool_v, self._keys = fn(
-            self.params, self._tokens, self.kv.pools["k"],
-            self.kv.pools["v"], jnp.asarray(tables), jnp.asarray(lens),
+        # ``live``: a lane outside the batch keeps its fixed state as it is
+        # (one mid-prefill carries it from chunk to chunk)
+        live = np.array([i in included for i in range(n)])
+        self._tokens, self._keys = self._programs.tick(
+            self._tick_for(n), self.params, self.kv, self._tokens,
+            jnp.asarray(tables), jnp.asarray(lens), live,
             jnp.asarray(temps), jnp.asarray(topks), self._keys,
         )
-        self.kv.pools["k"] = pool_k
-        self.kv.pools["v"] = pool_v
         self._tokens.copy_to_host_async()
         self._inflight.append((self._tokens, tuple(active), None))
         self._log_tick(
-            "decode", t0, tuple(i for i, _ in active), self._tokens
+            "decode", t0, tuple(i for i, _ in active), self._tokens,
+            lens[live],
         )
         return True
 
@@ -1526,7 +1645,7 @@ class LmEngine:
                         _verify_tick, cfg=self.cfg, n=n, width=w,
                         block_size=self.block_size,
                     ),
-                    donate_argnums=self._donate,
+                    donate_argnums=self._programs.donate,
                 )
                 self._verify_jits[(n, w)] = fn
         return fn
@@ -1660,7 +1779,8 @@ class LmEngine:
             )
             self.kv.pools["k"] = pool_k
             self.kv.pools["v"] = pool_v
-        self._log_tick("verify", t0, tuple(i for i, _ in active), out)
+        self._log_tick("verify", t0, tuple(i for i, _ in active), out,
+                       [lens[i] for i, _ in active])
         with ptick.phase("device_wait"):
             vals = np.asarray(out)  # [2, n]: accepted count, correction
         self._deliver_verified(ptick, active, vals, props, counts)
@@ -1749,14 +1869,26 @@ class LmEngine:
             ptick.compute("lm", delivered, self._flops_per_token,
                           device_s=device_s)
 
-    def _log_tick(self, kind, t0, slots, result=None):
+    def _log_tick(self, kind, t0, slots, result=None, lengths=None,
+                  **fields):
         """Append one tick_trace() entry and return it.  *result* is an
         output of the program the tick dispatched at ``t0``: the
         completion observer fills in ``t_done`` and ``device_s`` when it
-        lands."""
+        lands.  *lengths* are the real lengths of the lanes the program
+        worked on (the engine's own count): the entry carries their sum
+        as ``context_tokens`` and, for a model with window layers, what
+        of it a window holds as ``window_tokens``."""
         entry = {
             "kind": kind, "t0": t0, "t1": time.monotonic(), "lanes": slots,
+            **fields,
         }
+        if lengths is not None:
+            entry["context_tokens"] = int(sum(lengths))
+            window = self._programs.window
+            if window is not None:
+                entry["window_tokens"] = int(
+                    sum(min(int(n), window) for n in lengths)
+                )
         with self._cv:
             entry["n_lanes"] = self._scaler.n_lanes
             self._tick_log.append(entry)

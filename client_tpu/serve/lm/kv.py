@@ -19,6 +19,17 @@ sharing safe: an 80%-shared prompt adopts its prefix blocks by reference
 instead of recomputing them, and nobody can free a block out from under
 another holder.
 
+LAYER KINDS: the pool asks the configuration what a lane owns
+(``cfg.state_spec``).  A decoder of identical layers pages every layer;
+a family with other kinds of layer (``models/sambay.py``) says how many
+layers page their K/V (one block table a lane serves them all) and which
+FIXED per-lane state lives beside the pool — a ring of ``window``
+positions for a window-attention layer, a conv tail and an SSM state for
+a recurrent one — so a window layer never pages the whole context and a
+recurrent layer pages nothing.  ``lane_state`` holds those arrays,
+``[lanes, ...]`` each, allocated here with the pool and donated to the
+jitted programs like it.
+
 Static shapes throughout (TPU-first): the device arrays never change
 shape; splice/free are index bookkeeping on the host plus
 scatter/gather through per-lane block tables inside the jitted programs.
@@ -35,6 +46,9 @@ import jax.numpy as jnp
 _KV_HELP = {
     "ctpu_lm_kv_blocks_used": "Paged-KV blocks currently referenced",
     "ctpu_lm_kv_blocks_free": "Paged-KV blocks free in the pool",
+    "ctpu_lm_state_bytes":
+        "Fixed per-lane state allocated beside the paged pool (window "
+        "rings, recurrent state), bytes over all lanes",
 }
 
 
@@ -43,23 +57,35 @@ class KvBlockPool:
 
     ``n_blocks`` counts usable blocks; one extra trash block (index 0) is
     allocated on top, so the device arrays hold ``n_blocks + 1`` blocks.
+    ``lanes`` sizes the fixed per-lane state of configurations that have
+    any (``lane_state``; empty for a decoder of identical layers).
     """
 
     TRASH = 0
 
-    def __init__(self, cfg, n_blocks, block_size, registry=None):
+    def __init__(self, cfg, n_blocks, block_size, registry=None, lanes=0):
         if block_size <= 0 or n_blocks <= 0:
             raise ValueError("block_size and n_blocks must be positive")
         self.cfg = cfg
         self.block_size = int(block_size)
         self.n_blocks = int(n_blocks)
         self.registry = registry
-        shape = (self.n_blocks + 1, self.block_size,
-                 cfg.n_kv_heads, cfg.head_dim)
+        paged_layers, block, lane_spec = cfg.state_spec
+        shape = (self.n_blocks + 1,) + tuple(
+            self.block_size if d is None else d for d in block)
         self.pools = {
-            "k": [jnp.zeros(shape, cfg.jdtype) for _ in range(cfg.n_layers)],
-            "v": [jnp.zeros(shape, cfg.jdtype) for _ in range(cfg.n_layers)],
+            "k": [jnp.zeros(shape, cfg.jdtype) for _ in range(paged_layers)],
+            "v": [jnp.zeros(shape, cfg.jdtype) for _ in range(paged_layers)],
         }
+        self.lane_state = {
+            name: [jnp.zeros((int(lanes),) + tuple(shp), dtype)
+                   for shp, dtype in layers]
+            for name, layers in lane_spec.items()
+        }
+        self.state_bytes = sum(
+            a.size * a.dtype.itemsize
+            for layers in self.lane_state.values() for a in layers
+        )
         self._lock = threading.Lock()
         self._free = list(range(1, self.n_blocks + 1))
         self._refs = {}  # block -> live reference count (absent = free)
@@ -139,6 +165,8 @@ class KvBlockPool:
                           help_=_KV_HELP["ctpu_lm_kv_blocks_used"])
         self.registry.set("ctpu_lm_kv_blocks_free", None, free,
                           help_=_KV_HELP["ctpu_lm_kv_blocks_free"])
+        self.registry.set("ctpu_lm_state_bytes", None, self.state_bytes,
+                          help_=_KV_HELP["ctpu_lm_state_bytes"])
 
     def set_registry(self, registry):
         """Late-bind a metrics registry (the engine learns its server's

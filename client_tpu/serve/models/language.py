@@ -18,6 +18,7 @@ import numpy as np
 import jax
 
 from client_tpu.serve.model_runtime import Model, TensorSpec
+from client_tpu.serve.models import sambay
 from client_tpu.serve.models import transformer as tfm
 from client_tpu.utils import InferenceServerException
 
@@ -97,13 +98,25 @@ def detokenizer_model(name="detokenizer"):
 
 
 class _LmRunner:
-    """Owns the transformer params + jitted decode programs."""
+    """Owns the params of either family (a ``TransformerConfig`` or a
+    ``sambay.SambaYConfig``) + the serial path's jitted decode programs.
+    The SambaY family is served by the continuous-batching engine alone
+    (``lm_streaming_batched_model(runner=...)``): it has no contiguous-cache
+    ``generate`` and no int8 weights."""
 
     def __init__(self, cfg=None, seed=0, quantize=False, params=None):
         self.cfg = cfg or DEFAULT_LM_CONFIG
+        self.hybrid = isinstance(self.cfg, sambay.SambaYConfig)
+        if self.hybrid and quantize:
+            raise ValueError("int8 weights cover the decoder family only")
         if params is None:
-            params = tfm.init_params(jax.random.PRNGKey(seed), self.cfg)
+            family = sambay if self.hybrid else tfm
+            params = family.init_params(jax.random.PRNGKey(seed), self.cfg)
         self.params = params
+        # id 257 ends a stream only under the byte-level tokenizer's
+        # vocabulary: in any other it is a token like the rest, and a
+        # stream ends at its budget
+        self.eos_id = _EOS if self.cfg.vocab_size == _VOCAB else None
         if quantize:
             # int8 weight-only serving (client_tpu.ops.quant): ~2x weight
             # capacity per chip, same decode programs via the _mm dispatch
@@ -125,6 +138,11 @@ class _LmRunner:
     def stream(self, tokens, max_tokens, temperature=0.0, seed=0,
                top_k=0, tenant=""):
         self.check_prompt(int(np.asarray(tokens).reshape(-1).shape[0]))
+        if self.hybrid:
+            raise InferenceServerException(
+                "this model streams through the continuous-batching "
+                "engine only (lm_streaming_batched_model)", status="400",
+            )
         if top_k and int(top_k) > 0:
             raise InferenceServerException(
                 "top_k sampling needs the continuous-batching engine "
@@ -134,10 +152,11 @@ class _LmRunner:
         key = jax.random.PRNGKey(seed) if temperature > 0 else None
         for tok in tfm.generate(
             self.params, self.cfg, tokens, max_tokens,
-            temperature=temperature, key=key, stop_tokens=(_EOS,),
+            temperature=temperature, key=key,
+            stop_tokens=() if self.eos_id is None else (self.eos_id,),
         ):
             yield tok
-            if tok == _EOS:
+            if tok == self.eos_id:
                 return
 
 
@@ -212,7 +231,11 @@ def lm_streaming_batched_model(name="lm_streaming_batched", runner=None,
     engine (off by default): ``{"k": 4, "drafter": "ngram", ...}`` —
     see serve/lm/spec.py:SpecConfig for the full knob set.  Greedy
     streams keep byte-exact output; temperature streams stay
-    distribution-exact via rejection sampling."""
+    distribution-exact via rejection sampling.
+
+    What ends a stream early is the runner's ``eos_id``: the byte-level
+    tokenizer's EOS where the vocabulary is that tokenizer's, else
+    nothing but the budget."""
     from client_tpu.serve.models.continuous import BatchedLmRunner
 
     prefix_knobs = dict((response_cache or {}).get("prefix_cache") or {})
@@ -226,7 +249,7 @@ def lm_streaming_batched_model(name="lm_streaming_batched", runner=None,
         engine_kwargs.setdefault("speculative", speculative)
     base = runner or _LmRunner()
     batched = BatchedLmRunner(
-        base.params, base.cfg, max_slots=max_slots, eos_id=_EOS,
+        base.params, base.cfg, max_slots=max_slots, eos_id=base.eos_id,
         check_prompt=base.check_prompt, **engine_kwargs,
     )
     model = lm_streaming_model(name=name, runner=batched)

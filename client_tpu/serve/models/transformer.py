@@ -67,6 +67,14 @@ class TransformerConfig:
     def jdtype(self):
         return jnp.dtype(self.dtype)
 
+    @property
+    def state_spec(self):
+        """What a lane owns, as ``serve/lm/kv.py`` asks every configuration:
+        (paged layers, a block's shape with None where the block's positions
+        go, per-lane fixed state).  Identical layers: every one paged, a
+        block ``[block_size, n_kv_heads, head_dim]``, nothing beside them."""
+        return self.n_layers, (None, self.n_kv_heads, self.head_dim), {}
+
 
 def init_params(key, cfg):
     """Initialize a params pytree (layout documented in parallel.param_specs)."""
