@@ -72,6 +72,8 @@ from client_tpu.serve._completion import CompletionObserver
 from client_tpu.serve.lm.kv import KvBlockPool
 from client_tpu.serve.lm.policy import (
     LaneAutoscaler,
+    attention_width_index,
+    attention_widths,
     bucket_for,
     chunk_plan,
     geometric_buckets,
@@ -103,6 +105,10 @@ _CANCELLED = object()
 _LANE_HELP = {
     "ctpu_lm_lanes": "Configured decode lane count (autoscaled)",
     "ctpu_lm_active_lanes": "Decode lanes currently streaming",
+    "ctpu_lm_attended_positions": (
+        "Cache positions a lane that the last tick's or chunk's attention "
+        "read (of max_seq: the table width its longest lane reached)"
+    ),
 }
 
 
@@ -355,6 +361,13 @@ class _DecoderPrograms:
             donate_argnums=self.donate,
         )
 
+    def attended_positions(self, max_pos, table_width):
+        """Positions a lane that ``paged_attention`` reads in a call whose
+        largest query position is ``max_pos``: the program's own rule."""
+        widths = attention_widths(table_width)
+        index = attention_width_index(max_pos, table_width, self.block_size)
+        return widths[min(index, len(widths) - 1)] * self.block_size
+
     def prefill(self, params, kv, chunk, table, slot, start, prompt_len,
                 fresh, key, temperature, top_k):
         tok, kv.pools["k"], kv.pools["v"], key = self.prefill_jit(
@@ -406,6 +419,11 @@ class _SambaYPrograms:
             static_argnames=("cfg", "n", "block_size"),
             donate_argnums=self.donate,
         )
+
+    def attended_positions(self, max_pos, table_width):
+        """The whole table, whatever the lanes hold: one gather of layer
+        17's blocks feeds eight reading layers."""
+        return table_width * self.block_size
 
     def prefill(self, params, kv, chunk, table, slot, start, prompt_len,
                 fresh, key, temperature, top_k):
@@ -759,8 +777,13 @@ class LmEngine:
         lengths: ``context_tokens`` (the lanes' real lengths, summed: a
         decode or verify tick's before its write, a chunk's lane after
         it), ``window_tokens`` for a model with window layers (the sum of
-        ``min(length, window)``), and on a ``prefill_chunk`` its bucket
-        ``width``, the real ``tokens`` in it and its ``start``."""
+        ``min(length, window)``), ``attended_positions`` (the cache
+        positions a lane its attention read: for the decoder the table
+        width that ``policy.attention_width_index`` picks for the longest
+        lane, or for a chunk's last position, so its mean over decode
+        ticks against ``max_seq`` is how far that bound engages), and on
+        a ``prefill_chunk`` its bucket ``width``, the real ``tokens`` in
+        it and its ``start``."""
         with self._cv:
             return [dict(entry) for entry in self._tick_log]
 
@@ -1404,7 +1427,7 @@ class LmEngine:
         tokens = min(start + width, handle.prompt_len) - start
         entry = self._log_tick(
             "prefill_chunk", t0, (job.slot,), tok, [start + tokens],
-            width=width, tokens=tokens, start=start,
+            start + width - 1, width=width, tokens=tokens, start=start,
         )
         if self.registry is not None:
             self.registry.inc(
@@ -1629,7 +1652,7 @@ class LmEngine:
         self._inflight.append((self._tokens, tuple(active), None))
         self._log_tick(
             "decode", t0, tuple(i for i, _ in active), self._tokens,
-            lens[live],
+            lens[live], int(lens.max()),
         )
         return True
 
@@ -1780,7 +1803,7 @@ class LmEngine:
             self.kv.pools["k"] = pool_k
             self.kv.pools["v"] = pool_v
         self._log_tick("verify", t0, tuple(i for i, _ in active), out,
-                       [lens[i] for i, _ in active])
+                       [lens[i] for i, _ in active], int(lens.max()) + w - 1)
         with ptick.phase("device_wait"):
             vals = np.asarray(out)  # [2, n]: accepted count, correction
         self._deliver_verified(ptick, active, vals, props, counts)
@@ -1870,14 +1893,16 @@ class LmEngine:
                           device_s=device_s)
 
     def _log_tick(self, kind, t0, slots, result=None, lengths=None,
-                  **fields):
+                  max_pos=None, **fields):
         """Append one tick_trace() entry and return it.  *result* is an
         output of the program the tick dispatched at ``t0``: the
         completion observer fills in ``t_done`` and ``device_s`` when it
         lands.  *lengths* are the real lengths of the lanes the program
         worked on (the engine's own count): the entry carries their sum
         as ``context_tokens`` and, for a model with window layers, what
-        of it a window holds as ``window_tokens``."""
+        of it a window holds as ``window_tokens``.  *max_pos* is the
+        largest position the program asked from: the entry carries the
+        width its attention read for it as ``attended_positions``."""
         entry = {
             "kind": kind, "t0": t0, "t1": time.monotonic(), "lanes": slots,
             **fields,
@@ -1889,9 +1914,18 @@ class LmEngine:
                 entry["window_tokens"] = int(
                     sum(min(int(n), window) for n in lengths)
                 )
+        if max_pos is not None:
+            entry["attended_positions"] = self._programs.attended_positions(
+                max_pos, self._table_width)
         with self._cv:
             entry["n_lanes"] = self._scaler.n_lanes
             self._tick_log.append(entry)
+            if max_pos is not None and self.registry is not None:
+                self.registry.set(
+                    "ctpu_lm_attended_positions", None,
+                    entry["attended_positions"],
+                    help_=_LANE_HELP["ctpu_lm_attended_positions"],
+                )
         if result is not None:
             self._observer.watch(
                 result, functools.partial(self._tick_done, entry),

@@ -1,4 +1,5 @@
-"""Admission policy pieces: prompt-length bucketing and lane autoscaling.
+"""Admission policy pieces: prompt-length bucketing, the widths paged
+attention reads the block table at, and lane autoscaling.
 
 Bucketing exists because ``jax.jit`` keys executables on shape: a prefill
 invoked at every distinct prompt length compiles a fresh XLA program per
@@ -73,6 +74,30 @@ def chunk_plan(prompt_len, buckets, start=0):
     if remaining <= chunk:
         return [(start, bucket_for(remaining, buckets))]
     return [(s, chunk) for s in range(start, prompt_len, chunk)]
+
+
+def _table_group(table_width):
+    """Columns in an eighth of the block table, rounded up to whole ones:
+    the step in which paged attention reads it."""
+    return -(-int(table_width) // 8)
+
+
+def attention_widths(table_width):
+    """The table widths (in columns) a call of
+    ``transformer.paged_attention`` can read: eighths of the table, ending
+    at the table itself; a table too small to divide has fewer.  The first
+    is the group of columns its loop gathers at a time."""
+    group = _table_group(table_width)
+    return tuple(range(group, table_width, group)) + (int(table_width),)
+
+
+def attention_width_index(max_pos, table_width, block_size):
+    """Which of ``attention_widths(table_width)`` a call reads whose
+    largest query position is ``max_pos``: the first that holds column
+    ``max_pos // block_size``.  Plain arithmetic, so the program asks it
+    with a traced scalar and the engine's counter with an int, and the two
+    cannot drift apart."""
+    return max_pos // (block_size * _table_group(table_width))
 
 
 def verify_widths(max_k, min_width=2):

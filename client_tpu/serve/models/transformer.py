@@ -378,22 +378,81 @@ def paged_attention(q, pool_k, pool_v, tables, pos, cfg, block_size):
     forever; the paged layout pools HBM across lanes and a lane holds
     only ceil((prompt+budget)/block_size) blocks.
 
+    Only the table columns that the call's LARGEST position reaches are
+    read, in groups of an eighth of the table.  The width is chosen on
+    the device, inside the one executable:
+    ``serve/lm/policy.attention_widths`` gives the widths (its first is
+    the group), ``policy.attention_width_index`` picks from ``pos``, and
+    a ``lax.fori_loop`` with that traced trip count runs
+    ``_attend_columns`` on one group of columns after another, keeping
+    the softmax's running maximum, sum and weighted sum in float32.
+    Every key a query may see lies in a group the loop reaches and a
+    masked score contributes an exact zero, so the result is the whole
+    table's to rounding (PERF.md section 6, PR 30).  A table with one
+    width has no loop at all.
+    """
+    from client_tpu.serve.lm.policy import (
+        attention_width_index,
+        attention_widths,
+    )
+
+    b, t = q.shape[:2]
+    table_width = tables.shape[-1]
+    widths = attention_widths(table_width)
+    if len(widths) == 1:
+        _, l, acc = _attend_columns(q, pool_k, pool_v, tables, pos, 0, cfg,
+                                    block_size)
+        return (acc / l).astype(q.dtype)
+    group = widths[0]
+    # whole groups, the last one over trash columns that no position reaches
+    tables = jnp.pad(tables, ((0, 0), (0, group * len(widths) - table_width)))
+
+    def step(g, carry):
+        m, l, acc = carry
+        m_g, l_g, acc_g = _attend_columns(
+            q, pool_k, pool_v,
+            lax.dynamic_slice_in_dim(tables, g * group, group, axis=1),
+            pos, g * group * block_size, cfg, block_size)
+        m_new = jnp.maximum(m, m_g)
+        old, new = jnp.exp(m - m_new), jnp.exp(m_g - m_new)
+        return m_new, l * old + l_g * new, acc * old + acc_g * new
+
+    rows = (b, t, cfg.n_heads, 1)
+    n_groups = jnp.minimum(
+        attention_width_index(jnp.max(pos), table_width, block_size) + 1,
+        len(widths))
+    m, l, acc = lax.fori_loop(0, n_groups, step, (
+        jnp.full(rows, -jnp.inf, jnp.float32),
+        jnp.zeros(rows, jnp.float32),
+        jnp.zeros(rows[:3] + (cfg.head_dim,), jnp.float32)))
+    return (acc / l).astype(q.dtype)
+
+
+def _attend_columns(q, pool_k, pool_v, tables, pos, first, cfg, block_size):
+    """``paged_attention`` over the columns of ``tables`` alone, which hold
+    logical positions ``first`` onward (S = ``tables.shape[-1] *
+    block_size`` of them a lane): each query row's maximum score ``m``,
+    the sum ``l`` of ``exp(score - m)`` and the sum ``acc`` of those
+    weights times V, [B,T,H,1], [B,T,H,1] and [B,T,H,hd] in float32.
+    ``acc / l`` is the attention over these columns; two sets of columns
+    combine as ``paged_attention`` does.
+
     The queries of a KV-head group are contracted against that group's
     gathered keys and values directly: ``q`` as [B,T,n_kv,n_rep,hd]
-    against K [B,S,n_kv,hd] (S = table_width * block_size) is ONE
-    ``dot_general`` with batch dimensions (lane, KV head), n_rep x T
-    query rows a group, scores [B,n_kv,n_rep,T,S] accumulated in
-    float32 from operands at their stored width; mask, scale and
-    softmax stay float32; the weighted sum is the mirror contraction
-    over V [B,S,n_kv,hd] with the probabilities cast to V's type.  So
-    the gathered K and V are read once each as stored: no copy at
-    n_heads, none in float32 (at Mistral-7B widths and 16 lanes those
-    were 1 GB written a layer a tick: PERF.md section 6, PR 28).  With
-    n_rep 1 (MHA) a group is one head and the same code runs.
+    against K [B,S,n_kv,hd] is ONE ``dot_general`` with batch dimensions
+    (lane, KV head), n_rep x T query rows a group, scores
+    [B,n_kv,n_rep,T,S] accumulated in float32 from operands at their
+    stored width; mask, scale and softmax stay float32; the weighted sum
+    is the mirror contraction over V [B,S,n_kv,hd] with the weights cast
+    to V's type.  So the gathered K and V are read once each as stored:
+    no copy at n_heads, none in float32 (at Mistral-7B widths and 16
+    lanes those were 1 GB written a layer a tick: PERF.md section 6,
+    PR 28).  With n_rep 1 (MHA) a group is one head and the same code
+    runs.
 
     A chunk of 2 * hd query rows or more (a prefill chunk, never a
     decode or verify tick) takes the per-head form over ``_repeat_kv``
-    instead.  There the float32 scores [B,H,T,S] outweigh the repeated
+    instead, within each set of columns.  There the float32 scores [B,H,T,S] outweigh the repeated
     K and V (hd : 2T in bytes), and XLA fuses that form's scores, row
     maximum, exponential and row sum into one pass over them, where the
     grouped form's get a pass more (a 512-wide chunk on the v5e: 29.8
@@ -409,7 +468,7 @@ def paged_attention(q, pool_k, pool_v, tables, pos, cfg, block_size):
     s_len = tables.shape[-1] * block_size
     kk = pool_k[tables].reshape(b, s_len, n_kv, hd)
     vv = pool_v[tables].reshape(b, s_len, n_kv, hd)
-    valid = jnp.arange(s_len)[None, None, :] <= pos[:, :, None]  # [B,T,S]
+    valid = first + jnp.arange(s_len)[None, None, :] <= pos[:, :, None]
     if t >= 2 * hd:
         # spelled out, not folded into the grouped einsums as n_rep 1: with
         # their size-1 axes XLA no longer fuses this softmax into one pass
@@ -418,16 +477,25 @@ def paged_attention(q, pool_k, pool_v, tables, pos, cfg, block_size):
             "bqhd,bkhd->bhqk", q, kk, preferred_element_type=jnp.float32
         ) * (hd ** -0.5)
         s = jnp.where(valid[:, None], s, -1e30)
-        p = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("bhqk,bkhd->bqhd", p.astype(vv.dtype), vv)
+        m = jnp.max(s, axis=-1, keepdims=True)
+        p = jnp.exp(s - m)
+        acc = jnp.einsum("bhqk,bkhd->bqhd", p.astype(vv.dtype), vv,
+                         preferred_element_type=jnp.float32)
+        rows = lambda x: x.transpose(0, 2, 1, 3)  # [B,H,T,1] -> [B,T,H,1]
+        return rows(m), rows(jnp.sum(p, axis=-1, keepdims=True)), acc
     qg = q.reshape(b, t, n_kv, n_rep, hd)
     s = jnp.einsum(
         "btgrd,bsgd->bgrts", qg, kk, preferred_element_type=jnp.float32
     ) * (hd ** -0.5)
     s = jnp.where(valid[:, None, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bgrts,bsgd->btgrd", p.astype(vv.dtype), vv)
-    return out.reshape(b, t, cfg.n_heads, hd)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.exp(s - m)
+    acc = jnp.einsum("bgrts,bsgd->btgrd", p.astype(vv.dtype), vv,
+                     preferred_element_type=jnp.float32)
+    # [B,n_kv,n_rep,T,1] -> [B,T,H,1]
+    rows = lambda x: x.transpose(0, 3, 1, 2, 4).reshape(b, t, cfg.n_heads, 1)
+    return (rows(m), rows(jnp.sum(p, axis=-1, keepdims=True)),
+            acc.reshape(b, t, cfg.n_heads, hd))
 
 
 def lm_flops_per_token(cfg, context=0):

@@ -16,8 +16,11 @@ import pytest
 import jax
 
 from client_tpu.serve.lm import KvBlockPool, LmEngine, PrefixCache
+from client_tpu.serve.lm import engine as lm_engine
 from client_tpu.serve.lm.policy import (
     LaneAutoscaler,
+    attention_width_index,
+    attention_widths,
     bucket_for,
     chunk_plan,
     geometric_buckets,
@@ -85,6 +88,26 @@ def test_chunk_plan_widths_are_bucket_members():
 def test_pad_prompt_rejects_overflow():
     with pytest.raises(ValueError):
         pad_prompt(np.zeros((1, 8), np.int32), 4)
+
+
+@pytest.mark.parametrize("table_width", [1, 3, 8, 12, 13, 16, 100, 128])
+def test_attention_width_rule_on_ints_and_traced_values_alike(table_width):
+    """Eighths of the table, duplicates dropped; the index is the first
+    width that holds ``need`` columns, for every ``need`` the table allows,
+    asked with Python ints and with a traced scalar."""
+    block = 4
+    step = -(-table_width // 8)
+    widths = attention_widths(table_width)
+    assert widths == tuple(sorted(
+        {min((k + 1) * step, table_width) for k in range(8)}))
+    assert widths[-1] == table_width and len(widths) <= 8
+    traced = jax.jit(
+        lambda pos: attention_width_index(pos, table_width, block))
+    for need in range(1, table_width + 1):
+        first = next(i for i, w in enumerate(widths) if w >= need)
+        for pos in ((need - 1) * block, need * block - 1):
+            assert attention_width_index(pos, table_width, block) == first
+            assert int(traced(np.int32(pos))) == first
 
 
 def test_lane_autoscaler_hysteresis():
@@ -878,6 +901,103 @@ def test_engine_metrics_and_tick_kinds(params):
             assert set(t) >= {"kind", "t0", "t1", "lanes", "n_lanes"}
     finally:
         eng.close()
+
+
+def _attended(max_pos, table_width, block):
+    widths = attention_widths(table_width)
+    return block * widths[attention_width_index(max_pos, table_width, block)]
+
+
+def test_attended_positions_follow_the_longest_lane(params):
+    """Streams of unequal lengths: every decode tick and every chunk says
+    how wide its attention read, which is the width rule on the lanes' own
+    lengths (replayed here from the entries), and the gauge follows."""
+    block, table_width = 8, CFG.max_seq // 8
+    reg = Registry()
+    eng = LmEngine(params, CFG, max_slots=4, lane_counts=(4,),
+                   block_size=block, prefill_chunk=16, min_bucket=4,
+                   registry=reg)
+    try:
+        prompts = [[1, 2, 3], list(range(1, 41)), list(range(5, 25)), [9]]
+        budgets = [30, 20, 40, 12]
+        qs = [eng.submit(p, n)[0] for p, n in zip(prompts, budgets)]
+        assert [len(_collect(q)) for q in qs] == budgets
+        ticks = eng.tick_trace()
+        assert reg.get("ctpu_lm_attended_positions") == \
+            ticks[-1]["attended_positions"]
+    finally:
+        eng.close()
+    length, seen = {}, set()
+    for t in ticks:
+        if t["kind"] == "prefill_chunk":
+            (slot,) = t["lanes"]
+            length[slot] = t["context_tokens"]
+            max_pos = t["start"] + t["width"] - 1
+        else:
+            assert t["kind"] == "decode"
+            lens = [length[slot] for slot in t["lanes"]]
+            assert t["context_tokens"] == sum(lens)
+            max_pos = max(lens)
+            for slot in t["lanes"]:
+                length[slot] += 1
+        assert t["attended_positions"] == _attended(
+            max_pos, table_width, block), t
+        seen.add(t["attended_positions"])
+    # the longest stream ends at 60 of 96 positions: four widths met, never
+    # the table's own
+    assert seen == {16, 32, 48, 64}
+
+
+def test_decode_tick_at_a_narrow_width_agrees_with_the_whole_table(
+        params, monkeypatch):
+    """Three lanes of 5, 9 and 14 tokens in a table of 96 positions: the
+    tick reads 16 of them.  Its logits are the whole-table tick's to
+    rounding (the reductions are shorter, the terms the same) and its
+    tokens the same."""
+    block, table_width = 8, CFG.max_seq // 8
+    lens = np.array([5, 9, 14], np.int32)
+    assert _attended(int(lens.max()), table_width, block) == 16
+    pool_k = [jax.numpy.zeros((7, block, CFG.n_kv_heads, CFG.head_dim))
+              for _ in range(CFG.n_layers)]
+    pool_v = list(pool_k)
+    tables = np.zeros((3, table_width), np.int32)
+    tables[:, :2] = np.arange(1, 7).reshape(3, 2)
+    rng = np.random.default_rng(0)
+    for lane, n in enumerate(lens):
+        chunk = pad_prompt(rng.integers(1, 128, (1, n)), 16)
+        _, pool_k, pool_v, _ = lm_engine._prefill_chunk(
+            params, chunk, pool_k, pool_v, tables[lane], np.int32(0),
+            np.int32(n), jax.random.PRNGKey(lane), np.float32(0),
+            np.int32(0), cfg=CFG, block_size=block)
+    products = []
+    real = lm_engine._mm
+
+    def spy(x, w):
+        products.append(real(x, w))
+        return products[-1]
+
+    def tick():
+        """Run eagerly, so that the head's product can be seen."""
+        tokens, *_ = lm_engine._decode_tick(
+            params, jax.numpy.array([3, 5, 7]), pool_k, pool_v,
+            jax.numpy.asarray(tables), jax.numpy.asarray(lens),
+            np.zeros(3, np.float32), np.zeros(3, np.int32),
+            jax.random.split(jax.random.PRNGKey(1), 3), cfg=CFG, n=3,
+            block_size=block)
+        return np.asarray(tokens), np.asarray(products[-1])
+
+    def whole_table(q, pool_k, pool_v, tables, pos, cfg, block_size):
+        _, l, acc = tfm._attend_columns(
+            q, pool_k, pool_v, tables, pos, 0, cfg, block_size)
+        return (acc / l).astype(q.dtype)
+
+    monkeypatch.setattr(lm_engine, "_mm", spy)
+    tokens, logits = tick()
+    monkeypatch.setattr(lm_engine, "paged_attention", whole_table)
+    whole_tokens, whole_logits = tick()
+    assert logits.shape == (3, CFG.vocab_size)
+    np.testing.assert_allclose(logits, whole_logits, rtol=1e-5, atol=1e-5)
+    assert tokens.tolist() == whole_tokens.tolist()
 
 
 # -- speculative decoding: draft/verify over the paged KV cache ------------

@@ -1,7 +1,8 @@
 """``transformer.paged_attention``: the grouped contraction against a plain
-float32 reference, and the shape of the decode tick it leaves behind (no
-repeat of the gathered cache to every query head, no float32 copy of it) in
-the traced program and in the module the v5e's compiler makes of it."""
+float32 reference, at every table width it chooses among, and the shape of
+the decode tick it leaves behind (no repeat of the gathered cache to every
+query head, no float32 copy of it, no copy of a layer's pool) in the traced
+program and in the module the v5e's compiler makes of it."""
 
 import functools
 import re
@@ -14,6 +15,7 @@ import jax.numpy as jnp
 
 from client_tpu.serve.lm import KvBlockPool
 from client_tpu.serve.lm.engine import _decode_tick
+from client_tpu.serve.lm.policy import attention_width_index, attention_widths
 from client_tpu.serve.models import transformer as tfm
 
 BLOCK = 4
@@ -30,13 +32,14 @@ def _cfg(n_rep, dtype, n_kv=N_KV, hd=HEAD_DIM, **kw):
         dtype=dtype, **kw)
 
 
-def _paged_case(b, t, n_rep, dtype, seed):
+def _paged_case(lengths, t, n_rep, dtype, seed, unused=KvBlockPool.TRASH):
     """Random q, contiguous K/V, and the same K/V scattered over a pool by a
-    shuffled table whose unused columns point at the trash block."""
+    shuffled table whose unused columns point at block ``unused``."""
     rng = np.random.default_rng(seed)
     cfg = _cfg(n_rep, dtype)
     s_len = WIDTH * BLOCK
-    lengths = np.maximum(np.array(LENGTHS[b]), t)
+    b = len(lengths)
+    lengths = np.maximum(np.array(lengths), t)
     q = rng.standard_normal((b, t, cfg.n_heads, HEAD_DIM), np.float32)
     k = rng.standard_normal((b, s_len, N_KV, HEAD_DIM), np.float32)
     v = rng.standard_normal((b, s_len, N_KV, HEAD_DIM), np.float32)
@@ -45,7 +48,7 @@ def _paged_case(b, t, n_rep, dtype, seed):
     # key that slips past the mask shows
     pool_k = np.full((n_blocks + 1, BLOCK, N_KV, HEAD_DIM), 50.0, np.float32)
     pool_v = np.full((n_blocks + 1, BLOCK, N_KV, HEAD_DIM), -50.0, np.float32)
-    tables = np.full((b, WIDTH), KvBlockPool.TRASH, np.int32)
+    tables = np.full((b, WIDTH), unused, np.int32)
     free = rng.permutation(np.arange(1, n_blocks + 1))
     for lane, length in enumerate(lengths):
         used = -(-int(length) // BLOCK)
@@ -80,12 +83,82 @@ def test_paged_attention_matches_plain_reference(b, t, n_rep, dtype, tol):
     """(2, 32) is a chunk of 2 x head_dim query rows: the per-head side of
     ``paged_attention``'s choice; the others contract a group at a time."""
     cfg, q, k, v, pool_k, pool_v, tables, pos = _paged_case(
-        b, t, n_rep, dtype, seed=100 * b + 10 * t + n_rep)
+        LENGTHS[b], t, n_rep, dtype, seed=100 * b + 10 * t + n_rep)
     out = tfm.paged_attention(q, pool_k, pool_v, tables, pos, cfg, BLOCK)
     assert out.shape == q.shape and out.dtype == q.dtype
     want = _reference(q, k, v, pos, n_rep)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), want, rtol=tol, atol=tol)
+
+
+# -- the width chosen on the device -------------------------------------------
+
+WIDTHS = attention_widths(WIDTH)    # 2, 4 .. 16 columns: 8, 16 .. 64 positions
+# the longest lane's last position on each side of every width's edge, by the
+# index of the width it has to take: the last position a width holds and the
+# first it does not (the table's own last position has no other side)
+EDGES = [(k, w * BLOCK - 1) for k, w in enumerate(WIDTHS)] + [
+    (k + 1, w * BLOCK) for k, w in enumerate(WIDTHS[:-1])]
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted_attention(n_rep, dtype):
+    """One executable a (shape, type): the width is data, not shape."""
+    return jax.jit(functools.partial(
+        tfm.paged_attention, cfg=_cfg(n_rep, dtype), block_size=BLOCK))
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 3e-2)])
+@pytest.mark.parametrize("index,max_pos,t", [
+    (index, max_pos, t) for index, max_pos in sorted(EDGES)
+    for t in (1, 5, 2 * HEAD_DIM) if max_pos >= t - 1])  # t rows end there
+def test_paged_attention_reads_the_width_its_longest_position_reaches(
+        index, max_pos, t, dtype, tol):
+    """Three lanes, the longest ending at ``max_pos``: the rule picks width
+    ``index`` of eight, the answer is the whole table's, and no column past
+    that width is read.  Those columns point at a block of NaNs, which the
+    mask alone does not keep out of the weighted sum (0 x NaN).  The shorter
+    lanes have whole groups of columns with no key to see."""
+    assert len(WIDTHS) == 8
+    assert attention_width_index(max_pos, WIDTH, BLOCK) == index
+    longest = max_pos + 1
+    cfg, q, k, v, pool_k, pool_v, tables, pos = _paged_case(
+        (longest, max(longest // 2, 1), 1), t, 4, dtype, seed=max_pos + t)
+    assert int(pos.max()) == max_pos
+    poison = pool_k.shape[0]
+    nans = jnp.full((1,) + pool_k.shape[1:], jnp.nan, pool_k.dtype)
+    pool_k = jnp.concatenate([pool_k, nans])
+    pool_v = jnp.concatenate([pool_v, nans])
+    tables = tables.at[:, WIDTHS[index]:].set(poison)
+    out = _jitted_attention(4, dtype)(q, pool_k, pool_v, tables, pos)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), _reference(q, k, v, pos, 4),
+        rtol=tol, atol=tol)
+    if index + 1 < len(WIDTHS):
+        # one pass over the whole table on the same arguments does read them
+        _, _, whole = tfm._attend_columns(
+            q, pool_k, pool_v, tables, pos, 0, cfg, BLOCK)
+        assert np.isnan(np.asarray(whole)).any()
+
+
+@pytest.mark.parametrize("width,group", [(1, 0), (5, 1), (13, 2), (WIDTH, 2)])
+def test_paged_attention_loops_only_where_the_table_has_widths(width, group):
+    """A table of one width is one pass, no loop and no branch in it at all;
+    a wider one is gathered a group of columns at a time, inside a loop."""
+    cfg = _cfg(4, "bfloat16")
+    sds = jax.ShapeDtypeStruct
+    pool = sds((9, BLOCK, N_KV, HEAD_DIM), cfg.jdtype)
+    jaxpr = jax.make_jaxpr(functools.partial(
+        tfm.paged_attention, cfg=cfg, block_size=BLOCK))(
+            sds((2, 1, cfg.n_heads, HEAD_DIM), cfg.jdtype), pool, pool,
+            sds((2, width), jnp.int32), sds((2, 1), jnp.int32))
+    outer = [e.primitive.name for e in jaxpr.jaxpr.eqns]
+    assert outer.count("while") == (1 if group else 0)
+    assert "cond" not in outer
+    gathered = [aval.shape for name, aval in _intermediates(jaxpr.jaxpr)
+                if name == "gather" and aval.shape[-1] == HEAD_DIM]
+    assert gathered == 2 * [(2, group or width, BLOCK, N_KV, HEAD_DIM)]
 
 
 # -- the decode tick's program ------------------------------------------------
@@ -115,34 +188,42 @@ def _intermediates(jaxpr):
                     yield from _intermediates(sub)
 
 
-def _assert_cache_kept_at_its_width(values, cfg, n, positions):
-    """``values``: (label, dtype name, elements) of everything a tick makes.
-    The gather of ``n`` lanes' ``positions`` is among them; nothing has it
-    once for every QUERY head, and nothing of float32 is as large as it."""
-    gathered = n * positions * cfg.n_kv_heads * cfg.head_dim
-    repeated = gathered * (cfg.n_heads // cfg.n_kv_heads)
-    values = list(values)
-    assert any(size == gathered for _, _, size in values)
-    for label, dtype, size in values:
-        assert size < repeated, label
-        assert not (dtype == "float32" and size >= gathered), label
+def _assert_cache_kept_at_its_width(values, cfg, n, group, table):
+    """``values``: (label, dtype name, dims) of everything a tick makes.
+    The gather of ``n`` lanes' ``group`` positions (one group of the table's
+    columns) is among them.  Of what spans a group's positions or the whole
+    ``table``'s, nothing has a lane's keys once for every QUERY head, nothing
+    of float32 is as large as the gathered blocks, and nothing gathers the
+    whole table."""
+    lanes_keys = n * cfg.n_kv_heads * cfg.head_dim      # at one position
+    n_rep = cfg.n_heads // cfg.n_kv_heads
+    values = [(label, dtype, dims, int(np.prod(dims)))
+              for label, dtype, dims in values]
+    assert any(size == group * lanes_keys and group in dims
+               for _, _, dims, size in values)
+    for label, dtype, dims, size in values:
+        for positions in {group, table} & set(dims):
+            assert size < positions * lanes_keys * n_rep, label
+            assert not (dtype == "float32"
+                        and size >= positions * lanes_keys), label
+        assert not (table in dims and size >= table * lanes_keys), label
 
 
 def test_decode_tick_never_repeats_or_widens_the_gathered_cache():
     """At a GQA configuration (4 query heads a KV head, bf16) nothing in the
-    tick has a lane's keys once for every QUERY head, and nothing of float32
-    is as large as the gathered blocks.  Sized so that weights, logits and
-    scores are all smaller than either."""
-    n, width, block = 4, 16, 8
+    tick has a lane's keys once for every QUERY head, nothing of float32 is
+    as large as the gathered blocks, and the gather is a group of columns
+    wide (an eighth of the table), never the table."""
+    n, width, block = 4, 128, 8
     cfg = _cfg(4, "bfloat16", n_kv=2, hd=8, vocab_size=64, d_ff=64,
                n_layers=2, max_seq=width * block)
-    args = _decode_tick_args(cfg, n, width, block, n_blocks=n * width)
+    args = _decode_tick_args(cfg, n, width, block, n_blocks=64)
     jaxpr = jax.make_jaxpr(functools.partial(
         _decode_tick, cfg=cfg, n=n, block_size=block))(*args)
     _assert_cache_kept_at_its_width(
-        ((f"{name}: {aval}", str(aval.dtype), aval.size)
+        ((f"{name}: {aval}", str(aval.dtype), aval.shape)
          for name, aval in _intermediates(jaxpr.jaxpr)),
-        cfg, n, width * block)
+        cfg, n, attention_widths(width)[0] * block, width * block)
 
 
 @pytest.fixture(scope="module")
@@ -161,19 +242,25 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def test_decode_tick_compiled_for_v5e_keeps_the_cache_at_its_width(one_chip):
+@pytest.mark.parametrize("n_layers", [1, 16])
+def test_decode_tick_compiled_for_v5e_keeps_the_cache_at_its_width(
+        one_chip, n_layers):
     """The chat cell's tick (Mistral-7B widths but for the vocabulary, 16
-    lanes, a table of 2,048 positions; one layer) as the v5e's compiler
-    leaves it: no operation with 16 x 2,048 x 32 x 128 elements of either
-    width, and no float32 tensor of the gathered blocks' size.  The parent's
-    module had two of the first (broadcasts of 256 and 512 MB a layer) and
-    two of the second."""
+    lanes, a table of 2,048 positions; one layer, and the cell's sixteen) as
+    the v5e's compiler leaves it: the gather is of 256 positions a lane, no
+    operation has a lane's keys once for every query head, and no float32
+    tensor is of the gathered blocks' size.  PR 28's parent had two of the
+    first (broadcasts of 256 and 512 MB a layer) and two of the second.  Nor
+    does the loop over the table's columns cost a layer's pool its place:
+    every pool is still an output that aliases its donated argument, and
+    nothing copies one (67 MB), in place or through another memory space."""
     from jax.experimental.compilation_cache import compilation_cache
 
     n, width, block = 16, 128, 16
     cfg = tfm.TransformerConfig(
-        vocab_size=4096, d_model=4096, n_layers=1, n_heads=32, n_kv_heads=8,
-        d_ff=14336, max_seq=width * block, rope_theta=1e6, dtype="bfloat16")
+        vocab_size=4096, d_model=4096, n_layers=n_layers, n_heads=32,
+        n_kv_heads=8, d_ff=14336, max_seq=width * block, rope_theta=1e6,
+        dtype="bfloat16")
     args = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         _decode_tick_args(cfg, n, width, block, n_blocks=2048))
@@ -192,6 +279,12 @@ def test_decode_tick_compiled_for_v5e_keeps_the_cache_at_its_width(one_chip):
     names = {"f32": "float32", "bf16": "bfloat16"}
     _assert_cache_kept_at_its_width(
         ((f"{dtype}[{dims}]", names[dtype],
-          int(np.prod([int(d) for d in dims.split(",")])))
+          tuple(int(d) for d in dims.split(",")))
          for dtype, dims in set(re.findall(r"\b(f32|bf16)\[([\d,]+)\]", text))),
-        cfg, n, width * block)
+        cfg, n, attention_widths(width)[0] * block, width * block)
+    assert len(re.findall(r" while\(", text)) == n_layers
+    pool = rf"bf16\[2049,{block},{cfg.n_kv_heads},{cfg.head_dim}\]"
+    copied = re.findall(rf"= \(?{pool}[^=]* (?:copy|copy-start|slice-start)\(.*", text)
+    assert not copied, copied[:2]
+    aliases = re.search(r"input_output_alias=\{(.*?)\}, entry_comp", text)
+    assert aliases.group(1).count("-alias") == 2 * n_layers
