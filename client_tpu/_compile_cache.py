@@ -2,9 +2,9 @@
 compilation cache.
 
 Called from the process entry points only (``python -m client_tpu.serve``,
-``python -m client_tpu.perf``, ``bench.py``, ``chip_smoke.py``), before they
-import jax — never from library code: a library that moved the cache would
-move it under every program that imports it.
+``python -m client_tpu.perf``, ``chip_smoke.py``, ``benchmark/run.py``),
+before they import jax — never from library code: a library that moved
+the cache would move it under every program that imports it.
 
 The directory is part of every cache entry's address, so it must not move
 between runs: no temp name, pid or timestamp.  An operator places it with

@@ -30,8 +30,8 @@ def native_worker_available(binary=None):
 def native_windows_stable(windows, threshold, window_count=3):
     """DetermineStability over trailing native windows (reference
     inference_profiler.h:365-399): throughput and p99 latency of the last
-    ``window_count`` windows each within ±threshold of their mean.  Shared
-    by the perf CLI sweep and bench.py's headline qualification."""
+    ``window_count`` windows each within ±threshold of their mean: the
+    perf CLI sweep's stability rule."""
     if len(windows) < window_count:
         return False
     tail = windows[-window_count:]

@@ -85,30 +85,6 @@ class PreRegisteredShmInferDataManager:
         pass
 
 
-def export_region_specs(data_manager, inputs_meta, loader):
-    """(input_specs, output_specs) for PreRegisteredShmInferDataManager from
-    a live shm data manager (its regions stay registered with the server)."""
-    metas = {m["name"]: m for m in inputs_meta}
-    input_specs = {}
-    for s in range(loader.num_streams):
-        for t in range(loader.num_steps(s)):
-            tensors = []
-            for name, meta in metas.items():
-                entry = data_manager._regions.get((s, t, name))
-                if entry is None:
-                    continue
-                region, nbytes = entry
-                td = loader.get_input_data(s, t).get(name)
-                shape = list(td.array.shape) if td is not None else meta["shape"]
-                tensors.append((name, shape, meta["datatype"], region, nbytes))
-            input_specs[(s, t)] = tensors
-    output_specs = [
-        (name,) + data_manager._out_regions.get(name, ("", 0))
-        for name in [m["name"] for m in getattr(data_manager, "_outputs_meta", [])]
-    ]
-    return input_specs, output_specs
-
-
 def _worker_main(conn, url, model_name, concurrency, warmup_s, window_s, spec):
     """One load process: build the object graph, wait for 'go', run the
     window, report records.  Never touches a device backend."""

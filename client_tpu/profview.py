@@ -37,7 +37,7 @@ __all__ = ["load_reports", "rollup_from_ticks", "render_engine", "main"]
 
 def _engines_of(obj):
     """Engine rollup dicts inside one parsed JSON object (a prof_report,
-    a bare rollup, or a bench record carrying a ``prof`` block)."""
+    a bare rollup, or a record carrying a ``prof`` block)."""
     if not isinstance(obj, dict):
         return []
     if isinstance(obj.get("engines"), list):

@@ -145,8 +145,8 @@ class ServerReplicaLauncher:
 
 
 class Autoscaler:
-    """The control loop.  Drive it synchronously (``tick()`` — tests and
-    the bench own the clock) or via ``start()``/``close()`` (a daemon
+    """The control loop.  Drive it synchronously (``tick()`` — tests own
+    the clock) or via ``start()``/``close()`` (a daemon
     thread ticking every ``policy.tick_interval_s``) — one driver at a
     time, never both: ticks are single-threaded by contract, so no lock
     is ever held across the spawn/retire/warm peer traffic a tick

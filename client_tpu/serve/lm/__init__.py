@@ -1,8 +1,8 @@
 """Continuous-batching LM serving subsystem.
 
-The production-grade successor of the fixed-lane prototype that used to
-live inside ``serve/models/continuous.py`` (which now re-exports this
-package's engine under its old names).  Four pillars:
+The engine knows no model: a family's programs live in the family's own
+module (``serve/models/transformer.py``, ``sambay.py``) and its
+configuration hands them out (``cfg.family``).  Four pillars:
 
 - **prompt-length bucketing** (:mod:`.policy`) — prompts pad to a small
   geometric set of prefill widths so the compiled prefill-executable
@@ -44,8 +44,10 @@ from client_tpu.serve.lm.policy import (
     pad_prompt,
 )
 from client_tpu.serve.lm.prefix import PrefixCache
+from client_tpu.serve.lm.runner import BatchedLmRunner
 
 __all__ = [
+    "BatchedLmRunner",
     "LmEngine",
     "KvBlockPool",
     "LaneAutoscaler",

@@ -64,16 +64,11 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
-from client_tpu.ops.sampling import TOPK_CAP as _TOPK_CAP
-from client_tpu.ops.sampling import select_token as _select_token
 from client_tpu.serve._completion import CompletionObserver
 from client_tpu.serve.lm.kv import KvBlockPool
 from client_tpu.serve.lm.policy import (
     LaneAutoscaler,
-    attention_width_index,
-    attention_widths,
     bucket_for,
     chunk_plan,
     geometric_buckets,
@@ -83,16 +78,7 @@ from client_tpu.serve.lm.policy import (
 from client_tpu.serve.lm.prefix import PrefixCache
 from client_tpu.serve.lm.spec import LaneSpec, SpecConfig
 from client_tpu.serve.metrics import FLEET_HELP, LM_PREFIX_HELP, LM_SPEC_HELP
-from client_tpu.serve.models import sambay
-from client_tpu.serve.models.transformer import (
-    _ffn_block,
-    _mm,
-    _rms_norm,
-    _rope,
-    lm_flops_per_token,
-    paged_attention,
-)
-from client_tpu.serve.prof import NULL_TICK, PhaseProfiler, annotation
+from client_tpu.serve.prof import NULL_TICK, PhaseProfiler
 
 # sentinel object closing a stream's token queue
 _CLOSE = object()
@@ -112,351 +98,10 @@ _LANE_HELP = {
 }
 
 
-def _decode_tick(params, tokens_full, pool_k, pool_v, tables, lens,
-                 temps, topks, keys_full, *, cfg, n, block_size):
-    """One batched decode step over the first ``n`` lanes (n is static:
-    one executable per configured lane count)."""
-    pool_k = list(pool_k)
-    pool_v = list(pool_v)
-    tok = tokens_full[:n]
-    x = jnp.take(params["embed"], tok, axis=0)[:, None, :]  # [n,1,D]
-    pos = lens  # [n]
-    hd = cfg.head_dim
-    lane = jnp.arange(n)
-    blk_col = pos // block_size
-    slot = pos % block_size
-    for i, layer in enumerate(params["layers"]):
-        h = _rms_norm(x, layer["ln_attn"])
-        q = _mm(h, layer["attn"]["wq"]).reshape(n, 1, cfg.n_heads, hd)
-        k = _mm(h, layer["attn"]["wk"]).reshape(n, 1, cfg.n_kv_heads, hd)
-        v = _mm(h, layer["attn"]["wv"]).reshape(n, 1, cfg.n_kv_heads, hd)
-        q = _rope(q, pos[:, None], cfg.rope_theta)
-        k = _rope(k, pos[:, None], cfg.rope_theta)
-        blk = tables[lane, blk_col]  # [n] physical block per lane
-        pool_k[i] = pool_k[i].at[blk, slot].set(k[:, 0])
-        pool_v[i] = pool_v[i].at[blk, slot].set(v[:, 0])
-        attn = paged_attention(
-            q, pool_k[i], pool_v[i], tables, pos[:, None], cfg, block_size
-        )
-        out = _mm(
-            attn.reshape(n, 1, cfg.n_heads * hd), layer["attn"]["wo"]
-        )
-        x = x + out.astype(x.dtype)
-        x, _ = _ffn_block(layer, x, cfg)
-    x = _rms_norm(x, params["ln_f"])
-    logits = _mm(x[:, 0], params["lm_head"]).astype(jnp.float32)  # [n,V]
-    pairs = jax.vmap(functools.partial(jax.random.split, num=2))(
-        keys_full[:n]
-    )
-    nxt = jax.vmap(_select_token)(logits, pairs[:, 0], temps, topks)
-    tokens_out = tokens_full.at[:n].set(nxt)
-    keys_out = keys_full.at[:n].set(pairs[:, 1])
-    return tokens_out, pool_k, pool_v, keys_out
-
-
-def _accept_lane(logits, props, count, temp, top_k, keys, *, width):
-    """One lane's speculative acceptance rule on device.
-
-    ``logits`` [w, V] are the target model's scores at positions
-    ``length .. length + w - 1`` (position j scores the token FOLLOWING
-    ``seq[j]``), ``props`` [w - 1] the drafted tokens (``props[j]`` is
-    the proposal for what position j generates), ``count`` how many are
-    real, ``keys`` [w + 1, 2] this lane's per-position RNG subkeys.
-
-    Greedy lanes (temperature 0) accept a draft iff it equals the
-    argmax — the accepted prefix + the argmax correction reconstructs
-    plain greedy decode byte-exactly.  Temperature lanes run rejection
-    sampling for a point-mass proposal: accept draft ``x`` with
-    probability ``p(x)`` under the lane's filtered/tempered target
-    distribution (the exact `_select_token` distribution), and on
-    rejection sample the correction from the residual (``p`` with
-    ``x``'s mass removed, renormalized) — the delivered tokens are an
-    exact draw from the target distribution.  When every draft is
-    accepted the correction is a free "bonus" sample from the last
-    position's full distribution.
-
-    Returns (n_accepted, correction_token).
-    """
-    w = width
-    vocab = logits.shape[-1]
-    greedy = jnp.argmax(logits, axis=-1)  # [w]
-    kmax = min(_TOPK_CAP, vocab)
-    vals = lax.top_k(logits, kmax)[0]
-    thresh = vals[:, jnp.clip(top_k - 1, 0, kmax - 1)]
-    keep = (top_k <= 0) | (logits >= thresh[:, None])
-    scaled = jnp.where(keep, logits, -jnp.inf) / jnp.maximum(temp, 1e-6)
-    probs = jax.nn.softmax(scaled, axis=-1)  # [w, V] target distribution
-    j = jnp.arange(w - 1)
-    p_draft = probs[j, props]
-    u = jax.vmap(jax.random.uniform)(keys[:w - 1])
-    accept = jnp.where(temp > 0.0, u < p_draft, props == greedy[:w - 1])
-    # longest accepted prefix of the REAL drafts (cumprod stops at the
-    # first rejection; padding past ``count`` never counts)
-    chain = jnp.cumprod(
-        jnp.where(j < count, accept, False).astype(jnp.int32)
-    )
-    n_acc = jnp.sum(chain).astype(jnp.int32)
-    rejected = n_acc < count
-    rej_tok = props[jnp.minimum(n_acc, w - 2)]
-    corr_scaled = jnp.where(
-        rejected & (jnp.arange(vocab) == rej_tok), -jnp.inf,
-        scaled[n_acc],
-    )
-    sampled = jax.random.categorical(keys[w - 1], corr_scaled)
-    corr = jnp.where(temp > 0.0, sampled, greedy[n_acc])
-    return n_acc, corr.astype(jnp.int32)
-
-
-def _verify_tick(params, tokens_full, pool_k, pool_v, tables, lens,
-                 temps, topks, keys_full, props, counts, *, cfg, n,
-                 width, block_size):
-    """One speculative verify step over the first ``n`` lanes: embed the
-    pending input token plus up to ``width - 1`` drafted tokens per lane
-    and score all of them in ONE multi-position paged-attention pass
-    (``paged_attention`` already handles [n, T] query positions — this
-    is ``_decode_tick`` generalized from T = 1 to T = width).
-
-    K/V for every drafted position scatters into the lane's own block
-    reservation as it is computed (position ``lens + j`` attends only
-    positions ``<= lens + j``, all of which this tick or history wrote),
-    so accepted positions need no second write.  Positions past the
-    lane's draft count write to the trash block (the prefill padding
-    trick); positions past the ACCEPTED prefix hold garbage the length
-    mask never reads — the host advances ``lane.length`` only to the
-    accepted end, and the next tick overwrites from there.  Rejection
-    therefore "rewinds" by pointer arithmetic alone: no block ever
-    leaves the lane's reservation, so nothing can leak.
-
-    Returns ``(out, tokens_out, pool_k, pool_v, keys_out)`` where
-    ``out`` is ``[2, n]`` (accepted count, correction token) — one
-    host readback for the whole tick.  ``n`` and ``width`` are static:
-    executables stay ``<= len(verify_widths) * len(lane_counts)``.
-    """
-    pool_k = list(pool_k)
-    pool_v = list(pool_v)
-    w = width
-    seq = jnp.concatenate([tokens_full[:n, None], props], axis=1)  # [n,w]
-    x = jnp.take(params["embed"], seq, axis=0)  # [n,w,D]
-    pos = lens[:, None] + jnp.arange(w)[None, :]  # [n,w]
-    writable = jnp.arange(w)[None, :] <= counts[:, None]
-    hd = cfg.head_dim
-    col = jnp.minimum(pos // block_size, tables.shape[1] - 1)
-    blk = jnp.where(
-        writable, jnp.take_along_axis(tables, col, axis=1),
-        KvBlockPool.TRASH,
-    )
-    slot = pos % block_size
-    for i, layer in enumerate(params["layers"]):
-        h = _rms_norm(x, layer["ln_attn"])
-        q = _mm(h, layer["attn"]["wq"]).reshape(n, w, cfg.n_heads, hd)
-        k = _mm(h, layer["attn"]["wk"]).reshape(n, w, cfg.n_kv_heads, hd)
-        v = _mm(h, layer["attn"]["wv"]).reshape(n, w, cfg.n_kv_heads, hd)
-        q = _rope(q, pos, cfg.rope_theta)
-        k = _rope(k, pos, cfg.rope_theta)
-        pool_k[i] = pool_k[i].at[blk, slot].set(k)
-        pool_v[i] = pool_v[i].at[blk, slot].set(v)
-        attn = paged_attention(
-            q, pool_k[i], pool_v[i], tables, pos, cfg, block_size
-        )
-        out = _mm(
-            attn.reshape(n, w, cfg.n_heads * hd), layer["attn"]["wo"]
-        )
-        x = x + out.astype(x.dtype)
-        x, _ = _ffn_block(layer, x, cfg)
-    x = _rms_norm(x, params["ln_f"])
-    logits = _mm(x, params["lm_head"]).astype(jnp.float32)  # [n,w,V]
-    keys = jax.vmap(functools.partial(jax.random.split, num=w + 1))(
-        keys_full[:n]
-    )  # [n, w+1, 2]: w-1 accept draws, 1 correction sample, 1 carry
-    n_acc, corr = jax.vmap(
-        functools.partial(_accept_lane, width=w)
-    )(logits, props, counts, temps, topks, keys)
-    tokens_out = tokens_full.at[:n].set(corr)
-    keys_out = keys_full.at[:n].set(keys[:, w])
-    out = jnp.stack([n_acc, corr])  # [2, n]: one readback per tick
-    return out, tokens_out, pool_k, pool_v, keys_out
-
-
-def _prefill_chunk(params, chunk, pool_k, pool_v, table, start,
-                   prompt_len, key, temperature, top_k, *, cfg,
-                   block_size):
-    """One prefill chunk ([1, C] tokens at logical positions
-    start..start+C-1) written straight into the paged pool.
-
-    Positions >= prompt_len (bucket padding) write to the trash block
-    and are never attended (the length mask), so padding is inert.  The
-    returned token is the sampled/greedy first generation token — only
-    the FINAL chunk's return is meaningful (its chunk contains position
-    prompt_len - 1)."""
-    pool_k = list(pool_k)
-    pool_v = list(pool_v)
-    c = chunk.shape[1]
-    x = jnp.take(params["embed"], chunk, axis=0)  # [1,C,D]
-    pos = start + jnp.arange(c)  # [C] logical positions
-    writable = pos < prompt_len
-    hd = cfg.head_dim
-    blk = jnp.where(
-        writable, table[pos // block_size], KvBlockPool.TRASH
-    )
-    slot = pos % block_size
-    for i, layer in enumerate(params["layers"]):
-        h = _rms_norm(x, layer["ln_attn"])
-        q = _mm(h, layer["attn"]["wq"]).reshape(1, c, cfg.n_heads, hd)
-        k = _mm(h, layer["attn"]["wk"]).reshape(1, c, cfg.n_kv_heads, hd)
-        v = _mm(h, layer["attn"]["wv"]).reshape(1, c, cfg.n_kv_heads, hd)
-        q = _rope(q, pos[None, :], cfg.rope_theta)
-        k = _rope(k, pos[None, :], cfg.rope_theta)
-        pool_k[i] = pool_k[i].at[blk, slot].set(k[0])
-        pool_v[i] = pool_v[i].at[blk, slot].set(v[0])
-        attn = paged_attention(
-            q, pool_k[i], pool_v[i], table[None], pos[None], cfg,
-            block_size,
-        )
-        out = _mm(
-            attn.reshape(1, c, cfg.n_heads * hd), layer["attn"]["wo"]
-        )
-        x = x + out.astype(x.dtype)
-        x, _ = _ffn_block(layer, x, cfg)
-    x = _rms_norm(x, params["ln_f"])
-    last = jnp.clip(prompt_len - 1 - start, 0, c - 1)
-    xsel = jnp.take(x, last[None], axis=1)  # [1,1,D]
-    logits = _mm(xsel[:, 0], params["lm_head"]).astype(jnp.float32)[0]
-    k_sample, k_carry = jax.random.split(key)
-    tok = _select_token(logits, k_sample, temperature, top_k)
-    return tok, pool_k, pool_v, k_carry
-
-
 def _adopt(tokens, keys, slot, tok, key):
     """Install an admitted request's first token + RNG carry into lane
     ``slot`` (traced index: one executable regardless of slot)."""
     return tokens.at[slot].set(tok), keys.at[slot].set(key)
-
-
-class _DecoderPrograms:
-    """What the engine dispatches for a model family, behind one interface:
-    ``prefill`` runs one (1, C) chunk of a lane's prompt, ``tick`` one
-    (n, 1) decode step; both take the ``KvBlockPool`` and leave the arrays
-    their program returned in it.  This one is the decoder of identical
-    layers (``TransformerConfig``): a lane is its blocks, so the lane
-    arguments (``slot``, ``fresh``, ``live``: host values, which only a
-    family that uses them sends to the device) have nothing to act on."""
-
-    # why a lane's cache cannot be rebuilt from its blocks ("" = it can):
-    # the engine switches off what assumes it can
-    recurrent = ""
-
-    def __init__(self, cfg, block_size, donate_pools):
-        self.cfg, self.block_size = cfg, block_size
-        # donate the KV pool buffers (args 2/3 of the programs): the
-        # functional .at[].set update would otherwise materialize a full
-        # copy of every per-layer block pool on EACH dispatch — ~2x the
-        # dominant HBM allocation and a whole-pool copy per token.  The
-        # pool is reassigned from the outputs immediately, so the donated
-        # inputs are never touched again.
-        self.donate = (2, 3) if donate_pools else ()
-        self.flops_per_token = lm_flops_per_token(cfg)
-        self.window = None  # positions a window layer keeps, if any
-        self.prefill_jit = jax.jit(
-            functools.partial(_prefill_chunk, cfg=cfg, block_size=block_size),
-            donate_argnums=self.donate,
-        )
-
-    def attended_positions(self, max_pos, table_width):
-        """Positions a lane that ``paged_attention`` reads in a call whose
-        largest query position is ``max_pos``: the program's own rule."""
-        widths = attention_widths(table_width)
-        index = attention_width_index(max_pos, table_width, self.block_size)
-        return widths[min(index, len(widths) - 1)] * self.block_size
-
-    def prefill(self, params, kv, chunk, table, slot, start, prompt_len,
-                fresh, key, temperature, top_k):
-        tok, kv.pools["k"], kv.pools["v"], key = self.prefill_jit(
-            params, chunk, kv.pools["k"], kv.pools["v"], table, start,
-            prompt_len, key, temperature, top_k,
-        )
-        return tok, key
-
-    def make_tick(self, n):
-        return jax.jit(
-            functools.partial(
-                _decode_tick, cfg=self.cfg, n=n, block_size=self.block_size,
-            ),
-            donate_argnums=self.donate,
-        )
-
-    def tick(self, fn, params, kv, tokens, tables, lens, live, temps, topks,
-             keys):
-        tokens, kv.pools["k"], kv.pools["v"], keys = fn(
-            params, tokens, kv.pools["k"], kv.pools["v"], tables, lens,
-            temps, topks, keys,
-        )
-        return tokens, keys
-
-
-class _SambaYPrograms:
-    """The SambaY family (``models/sambay.py``) behind the interface of
-    ``_DecoderPrograms``: the same two shapes over one paged layer and the
-    lanes' fixed state, which is donated with the pools.  The programs are
-    jitted under their own names."""
-
-    recurrent = (
-        "lanes carry recurrent state (Mamba layers, window rings) that "
-        "K/V blocks alone do not rebuild"
-    )
-
-    def __init__(self, cfg, block_size, donate_pools):
-        self.cfg, self.block_size = cfg, block_size
-        self.donate = (2, 3, 4) if donate_pools else ()
-        self.flops_per_token = sambay.lm_flops_per_token(cfg)
-        self.window = cfg.window
-        self._static = dict(cfg=cfg, block_size=block_size)
-        self.prefill_jit = jax.jit(
-            sambay.sambay_prefill_chunk,
-            static_argnames=("cfg", "block_size"), donate_argnums=self.donate,
-        )
-        self._tick_jit = jax.jit(
-            sambay.sambay_decode_tick,
-            static_argnames=("cfg", "n", "block_size"),
-            donate_argnums=self.donate,
-        )
-
-    def attended_positions(self, max_pos, table_width):
-        """The whole table, whatever the lanes hold: one gather of layer
-        17's blocks feeds eight reading layers."""
-        return table_width * self.block_size
-
-    def prefill(self, params, kv, chunk, table, slot, start, prompt_len,
-                fresh, key, temperature, top_k):
-        with annotation("lm.sambay_prefill_chunk"):
-            tok, kv.pools["k"], kv.pools["v"], kv.lane_state, key = (
-                self.prefill_jit(
-                    params, chunk, kv.pools["k"], kv.pools["v"],
-                    kv.lane_state, table, jnp.int32(slot), start,
-                    prompt_len, jnp.bool_(fresh), key, temperature, top_k,
-                    **self._static,
-                )
-            )
-        return tok, key
-
-    def make_tick(self, n):
-        return functools.partial(self._tick_jit, n=n, **self._static)
-
-    def tick(self, fn, params, kv, tokens, tables, lens, live, temps, topks,
-             keys):
-        with annotation("lm.sambay_decode_tick"):
-            tokens, kv.pools["k"], kv.pools["v"], kv.lane_state, keys = fn(
-                params, tokens, kv.pools["k"], kv.pools["v"], kv.lane_state,
-                tables, lens, jnp.asarray(live), temps, topks, keys,
-            )
-        return tokens, keys
-
-
-def _programs_for(cfg, block_size):
-    """The family's programs, from the configuration's type alone."""
-    family = (_SambaYPrograms if isinstance(cfg, sambay.SambaYConfig)
-              else _DecoderPrograms)
-    # CPU (the test platform) has no donation support; jit would just warn
-    return family(cfg, block_size, jax.default_backend() != "cpu")
 
 
 class _Lane:
@@ -576,8 +221,7 @@ class _Swapped:
 
 
 class LmEngine:
-    """Continuous-batching decode engine (submit/cancel/close surface
-    compatible with the old ContinuousLmScheduler).
+    """Continuous-batching decode engine.
 
     ``submit(prompt_tokens, max_tokens, temperature=0, top_k=0, seed=0,
     tenant="")`` returns ``(queue, handle)``; the queue yields int token
@@ -656,7 +300,8 @@ class LmEngine:
         # tick — only that thread ever touches it.
         self.prof = PhaseProfiler(name="lm", registry=registry)
         self._ptick = NULL_TICK
-        # the family's programs, from the configuration alone.  Where its
+        # the family's programs, which its configuration hands out (as it
+        # does ``state_spec``): the engine knows no model.  Where its
         # lanes carry state that blocks do not rebuild, what assumes a
         # lane IS its blocks is switched off here, with the reason in the
         # stats: prefix adoption and fleet export (a block chain without
@@ -664,7 +309,7 @@ class LmEngine:
         # falls back to recompute-replay, which rebuilds the state), and
         # speculative verify (its rewind is a pointer into the lane's
         # blocks; a recurrent state has none).
-        self._programs = _programs_for(cfg, self.block_size)
+        self._programs = cfg.family(cfg, self.block_size)
         self._flops_per_token = self._programs.flops_per_token
         self._recurrent = self._programs.recurrent
         if self._recurrent and speculative is not None:
@@ -1663,14 +1308,8 @@ class LmEngine:
         with self._cv:
             fn = self._verify_jits.get((n, w))
             if fn is None:
-                fn = jax.jit(
-                    functools.partial(
-                        _verify_tick, cfg=self.cfg, n=n, width=w,
-                        block_size=self.block_size,
-                    ),
-                    donate_argnums=self._programs.donate,
-                )
-                self._verify_jits[(n, w)] = fn
+                fn = self._verify_jits[(n, w)] = self._programs.make_verify(
+                    n, w)
         return fn
 
     def _spec_pass(self, ptick):

@@ -1,36 +1,11 @@
-"""Continuous batching for decoupled LM token streaming — compat surface.
-
-The fixed-lane prototype that lived here grew into the
-``client_tpu.serve.lm`` subsystem (paged KV cache, bucketed + chunked
-prefill interleaved with decode, lane autoscaling, per-lane sampling,
-tenant-aware lane admission).  This module keeps the original names and
-submit/cancel/stream surface so existing callers and tests are
-untouched:
-
-- :class:`ContinuousLmScheduler` IS :class:`client_tpu.serve.lm.LmEngine`
-  (``submit(prompt, max_tokens) -> (queue, handle)``, ``cancel``,
-  ``close``, the ``CLOSE`` sentinel);
-- :class:`BatchedLmRunner` is the ``stream()`` provider
-  lm_streaming_batched_model plugs into — now with per-request
-  temperature / top-k / seed (per-lane RNG inside the jitted tick
-  removed the old "greedy only" 400) and a ``tenant`` identity that
-  feeds per-tenant decode-lane quotas.  Engine-level features arriving
-  after the split (speculative decoding via
-  ``lm_streaming_batched_model(speculative=...)``, prefix-cache
-  adoption, lane autoscaling) pass through this surface untouched:
-  they live below submit/cancel/stream.
-
-See ``client_tpu/serve/lm/`` for the engine internals and README
-"LLM serving / continuous batching" for the design.
-"""
+"""The ``stream()`` provider that puts :class:`LmEngine` behind a served
+model (``models/language.lm_streaming_batched_model``)."""
 
 import numpy as np
 
-from client_tpu.serve.lm.engine import _CLOSE, _TOPK_CAP, LmEngine
+from client_tpu.ops.sampling import TOPK_CAP
+from client_tpu.serve.lm.engine import LmEngine
 from client_tpu.utils import InferenceServerException
-
-# the engine, under its historical serving-path name
-ContinuousLmScheduler = LmEngine
 
 
 class BatchedLmRunner:
@@ -39,7 +14,8 @@ class BatchedLmRunner:
     batched model reuses lm_streaming_model verbatim.  Per-request
     sampling (temperature / top_k / seed) runs inside the jitted tick
     with per-lane RNG keys; temperature 0 lanes take the on-device
-    argmax, so mixed greedy/sampled batches share one executable."""
+    argmax, so mixed greedy/sampled batches share one executable.
+    ``tenant`` feeds the engine's per-tenant decode-lane quotas."""
 
     def __init__(self, params, cfg, max_slots=4, eos_id=None,
                  check_prompt=None, **engine_kwargs):
@@ -51,13 +27,13 @@ class BatchedLmRunner:
 
     def stream(self, tokens, max_tokens, temperature=0.0, seed=0,
                top_k=0, tenant=""):
-        if int(top_k) > _TOPK_CAP:
+        if int(top_k) > TOPK_CAP:
             # the jitted tick's per-lane filter has a static width: a
             # silently-truncated k would sample a different distribution
             # than the client asked for
             raise InferenceServerException(
                 f"top_k {int(top_k)} exceeds the engine's static cap of "
-                f"{_TOPK_CAP}; use top_k <= {_TOPK_CAP} or 0 (unfiltered)",
+                f"{TOPK_CAP}; use top_k <= {TOPK_CAP} or 0 (unfiltered)",
                 status="400",
             )
         if self.scheduler.check_prompt is not None:
@@ -71,7 +47,7 @@ class BatchedLmRunner:
         try:
             while True:
                 tok = q.get()
-                if tok is _CLOSE:
+                if tok is LmEngine.CLOSE:
                     return
                 yield tok
         finally:

@@ -4,8 +4,9 @@ control for the LM engine's draft/verify path.
 Speculative decoding turns one decode tick into up to ``k + 1``
 delivered tokens: a cheap host-side *drafter* proposes ``k``
 continuation tokens, the engine scores all of them (plus the pending
-input token) in ONE batched paged-attention pass (``engine._verify_tick``
-— the multi-position generalization of ``_decode_tick``), and an
+input token) in ONE batched paged-attention pass (the program the family's
+``make_verify`` gives: ``transformer.paged_verify_tick``, the
+multi-position generalization of ``paged_decode_tick``), and an
 acceptance rule keeps the longest valid prefix:
 
 - **greedy lanes** (temperature 0): a draft position is accepted iff it
@@ -22,7 +23,7 @@ acceptance rule keeps the longest valid prefix:
 - **temperature lanes**: distribution-preserving rejection sampling for
   point-mass (deterministic) drafters — draft token ``x`` at a position
   with target probability ``p(x)`` (after the lane's top-k filter and
-  temperature, exactly ``engine._select_token``'s distribution) is
+  temperature, exactly ``ops.sampling.select_token``'s distribution) is
   accepted with probability ``p(x)``; on rejection the correction token
   samples the residual (``p`` with ``x``'s mass removed, renormalized),
   which makes every delivered token an exact draw from the target

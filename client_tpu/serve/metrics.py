@@ -27,7 +27,7 @@ observability surface:
   adopted / shareable-but-cold / evicted under pool pressure),
   ``ctpu_lm_prefix_cached_blocks``, the prefill-compute accounting pair
   ``ctpu_lm_prefill_tokens_total`` / ``ctpu_lm_prefill_tokens_saved_total``
-  (the perf/bench ``prefix_hit_pct`` numerators), and
+  (the perf CLI's ``prefix_hit_pct`` numerators), and
   ``ctpu_lm_preemptions_total`` / ``ctpu_lm_swapped_blocks`` (lanes
   swapped to the host store under priority pressure), and the
   **speculative decoding** series (:data:`LM_SPEC_HELP`):
@@ -102,7 +102,7 @@ LM_SPEC_HELP = {
 
 # SLO watchdog + flight recorder series (written by serve/slo.py and
 # serve/flight.py into the engine registry; one help catalog so
-# /metrics, README, bench and tests agree).
+# /metrics, README and tests agree).
 SLO_HELP = {
     "ctpu_slo_p50_ms":
         "Windowed p50 request latency per model/tenant (sketch quantile)",

@@ -7,7 +7,7 @@ It serves two roles:
 1. Hermetic test double — the fake-server role SURVEY.md §4 calls for (the
    reference has no in-repo server; its tests need external infra).
 2. Real TPU serving path — models whose ``fn`` is a jitted JAX callable run on
-   the TPU chip, which is what bench.py measures end-to-end.
+   the TPU chip, which is what ``benchmark/run.py`` measures end-to-end.
 
 Request execution semantics (shared-memory resolution, classification
 extension, statistics accounting) follow the KServe-v2 spec the reference

@@ -2,7 +2,7 @@
 
 ``model_sets("builtin,jax,resnet,language,pipeline")`` is the single set-name
 resolver used by the serve and perf CLIs; ``jax_models()`` is the small-CNN
-vision set used by bench.py, ``resnet_models()`` the resnet50 of BASELINE
+vision set (``--models jax``), ``resnet_models()`` the resnet50 of BASELINE
 config 3, ``language_models()`` the tokenizer→streaming-LM stack of BASELINE
 config 5, and ``pipeline_models()`` the full-size vision ensemble DAG
 (preprocess → resnet50 backbone → classification postprocess).

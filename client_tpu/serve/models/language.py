@@ -18,7 +18,6 @@ import numpy as np
 import jax
 
 from client_tpu.serve.model_runtime import Model, TensorSpec
-from client_tpu.serve.models import sambay
 from client_tpu.serve.models import transformer as tfm
 from client_tpu.utils import InferenceServerException
 
@@ -28,8 +27,8 @@ _EOS = 257
 _VOCAB = 258
 
 # The hermetic serving configuration (swap for a full-size model on real
-# deployments).  Module-level so harnesses (bench.py's lm_mfu_pct) can
-# compute tfm.lm_flops_per_token without instantiating a runner's params.
+# deployments).  Module-level so harnesses can compute
+# tfm.lm_flops_per_token without instantiating a runner's params.
 DEFAULT_LM_CONFIG = tfm.TransformerConfig(
     vocab_size=_VOCAB,
     d_model=256,
@@ -98,19 +97,19 @@ def detokenizer_model(name="detokenizer"):
 
 
 class _LmRunner:
-    """Owns the params of either family (a ``TransformerConfig`` or a
-    ``sambay.SambaYConfig``) + the serial path's jitted decode programs.
-    The SambaY family is served by the continuous-batching engine alone
-    (``lm_streaming_batched_model(runner=...)``): it has no contiguous-cache
-    ``generate`` and no int8 weights."""
+    """Owns the params of any family + the serial path's jitted decode
+    programs.  What a family has is asked of ``cfg.family`` (a
+    ``TransformerConfig``'s or a ``sambay.SambaYConfig``'s): one without a
+    contiguous-cache ``generate`` is served by the continuous-batching
+    engine alone (``lm_streaming_batched_model(runner=...)``), one without
+    ``quantize_params`` has no int8 weights."""
 
     def __init__(self, cfg=None, seed=0, quantize=False, params=None):
         self.cfg = cfg or DEFAULT_LM_CONFIG
-        self.hybrid = isinstance(self.cfg, sambay.SambaYConfig)
-        if self.hybrid and quantize:
+        family = self.cfg.family
+        if quantize and family.quantize_params is None:
             raise ValueError("int8 weights cover the decoder family only")
         if params is None:
-            family = sambay if self.hybrid else tfm
             params = family.init_params(jax.random.PRNGKey(seed), self.cfg)
         self.params = params
         # id 257 ends a stream only under the byte-level tokenizer's
@@ -120,7 +119,7 @@ class _LmRunner:
         if quantize:
             # int8 weight-only serving (client_tpu.ops.quant): ~2x weight
             # capacity per chip, same decode programs via the _mm dispatch
-            self.params = tfm.quantize_params(self.params)
+            self.params = family.quantize_params(self.params)
 
     def check_prompt(self, n_prompt_tokens):
         """Reject prompts the KV cache cannot hold with a clear 400 instead
@@ -138,7 +137,8 @@ class _LmRunner:
     def stream(self, tokens, max_tokens, temperature=0.0, seed=0,
                top_k=0, tenant=""):
         self.check_prompt(int(np.asarray(tokens).reshape(-1).shape[0]))
-        if self.hybrid:
+        generate = self.cfg.family.generate
+        if generate is None:
             raise InferenceServerException(
                 "this model streams through the continuous-batching "
                 "engine only (lm_streaming_batched_model)", status="400",
@@ -150,7 +150,7 @@ class _LmRunner:
                 "distribution", status="400",
             )
         key = jax.random.PRNGKey(seed) if temperature > 0 else None
-        for tok in tfm.generate(
+        for tok in generate(
             self.params, self.cfg, tokens, max_tokens,
             temperature=temperature, key=key,
             stop_tokens=() if self.eos_id is None else (self.eos_id,),
@@ -236,7 +236,7 @@ def lm_streaming_batched_model(name="lm_streaming_batched", runner=None,
     What ends a stream early is the runner's ``eos_id``: the byte-level
     tokenizer's EOS where the vocabulary is that tokenizer's, else
     nothing but the budget."""
-    from client_tpu.serve.models.continuous import BatchedLmRunner
+    from client_tpu.serve.lm import BatchedLmRunner
 
     prefix_knobs = dict((response_cache or {}).get("prefix_cache") or {})
     if "enable" in prefix_knobs:

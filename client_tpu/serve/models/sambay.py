@@ -29,8 +29,8 @@ The family's step is written once, over a cache view, for both shapes the
 engine runs: ``decode_step`` at (n, 1) and ``prefill_step`` at (1, C).  A
 lane's fixed state (``SambaYConfig.state_spec``) is allocated by ``kv.KvBlockPool``
 beside the paged pool; the programs ``sambay_decode_tick`` and
-``sambay_prefill_chunk`` at the bottom are what the engine jits, under
-those names, so that a device trace tells them apart.
+``sambay_prefill_chunk`` are what ``SambaYPrograms`` at the bottom jits for
+the engine, under those names, so that a device trace tells them apart.
 
 Masking is what keeps the state right: a prefill bucket's padding past
 ``prompt_len`` and a decode tick's idle lanes leave every state as it was
@@ -39,6 +39,7 @@ ring writes dropped, paged writes sent to the trash block).
 """
 
 import dataclasses
+import functools
 import math
 
 import jax
@@ -47,6 +48,7 @@ from jax import lax
 
 from client_tpu.ops.quant import matmul as _mm
 from client_tpu.ops.sampling import select_token
+from client_tpu.serve.prof import annotation
 
 MAMBA, WINDOW, MEMORY, FULL, GMU, CROSS = (
     "mamba", "window", "memory", "full", "gmu", "cross")
@@ -148,6 +150,12 @@ class SambaYConfig:
             "ssm": [((self.d_state, self.d_inner),
                      jnp.dtype(self.state_dtype))] * n_rec,
         }
+
+    @property
+    def family(self):
+        """The family's programs, as ``serve/lm.LmEngine`` and
+        ``language._LmRunner`` ask every configuration for them."""
+        return SambaYPrograms
 
 
 # -- parameters -----------------------------------------------------------------
@@ -575,13 +583,13 @@ def prefill_step(params, chunk, pool_k, pool_v, state, table, slot, start,
     return _head(params, xsel)[0], cache
 
 
-# -- the two programs the engine jits ------------------------------------------
+# -- the two programs, and the family as the engine asks for it ----------------
 
 def sambay_decode_tick(params, tokens_full, pool_k, pool_v, state, tables,
                        lens, live, temps, topks, keys_full, *, cfg, n,
                        block_size):
     """One batched decode step over the first ``n`` lanes, with the token
-    choice on the device as ``engine._decode_tick`` makes it."""
+    choice on the device as ``transformer.paged_decode_tick`` makes it."""
     logits, (pool_k, pool_v, state) = decode_step(
         params, tokens_full[:n], pool_k, pool_v, state, tables, lens, live,
         cfg, block_size)
@@ -602,3 +610,65 @@ def sambay_prefill_chunk(params, chunk, pool_k, pool_v, state, table, slot,
     k_sample, k_carry = jax.random.split(key)
     tok = select_token(logits, k_sample, temperature, top_k)
     return tok, pool_k, pool_v, state, k_carry
+
+
+class SambaYPrograms:
+    """This family behind the interface of ``transformer.DecoderPrograms``,
+    handed out as ``cfg.family``: the same two shapes over one paged layer
+    and the lanes' fixed state, which is donated with the pools.  The
+    programs are jitted under their own names."""
+
+    recurrent = (
+        "lanes carry recurrent state (Mamba layers, window rings) that "
+        "K/V blocks alone do not rebuild"
+    )
+    init_params = staticmethod(init_params)
+    generate = None         # no contiguous cache: the engine alone serves it
+    quantize_params = None  # no int8 weights
+
+    def __init__(self, cfg, block_size):
+        self.cfg, self.block_size = cfg, block_size
+        # CPU (the test platform) has no donation support
+        self.donate = (2, 3, 4) if jax.default_backend() != "cpu" else ()
+        self.flops_per_token = lm_flops_per_token(cfg)
+        self.window = cfg.window
+        self._static = dict(cfg=cfg, block_size=block_size)
+        self.prefill_jit = jax.jit(
+            sambay_prefill_chunk,
+            static_argnames=("cfg", "block_size"), donate_argnums=self.donate,
+        )
+        self._tick_jit = jax.jit(
+            sambay_decode_tick,
+            static_argnames=("cfg", "n", "block_size"),
+            donate_argnums=self.donate,
+        )
+
+    def attended_positions(self, max_pos, table_width):
+        """The whole table, whatever the lanes hold: one gather of layer
+        17's blocks feeds eight reading layers."""
+        return table_width * self.block_size
+
+    def prefill(self, params, kv, chunk, table, slot, start, prompt_len,
+                fresh, key, temperature, top_k):
+        with annotation("lm.sambay_prefill_chunk"):
+            tok, kv.pools["k"], kv.pools["v"], kv.lane_state, key = (
+                self.prefill_jit(
+                    params, chunk, kv.pools["k"], kv.pools["v"],
+                    kv.lane_state, table, jnp.int32(slot), start,
+                    prompt_len, jnp.bool_(fresh), key, temperature, top_k,
+                    **self._static,
+                )
+            )
+        return tok, key
+
+    def make_tick(self, n):
+        return functools.partial(self._tick_jit, n=n, **self._static)
+
+    def tick(self, fn, params, kv, tokens, tables, lens, live, temps, topks,
+             keys):
+        with annotation("lm.sambay_decode_tick"):
+            tokens, kv.pools["k"], kv.pools["v"], kv.lane_state, keys = fn(
+                params, tokens, kv.pools["k"], kv.pools["v"], kv.lane_state,
+                tables, lens, jnp.asarray(live), temps, topks, keys,
+            )
+        return tokens, keys

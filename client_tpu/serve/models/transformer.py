@@ -34,10 +34,13 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from client_tpu.ops.quant import matmul as _mm
+from client_tpu.ops.sampling import accept_lane, select_token
 from client_tpu.parallel.ring_attention import (
     plain_attention,
     ring_attention_sharded,
 )
+from client_tpu.serve.lm.kv import KvBlockPool
+from client_tpu.serve.lm.policy import attention_width_index, attention_widths
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,6 +77,12 @@ class TransformerConfig:
         go, per-lane fixed state).  Identical layers: every one paged, a
         block ``[block_size, n_kv_heads, head_dim]``, nothing beside them."""
         return self.n_layers, (None, self.n_kv_heads, self.head_dim), {}
+
+    @property
+    def family(self):
+        """The family's programs, as ``serve/lm.LmEngine`` and
+        ``language._LmRunner`` ask every configuration for them."""
+        return DecoderPrograms
 
 
 def init_params(key, cfg):
@@ -391,11 +400,6 @@ def paged_attention(q, pool_k, pool_v, tables, pos, cfg, block_size):
     table's to rounding (PERF.md section 6, PR 30).  A table with one
     width has no loop at all.
     """
-    from client_tpu.serve.lm.policy import (
-        attention_width_index,
-        attention_widths,
-    )
-
     b, t = q.shape[:2]
     table_width = tables.shape[-1]
     widths = attention_widths(table_width)
@@ -496,6 +500,145 @@ def _attend_columns(q, pool_k, pool_v, tables, pos, first, cfg, block_size):
     rows = lambda x: x.transpose(0, 3, 1, 2, 4).reshape(b, t, cfg.n_heads, 1)
     return (rows(m), rows(jnp.sum(p, axis=-1, keepdims=True)),
             acc.reshape(b, t, cfg.n_heads, hd))
+
+
+# -- the paged decoder: one layer loop, and the three programs the engine runs --
+
+def paged_layers(params, x, pool_k, pool_v, tables, pos, blk, slot, cfg,
+                 block_size):
+    """Every layer over the embedded ``x`` [B,T,D] against the PAGED cache:
+    the one layer loop of the three programs below, which differ in the
+    shape of ``x`` and in what they do before and after it.  ``pos`` [B,T]
+    are the logical positions, ``tables`` [B,W] the lanes' block tables,
+    and ``blk``, ``slot`` [B*T] where each position's new K/V row lands in
+    a layer's pool, lane-major: ``(table[pos // block_size], pos %
+    block_size)``, or the trash block for a position that is padding.
+    Returns the final-normed ``x`` and the pools.
+
+    The new rows go in as ONE scatter of B*T rows at ``(blk, slot)``, then
+    ``paged_attention`` reads them back through the tables: position
+    ``pos`` attends positions ``<= pos``, all of which this call or an
+    earlier one wrote."""
+    b, t = x.shape[:2]
+    hd = cfg.head_dim
+    pool_k, pool_v = list(pool_k), list(pool_v)
+    for i, layer in enumerate(params["layers"]):
+        h = _rms_norm(x, layer["ln_attn"])
+        q = _mm(h, layer["attn"]["wq"]).reshape(b, t, cfg.n_heads, hd)
+        k = _mm(h, layer["attn"]["wk"]).reshape(b, t, cfg.n_kv_heads, hd)
+        v = _mm(h, layer["attn"]["wv"]).reshape(b, t, cfg.n_kv_heads, hd)
+        q = _rope(q, pos, cfg.rope_theta)
+        k = _rope(k, pos, cfg.rope_theta)
+        rows = (b * t, cfg.n_kv_heads, hd)
+        pool_k[i] = pool_k[i].at[blk, slot].set(k.reshape(rows))
+        pool_v[i] = pool_v[i].at[blk, slot].set(v.reshape(rows))
+        attn = paged_attention(
+            q, pool_k[i], pool_v[i], tables, pos, cfg, block_size
+        )
+        out = _mm(
+            attn.reshape(b, t, cfg.n_heads * hd), layer["attn"]["wo"]
+        )
+        x = x + out.astype(x.dtype)
+        x, _ = _ffn_block(layer, x, cfg)
+    return _rms_norm(x, params["ln_f"]), pool_k, pool_v
+
+
+def paged_decode_tick(params, tokens_full, pool_k, pool_v, tables, lens,
+                      temps, topks, keys_full, *, cfg, n, block_size):
+    """One batched decode step over the first ``n`` lanes (n is static:
+    one executable per configured lane count), each lane's pending token
+    at position ``lens``, the next one chosen on the device."""
+    x = jnp.take(params["embed"], tokens_full[:n], axis=0)[:, None, :]
+    blk = tables[jnp.arange(n), lens // block_size]  # [n] physical blocks
+    x, pool_k, pool_v = paged_layers(
+        params, x, pool_k, pool_v, tables, lens[:, None], blk,
+        lens % block_size, cfg, block_size)
+    logits = _mm(x[:, 0], params["lm_head"]).astype(jnp.float32)  # [n,V]
+    pairs = jax.vmap(functools.partial(jax.random.split, num=2))(
+        keys_full[:n]
+    )
+    nxt = jax.vmap(select_token)(logits, pairs[:, 0], temps, topks)
+    tokens_out = tokens_full.at[:n].set(nxt)
+    keys_out = keys_full.at[:n].set(pairs[:, 1])
+    return tokens_out, pool_k, pool_v, keys_out
+
+
+def paged_verify_tick(params, tokens_full, pool_k, pool_v, tables, lens,
+                      temps, topks, keys_full, props, counts, *, cfg, n,
+                      width, block_size):
+    """One speculative verify step over the first ``n`` lanes: embed the
+    pending input token plus up to ``width - 1`` drafted tokens per lane
+    and score all of them in ONE multi-position pass (``paged_decode_tick``
+    generalized from T = 1 to T = width).
+
+    K/V for every drafted position scatters into the lane's own block
+    reservation as it is computed (position ``lens + j`` attends only
+    positions ``<= lens + j``, all of which this tick or history wrote),
+    so accepted positions need no second write.  Positions past the
+    lane's draft count write to the trash block (the prefill padding
+    trick); positions past the ACCEPTED prefix hold garbage the length
+    mask never reads — the host advances ``lane.length`` only to the
+    accepted end, and the next tick overwrites from there.  Rejection
+    therefore "rewinds" by pointer arithmetic alone: no block ever
+    leaves the lane's reservation, so nothing can leak.
+
+    Returns ``(out, tokens_out, pool_k, pool_v, keys_out)`` where
+    ``out`` is ``[2, n]`` (accepted count, correction token) — one
+    host readback for the whole tick.  ``n`` and ``width`` are static:
+    executables stay ``<= len(verify_widths) * len(lane_counts)``.
+    """
+    w = width
+    seq = jnp.concatenate([tokens_full[:n, None], props], axis=1)  # [n,w]
+    x = jnp.take(params["embed"], seq, axis=0)  # [n,w,D]
+    pos = lens[:, None] + jnp.arange(w)[None, :]  # [n,w]
+    writable = jnp.arange(w)[None, :] <= counts[:, None]
+    col = jnp.minimum(pos // block_size, tables.shape[1] - 1)
+    blk = jnp.where(
+        writable, jnp.take_along_axis(tables, col, axis=1),
+        KvBlockPool.TRASH,
+    )
+    x, pool_k, pool_v = paged_layers(
+        params, x, pool_k, pool_v, tables, pos, blk.reshape(-1),
+        (pos % block_size).reshape(-1), cfg, block_size)
+    logits = _mm(x, params["lm_head"]).astype(jnp.float32)  # [n,w,V]
+    keys = jax.vmap(functools.partial(jax.random.split, num=w + 1))(
+        keys_full[:n]
+    )  # [n, w+1, 2]: w-1 accept draws, 1 correction sample, 1 carry
+    n_acc, corr = jax.vmap(
+        functools.partial(accept_lane, width=w)
+    )(logits, props, counts, temps, topks, keys)
+    tokens_out = tokens_full.at[:n].set(corr)
+    keys_out = keys_full.at[:n].set(keys[:, w])
+    out = jnp.stack([n_acc, corr])  # [2, n]: one readback per tick
+    return out, tokens_out, pool_k, pool_v, keys_out
+
+
+def paged_prefill_chunk(params, chunk, pool_k, pool_v, table, start,
+                        prompt_len, key, temperature, top_k, *, cfg,
+                        block_size):
+    """One prefill chunk ([1, C] tokens at logical positions
+    start..start+C-1) written straight into the paged pool.
+
+    Positions >= prompt_len (bucket padding) write to the trash block
+    and are never attended (the length mask), so padding is inert.  The
+    returned token is the sampled/greedy first generation token — only
+    the FINAL chunk's return is meaningful (its chunk contains position
+    prompt_len - 1)."""
+    c = chunk.shape[1]
+    x = jnp.take(params["embed"], chunk, axis=0)  # [1,C,D]
+    pos = start + jnp.arange(c)  # [C] logical positions
+    blk = jnp.where(
+        pos < prompt_len, table[pos // block_size], KvBlockPool.TRASH
+    )
+    x, pool_k, pool_v = paged_layers(
+        params, x, pool_k, pool_v, table[None], pos[None], blk,
+        pos % block_size, cfg, block_size)
+    last = jnp.clip(prompt_len - 1 - start, 0, c - 1)
+    xsel = jnp.take(x, last[None], axis=1)  # [1,1,D]
+    logits = _mm(xsel[:, 0], params["lm_head"]).astype(jnp.float32)[0]
+    k_sample, k_carry = jax.random.split(key)
+    tok = select_token(logits, k_sample, temperature, top_k)
+    return tok, pool_k, pool_v, k_carry
 
 
 def lm_flops_per_token(cfg, context=0):
@@ -730,3 +873,85 @@ def generate(params, cfg, prompt, max_new_tokens, temperature=0.0, key=None,
         yield t
         if t in stop:
             return
+
+
+class DecoderPrograms:
+    """A model family as the serving path sees it, handed out by the
+    family's configuration (``cfg.family``; ``sambay.SambaYPrograms`` is
+    the other one) so that neither the engine nor the runner picks a family
+    by type.
+
+    ``cfg.family(cfg, block_size)`` is what ``LmEngine`` dispatches:
+    ``prefill`` runs one (1, C) chunk of a lane's prompt, ``tick`` one
+    (n, 1) decode step, the program ``make_verify`` gives one (n, w)
+    speculative verify step; ``prefill`` and ``tick`` take the
+    ``KvBlockPool`` and leave the arrays their program returned in it.
+    This one is the decoder of identical layers (``TransformerConfig``): a
+    lane is its blocks, so the lane arguments (``slot``, ``fresh``,
+    ``live``: host values, which only a family that uses them sends to the
+    device) have nothing to act on.
+
+    On the class, what ``_LmRunner`` asks before any program exists:
+    ``init_params``, and ``generate`` / ``quantize_params``, each None in a
+    family that has no contiguous serial path or no int8 weights."""
+
+    # why a lane's cache cannot be rebuilt from its blocks ("" = it can):
+    # the engine switches off what assumes it can, and a family that sets
+    # this has no ``make_verify``
+    recurrent = ""
+    init_params = staticmethod(init_params)
+    generate = staticmethod(generate)
+    quantize_params = staticmethod(quantize_params)
+
+    def __init__(self, cfg, block_size):
+        self.cfg, self.block_size = cfg, block_size
+        # donate the KV pool buffers (args 2/3 of the programs): the
+        # functional .at[].set update would otherwise materialize a full
+        # copy of every per-layer block pool on EACH dispatch — ~2x the
+        # dominant HBM allocation and a whole-pool copy per token.  The
+        # pool is reassigned from the outputs immediately, so the donated
+        # inputs are never touched again.  CPU (the test platform) has no
+        # donation support; jit would just warn
+        self.donate = (2, 3) if jax.default_backend() != "cpu" else ()
+        self.flops_per_token = lm_flops_per_token(cfg)
+        self.window = None  # positions a window layer keeps, if any
+        self.prefill_jit = self._jit(paged_prefill_chunk)
+
+    def _jit(self, program, **static):
+        """``program`` jitted as a partial: the benchmark's trace readers
+        find the tick and the chunk under the name jit gives one, until
+        they and the programs' names change together (ROADMAP S7)."""
+        return jax.jit(
+            functools.partial(
+                program, cfg=self.cfg, block_size=self.block_size, **static),
+            donate_argnums=self.donate,
+        )
+
+    def attended_positions(self, max_pos, table_width):
+        """Positions a lane that ``paged_attention`` reads in a call whose
+        largest query position is ``max_pos``: the program's own rule."""
+        widths = attention_widths(table_width)
+        index = attention_width_index(max_pos, table_width, self.block_size)
+        return widths[min(index, len(widths) - 1)] * self.block_size
+
+    def prefill(self, params, kv, chunk, table, slot, start, prompt_len,
+                fresh, key, temperature, top_k):
+        tok, kv.pools["k"], kv.pools["v"], key = self.prefill_jit(
+            params, chunk, kv.pools["k"], kv.pools["v"], table, start,
+            prompt_len, key, temperature, top_k,
+        )
+        return tok, key
+
+    def make_tick(self, n):
+        return self._jit(paged_decode_tick, n=n)
+
+    def tick(self, fn, params, kv, tokens, tables, lens, live, temps, topks,
+             keys):
+        tokens, kv.pools["k"], kv.pools["v"], keys = fn(
+            params, tokens, kv.pools["k"], kv.pools["v"], tables, lens,
+            temps, topks, keys,
+        )
+        return tokens, keys
+
+    def make_verify(self, n, width):
+        return self._jit(paged_verify_tick, n=n, width=width)

@@ -364,7 +364,7 @@ def vision_pipeline_models(
     - ``{prefix}_pipeline``: the ensemble (IMAGE -> SCORES).
 
     Defaults are the hermetic tiny variant served by the builtin model set;
-    bench passes ``image_size=224, stages=_RESNET50_STAGES,
+    ``pipeline_models()`` passes ``image_size=224, stages=_RESNET50_STAGES,
     num_classes=1000`` for the full resnet50-backed pipeline.
     """
     runners = _VisionPipelineRunners(image_size, stages, num_classes)

@@ -24,9 +24,8 @@ no MFU — a host ratio under a device metric's name is how a run with no
 chip attached once passed for a benchmark.
 
 Surfaces: ``GET /v2/debug/prof`` (rollup JSON),
-``python -m client_tpu.profview`` (attribution tables), flight-recorder
-dumps (the last N tick profiles ride along), and bench.py's ``prof``
-block.
+``python -m client_tpu.profview`` (attribution tables) and
+flight-recorder dumps (the last N tick profiles ride along).
 
 Bracket discipline: a handle acquired with ``start_tick`` MUST reach
 ``finish`` on every exit path (``with`` handle, or ``try/finally``) —
@@ -37,9 +36,8 @@ failure makes the timeline interesting.
 
 Everything here must stay cheap enough to leave armed in production:
 one perf_counter pair per phase, one deque append per tick, no
-allocation beyond the record dict.  The measured budget (bench
-``prof_overhead_pct``, tests/test_prof.py) is <= 2% on the in-process
-headline path.
+allocation beyond the record dict.  The measured budget
+(tests/test_prof.py) is <= 2% on the in-process headline path.
 """
 
 import collections
@@ -59,8 +57,8 @@ __all__ = [
 ]
 
 # Published dense bf16 peak per chip in TFLOP/s, keyed by the exact
-# ``device_kind`` JAX reports (the MFU denominator; bench.py delegates
-# here so the table has one home).  A TPU kind that is not listed is an
+# ``device_kind`` JAX reports (the MFU denominator; the table's one
+# home in the program).  A TPU kind that is not listed is an
 # error, never a neighbour's peak: add the row, with its source, the
 # first time the program runs on that chip.
 _TPU_PEAK_BF16_TFLOPS = {
@@ -69,7 +67,7 @@ _TPU_PEAK_BF16_TFLOPS = {
 }
 
 # Phase -> attribution bucket for the compute/dispatch/device_wait/host/
-# idle split (bench's prof block, profview's summary row).  ``compute``
+# idle split (the rollup's attribution, profview's summary row).  ``compute``
 # is device time as the completion observer measured it
 # (serve/_completion.py), or a host model's own run time; the
 # dispatch-site phases are launch overhead; ``device_wait`` (the host

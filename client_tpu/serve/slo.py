@@ -264,8 +264,8 @@ class SloWatchdog:
     # -- introspection -----------------------------------------------------
 
     def check_now(self):
-        """Force an objective pass over every key (tests, bench rounds,
-        pre-scrape hooks) and return :meth:`summary`."""
+        """Force an objective pass over every key (tests, pre-scrape
+        hooks) and return :meth:`summary`."""
         with self._lock:
             items = [
                 (key, entry, entry.cur.merged(entry.prev))
@@ -277,8 +277,7 @@ class SloWatchdog:
 
     def summary(self):
         """``{"model|tenant": {p50_ms, p95_ms, p99_ms, error_rate,
-        count, breaches}}`` over the latest checked windows (JSON-safe —
-        bench rounds record this block)."""
+        count, breaches}}`` over the latest checked windows (JSON-safe)."""
         with self._lock:
             out = {}
             for (model, tenant), entry in self._keys.items():

@@ -2,8 +2,8 @@
 
 Tests run on a virtual 8-device CPU mesh, Pallas kernels interpreted, so
 sharding paths compile and execute without TPU hardware (the driver
-separately dry-runs the multi-chip path; chip_smoke.py and bench.py run on
-the real chip and do NOT import this).  Must run before jax is imported: jax
+separately dry-runs the multi-chip path; chip_smoke.py and
+benchmark/run.py run on the real chip and do NOT import this).  Must run before jax is imported: jax
 reads both variables at import.
 """
 

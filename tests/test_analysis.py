@@ -314,12 +314,11 @@ def test_current_metrics_module_passes_metric_label():
     ) == []
 
 
-def test_current_continuous_passes_every_rule():
-    """The post-fix scheduler is the motivating module: it must scan
-    clean (cancel closes active queues; prefill dispatch left the lock)."""
-    assert scan_paths(
-        [str(ROOT / "client_tpu" / "serve" / "models" / "continuous.py")]
-    ) == []
+def test_current_lm_engine_passes_every_rule():
+    """The post-fix scheduler is the motivating module: it and its
+    stream provider must scan clean (cancel closes active queues; prefill dispatch left the lock)."""
+    lm = ROOT / "client_tpu" / "serve" / "lm"
+    assert scan_paths([str(lm / "engine.py"), str(lm / "runner.py")]) == []
 
 
 # -- suppression ----------------------------------------------------------

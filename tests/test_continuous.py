@@ -1,4 +1,4 @@
-"""Continuous-batching LM decode (serve/models/continuous.py): batched
+"""Continuous-batching LM decode (serve/lm: LmEngine, BatchedLmRunner): batched
 lanes must reproduce serial greedy decoding exactly, reuse slots, survive
 cancels, and scale the serving path over concurrent streams."""
 
@@ -11,11 +11,8 @@ import pytest
 
 import jax
 
+from client_tpu.serve.lm import BatchedLmRunner, LmEngine
 from client_tpu.serve.models import transformer as tfm
-from client_tpu.serve.models.continuous import (
-    BatchedLmRunner,
-    ContinuousLmScheduler,
-)
 
 CFG = tfm.TransformerConfig(
     vocab_size=128,
@@ -42,7 +39,7 @@ def _collect(q):
     out = []
     while True:
         tok = q.get(timeout=60)
-        if tok is ContinuousLmScheduler.CLOSE:
+        if tok is LmEngine.CLOSE:
             return out
         out.append(tok)
 
@@ -50,7 +47,7 @@ def _collect(q):
 def test_concurrent_streams_match_serial(params):
     """Lanes with different prompts and lengths decode EXACTLY the serial
     greedy streams — heterogeneous positions share one batched tick."""
-    sched = ContinuousLmScheduler(params, CFG, max_slots=4)
+    sched = LmEngine(params, CFG, max_slots=4)
     try:
         prompts = [[1, 2, 3], [7, 9], [5], [11, 3, 2, 8]]
         lengths = [6, 9, 4, 7]
@@ -65,7 +62,7 @@ def test_concurrent_streams_match_serial(params):
 
 
 def test_slot_reuse_more_requests_than_lanes(params):
-    sched = ContinuousLmScheduler(params, CFG, max_slots=2)
+    sched = LmEngine(params, CFG, max_slots=2)
     try:
         prompts = [[i + 1, i + 2] for i in range(5)]
         queues = [sched.submit(p, 5)[0] for p in prompts]
@@ -76,10 +73,10 @@ def test_slot_reuse_more_requests_than_lanes(params):
 
 
 def test_cancel_frees_lane(params):
-    sched = ContinuousLmScheduler(params, CFG, max_slots=1)
+    sched = LmEngine(params, CFG, max_slots=1)
     try:
         q1, h1 = sched.submit([1, 2, 3], 30)
-        assert q1.get(timeout=60) is not ContinuousLmScheduler.CLOSE
+        assert q1.get(timeout=60) is not LmEngine.CLOSE
         sched.cancel(h1)
         # the single lane must come free for the next request
         q2, _ = sched.submit([4, 5], 4)
@@ -92,12 +89,12 @@ def test_cancel_with_pending_queue(params):
     """cancel() must work by identity while other requests are PENDING —
     entry lists hold numpy prompts, so naive `in`/`remove` membership would
     raise numpy's ambiguous-truth ValueError (regression)."""
-    sched = ContinuousLmScheduler(params, CFG, max_slots=1)
+    sched = LmEngine(params, CFG, max_slots=1)
     try:
         q1, h1 = sched.submit([1, 2, 3], 20)
         q2, h2 = sched.submit([1, 2, 3], 6)  # same-shape prompt, queued
         q3, h3 = sched.submit([9], 6)        # different-shape prompt, queued
-        assert q1.get(timeout=60) is not ContinuousLmScheduler.CLOSE
+        assert q1.get(timeout=60) is not LmEngine.CLOSE
         sched.cancel(h1)   # active lane, pending entries present
         sched.cancel(h3)   # pending entry, removed by identity
         assert _collect(q2) == _serial(params, [1, 2, 3], 6)
@@ -109,15 +106,15 @@ def test_cancel_active_slot_closes_queue(params):
     """cancel() on an ADMITTED request must enqueue CLOSE on the slot's
     queue: a public-API consumer reading the queue directly (not the
     abandoning BatchedLmRunner generator) must never hang on get()."""
-    sched = ContinuousLmScheduler(params, CFG, max_slots=1)
+    sched = LmEngine(params, CFG, max_slots=1)
     try:
         q, h = sched.submit([1, 2, 3], 30)
-        assert q.get(timeout=60) is not ContinuousLmScheduler.CLOSE
+        assert q.get(timeout=60) is not LmEngine.CLOSE
         sched.cancel(h)
         # drain whatever was in flight; the stream MUST terminate
         while True:
             tok = q.get(timeout=10)  # pre-fix: hangs forever here
-            if tok is ContinuousLmScheduler.CLOSE:
+            if tok is LmEngine.CLOSE:
                 break
         sched.cancel(h)  # idempotent: double-cancel of a released lane
     finally:
@@ -143,7 +140,7 @@ def test_submit_not_blocked_by_slow_prefill(params):
     """A slow (cold-compile) prefill must not head-of-line-block submit():
     the admission dispatch runs outside _cv (regression for the pre-fix
     _admit_locked, which held the condition lock across the compile)."""
-    sched = ContinuousLmScheduler(params, CFG, max_slots=2)
+    sched = LmEngine(params, CFG, max_slots=2)
     gate = _GatedPrefill(sched._prefill)
     sched._prefill = gate
     try:
@@ -167,7 +164,7 @@ def test_submit_not_blocked_by_slow_prefill(params):
 def test_cancel_during_prefill_closes_stream(params):
     """cancel() racing the (unlocked) prefill dispatch: the stream still
     terminates with CLOSE and the lane comes back free."""
-    sched = ContinuousLmScheduler(params, CFG, max_slots=1)
+    sched = LmEngine(params, CFG, max_slots=1)
     gate = _GatedPrefill(sched._prefill)
     sched._prefill = gate
     try:
@@ -188,7 +185,7 @@ def test_cancel_twice_during_prefill_is_idempotent(params):
     the first cancel marks the handle _CANCELLED, the second must be a
     no-op — and the lane still comes back free once _admit observes the
     marker and closes the stream."""
-    sched = ContinuousLmScheduler(params, CFG, max_slots=1)
+    sched = LmEngine(params, CFG, max_slots=1)
     gate = _GatedPrefill(sched._prefill)
     sched._prefill = gate
     try:
@@ -210,11 +207,11 @@ def test_submit_after_close_returns_closed_stream(params):
     """submit() on a closed scheduler must hand back an already-closed
     queue (reader gets CLOSE immediately) instead of queueing work no
     scheduler thread will ever admit."""
-    sched = ContinuousLmScheduler(params, CFG, max_slots=1)
+    sched = LmEngine(params, CFG, max_slots=1)
     sched.close()
     q, handle = sched.submit([1, 2, 3], 4)
     assert handle is None
-    assert q.get(timeout=10) is ContinuousLmScheduler.CLOSE
+    assert q.get(timeout=10) is LmEngine.CLOSE
     sched.cancel(handle)  # cancel of a rejected submit: no-op
 
 
@@ -222,7 +219,7 @@ def test_failing_prefill_does_not_strand_reader(params):
     """If the admission dispatch itself dies (device OOM / XLA failure on
     a cold compile), the popped entry's reader must still get CLOSE — it
     is in neither _pending nor a slot when the crash handler runs."""
-    sched = ContinuousLmScheduler(params, CFG, max_slots=1)
+    sched = LmEngine(params, CFG, max_slots=1)
 
     def exploding_prefill(*a, **kw):
         raise RuntimeError("XLA compile failed")
@@ -241,7 +238,7 @@ def test_eos_stops_stream(params):
     # find a token the model actually emits early for this prompt
     serial = _serial(params, [1, 2, 3], 4)
     eos = serial[1]
-    sched = ContinuousLmScheduler(params, CFG, max_slots=1, eos_id=eos)
+    sched = LmEngine(params, CFG, max_slots=1, eos_id=eos)
     try:
         q, _ = sched.submit([1, 2, 3], 10)
         got = _collect(q)

@@ -16,7 +16,6 @@ import pytest
 import jax
 
 from client_tpu.serve.lm import KvBlockPool, LmEngine, PrefixCache
-from client_tpu.serve.lm import engine as lm_engine
 from client_tpu.serve.lm.policy import (
     LaneAutoscaler,
     attention_width_index,
@@ -428,8 +427,8 @@ def test_top_k_above_static_cap_rejected(params):
     """The jitted tick's per-lane top-k filter has a static width: a k
     above it must 400, not silently sample a narrower distribution than
     the client asked for."""
-    from client_tpu.serve.lm.engine import _TOPK_CAP
-    from client_tpu.serve.models.continuous import BatchedLmRunner
+    from client_tpu.ops.sampling import TOPK_CAP as _TOPK_CAP
+    from client_tpu.serve.lm import BatchedLmRunner
     from client_tpu.utils import InferenceServerException
 
     runner = BatchedLmRunner(params, CFG, max_slots=1, lane_counts=(1,),
@@ -965,12 +964,12 @@ def test_decode_tick_at_a_narrow_width_agrees_with_the_whole_table(
     rng = np.random.default_rng(0)
     for lane, n in enumerate(lens):
         chunk = pad_prompt(rng.integers(1, 128, (1, n)), 16)
-        _, pool_k, pool_v, _ = lm_engine._prefill_chunk(
+        _, pool_k, pool_v, _ = tfm.paged_prefill_chunk(
             params, chunk, pool_k, pool_v, tables[lane], np.int32(0),
             np.int32(n), jax.random.PRNGKey(lane), np.float32(0),
             np.int32(0), cfg=CFG, block_size=block)
     products = []
-    real = lm_engine._mm
+    real = tfm._mm
 
     def spy(x, w):
         products.append(real(x, w))
@@ -978,7 +977,7 @@ def test_decode_tick_at_a_narrow_width_agrees_with_the_whole_table(
 
     def tick():
         """Run eagerly, so that the head's product can be seen."""
-        tokens, *_ = lm_engine._decode_tick(
+        tokens, *_ = tfm.paged_decode_tick(
             params, jax.numpy.array([3, 5, 7]), pool_k, pool_v,
             jax.numpy.asarray(tables), jax.numpy.asarray(lens),
             np.zeros(3, np.float32), np.zeros(3, np.int32),
@@ -991,9 +990,9 @@ def test_decode_tick_at_a_narrow_width_agrees_with_the_whole_table(
             q, pool_k, pool_v, tables, pos, 0, cfg, block_size)
         return (acc / l).astype(q.dtype)
 
-    monkeypatch.setattr(lm_engine, "_mm", spy)
+    monkeypatch.setattr(tfm, "_mm", spy)
     tokens, logits = tick()
-    monkeypatch.setattr(lm_engine, "paged_attention", whole_table)
+    monkeypatch.setattr(tfm, "paged_attention", whole_table)
     whole_tokens, whole_logits = tick()
     assert logits.shape == (3, CFG.vocab_size)
     np.testing.assert_allclose(logits, whole_logits, rtol=1e-5, atol=1e-5)

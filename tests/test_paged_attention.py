@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 
 from client_tpu.serve.lm import KvBlockPool
-from client_tpu.serve.lm.engine import _decode_tick
 from client_tpu.serve.lm.policy import attention_width_index, attention_widths
 from client_tpu.serve.models import transformer as tfm
 
@@ -164,7 +163,7 @@ def test_paged_attention_loops_only_where_the_table_has_widths(width, group):
 # -- the decode tick's program ------------------------------------------------
 
 def _decode_tick_args(cfg, n, table_width, block_size, n_blocks):
-    """Shapes of one ``_decode_tick`` call (no arrays: nothing runs)."""
+    """Shapes of one ``paged_decode_tick`` call (no arrays: nothing runs)."""
     sds = jax.ShapeDtypeStruct
     params = jax.eval_shape(
         lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
@@ -219,7 +218,7 @@ def test_decode_tick_never_repeats_or_widens_the_gathered_cache():
                n_layers=2, max_seq=width * block)
     args = _decode_tick_args(cfg, n, width, block, n_blocks=64)
     jaxpr = jax.make_jaxpr(functools.partial(
-        _decode_tick, cfg=cfg, n=n, block_size=block))(*args)
+        tfm.paged_decode_tick, cfg=cfg, n=n, block_size=block))(*args)
     _assert_cache_kept_at_its_width(
         ((f"{name}: {aval}", str(aval.dtype), aval.shape)
          for name, aval in _intermediates(jaxpr.jaxpr)),
@@ -265,7 +264,8 @@ def test_decode_tick_compiled_for_v5e_keeps_the_cache_at_its_width(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         _decode_tick_args(cfg, n, width, block, n_blocks=2048))
     tick = jax.jit(functools.partial(
-        _decode_tick, cfg=cfg, n=n, block_size=block), donate_argnums=(2, 3))
+        tfm.paged_decode_tick, cfg=cfg, n=n, block_size=block),
+        donate_argnums=(2, 3))
     # a module compiled for a described chip cannot be read back from the
     # persistent cache: keep it out
     was = jax.config.jax_enable_compilation_cache

@@ -11,8 +11,7 @@ Covers:
 - the server surfaces: GET /v2/debug/prof, prof_tick records in
   flight dumps, and the profview CLI (text / json / exit codes),
 - the always-on budget: one armed commit costs <= 2% of a headline
-  in-process request (same ratio bench.py records as
-  prof_overhead_pct).
+  in-process request.
 """
 
 import json
@@ -356,7 +355,7 @@ class TestOverheadBudget:
     def test_armed_commit_within_2pct_of_headline_request(self):
         """The always-on budget: one armed commit (the unary path adds
         exactly one per request) costs <= 2% of an in-process headline
-        request — same ratio bench.py records as prof_overhead_pct."""
+        request."""
         work = np.ones((384, 384), np.float32) * 1e-3
 
         def fn(inputs, params, ctx):

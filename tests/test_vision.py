@@ -1,7 +1,7 @@
 """Vision servables: resnet50 structure, FLOP accounting, forward health.
 
 The resnet50 model is BASELINE.md config 3's subject; its flops_per_item
-feeds the bench's MFU figures, so the analytic count is cross-checked against
+feeds the server's MFU figures (serve/prof.py), so the analytic count is cross-checked against
 XLA's own cost analysis here.
 """
 
