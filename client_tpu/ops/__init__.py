@@ -4,7 +4,8 @@ The serving/training compute path is XLA-compiled JAX; this package holds
 the hand-written Pallas TPU kernels for the operations where blockwise
 control over VMEM residency beats what the compiler fuses on its own —
 starting with causal flash attention (:mod:`client_tpu.ops.flash_attention`),
-the transformer family's dominant op.
+the transformer family's dominant op; :mod:`client_tpu.ops.paged_decode`
+reads a decode tick's paged K/V blocks where they lie.
 """
 
 from client_tpu.ops.flash_attention import flash_attention  # noqa: F401

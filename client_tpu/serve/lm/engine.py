@@ -93,7 +93,8 @@ _LANE_HELP = {
     "ctpu_lm_active_lanes": "Decode lanes currently streaming",
     "ctpu_lm_attended_positions": (
         "Cache positions a lane that the last tick's or chunk's attention "
-        "read (of max_seq: the table width its longest lane reached)"
+        "read (of max_seq: the table width its longest lane reached, or "
+        "that lane's own read where a tick reads each lane to its length)"
     ),
 }
 
@@ -347,6 +348,11 @@ class LmEngine:
         self._tokens = None
         self._keys = None
         self._prefill = self._programs.prefill  # one chunk's dispatch
+        # a family whose decode tick reads each lane to its own length
+        # says how far (``_tick_reads(lengths, table_width)``: asked of
+        # one family, so not among the questions both answer); without it
+        # every lane reads ``attended_positions`` of the longest
+        self._tick_reads = getattr(self._programs, "_tick_reads", None)
         self._adopt = jax.jit(_adopt)
         self._tick_jits = {}
 
@@ -426,7 +432,11 @@ class LmEngine:
         positions a lane its attention read: for the decoder the table
         width that ``policy.attention_width_index`` picks for the longest
         lane, or for a chunk's last position, so its mean over decode
-        ticks against ``max_seq`` is how far that bound engages), and on
+        ticks against ``max_seq`` is how far that bound engages; for a
+        family whose tick reads each lane to its own length, the longest
+        lane's read), ``attended_tokens`` (those positions summed over the
+        lanes: against ``context_tokens`` on decode ticks, how much of
+        what attention read was live), and on
         a ``prefill_chunk`` its bucket ``width``, the real ``tokens`` in
         it and its ``start``."""
         with self._cv:
@@ -1298,6 +1308,8 @@ class LmEngine:
         self._log_tick(
             "decode", t0, tuple(i for i, _ in active), self._tokens,
             lens[live], int(lens.max()),
+            self._tick_reads and self._tick_reads(lens[live],
+                                                  self._table_width),
         )
         return True
 
@@ -1532,7 +1544,7 @@ class LmEngine:
                           device_s=device_s)
 
     def _log_tick(self, kind, t0, slots, result=None, lengths=None,
-                  max_pos=None, **fields):
+                  max_pos=None, reads=None, **fields):
         """Append one tick_trace() entry and return it.  *result* is an
         output of the program the tick dispatched at ``t0``: the
         completion observer fills in ``t_done`` and ``device_s`` when it
@@ -1541,7 +1553,10 @@ class LmEngine:
         as ``context_tokens`` and, for a model with window layers, what
         of it a window holds as ``window_tokens``.  *max_pos* is the
         largest position the program asked from: the entry carries the
-        width its attention read for it as ``attended_positions``."""
+        width its attention read for it as ``attended_positions`` and that
+        width over the lanes as ``attended_tokens``, or, from a family
+        whose tick reads each lane to its own length, the largest and the
+        sum of those *reads*."""
         entry = {
             "kind": kind, "t0": t0, "t1": time.monotonic(), "lanes": slots,
             **fields,
@@ -1554,8 +1569,11 @@ class LmEngine:
                     sum(min(int(n), window) for n in lengths)
                 )
         if max_pos is not None:
-            entry["attended_positions"] = self._programs.attended_positions(
-                max_pos, self._table_width)
+            if not reads:
+                reads = [self._programs.attended_positions(
+                    max_pos, self._table_width)] * len(slots)
+            entry["attended_positions"] = max(reads)
+            entry["attended_tokens"] = sum(reads)
         with self._cv:
             entry["n_lanes"] = self._scaler.n_lanes
             self._tick_log.append(entry)

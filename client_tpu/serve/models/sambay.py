@@ -46,6 +46,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from client_tpu.ops.paged_decode import (
+    STEP_BLOCKS, paged_decode_attention, reads_in_place, steps_read)
 from client_tpu.ops.quant import matmul as _mm
 from client_tpu.ops.sampling import select_token
 from client_tpu.serve.prof import annotation
@@ -259,46 +261,56 @@ def _mlp(layer, x, cfg):
     return x + _mm(up * jax.nn.silu(gate), layer["mlp"]["w2"]).astype(x.dtype)
 
 
-def diff_attention(q, kk, vv, valid, mixer, l0, cfg):
-    """Differential attention of ``q`` [B,T,H,hd] over keys and values
-    [B,KV/2,S,2hd] (``SambaYConfig.kv_row``) that the caller has laid out
-    (a gather through a block table, a lane's ring, a ring beside a
-    chunk's own keys), masked by ``valid`` [B,T,S].  Query heads ``2p+m``
-    go with KV heads ``2g+m``, ``g = p // (H / KV)``; both maps of a pair
-    weigh the pair's two value heads side by side (a value of width 2 hd),
-    and the pair's output is ``RMSNorm(a_0 - lambda a_1) * (1 - l0)``.
-
-    As ``transformer.paged_attention`` does, the queries of a KV pair are
-    contracted against the pair's rows as they are stored: ONE
-    ``dot_general`` with batch dimensions (lane, KV pair), float32
-    accumulation, no repeat and no copy.  A row of keys holds both maps'
-    keys, so map ``m``'s query is laid into its half of a 2hd-wide row of
-    zeros: the product over the whole row is ``q_(2p+m) . k_(2g+m)``
-    exactly, for twice the multiplications of a matrix unit that a decode
-    tick leaves idle, and the keys are never split or transposed.
-    Returns [B,T,H*hd]."""
+def _pair_queries(q, cfg):
+    """``q`` [B,T,H,hd] as a KV pair's rows meet them, [B,T,KV/2,r,2,2hd]
+    with ``r = H / KV``: query heads ``2p+m`` go with KV heads ``2g+m``,
+    ``g = p // r``, and a row of keys holds both maps' keys
+    (``SambaYConfig.kv_row``), so map ``m``'s query is laid into its half
+    of a 2hd-wide row of zeros, scaled by ``hd ** -0.5``: the product over
+    the whole row is ``q_(2p+m) . k_(2g+m)`` exactly, for twice the
+    multiplications of a matrix unit that a decode tick leaves idle, and
+    the keys are never split or transposed."""
     b, t = q.shape[:2]
     hd = cfg.head_dim
-    g = cfg.n_kv_heads // 2
-    r = cfg.n_heads // cfg.n_kv_heads
-    qg = q.reshape(b, t, g, r, 2, hd)
+    qg = q.reshape(b, t, cfg.n_kv_heads // 2, cfg.n_heads // cfg.n_kv_heads,
+                   2, hd) * jnp.asarray(hd ** -0.5, q.dtype)
     zeros = jnp.zeros_like(qg[..., 0, :])
-    qg = jnp.stack([jnp.concatenate([qg[..., 0, :], zeros], axis=-1),
-                    jnp.concatenate([zeros, qg[..., 1, :]], axis=-1)],
-                   axis=-2)                                # [B,T,g,r,2,2hd]
-    s = jnp.einsum("btgrme,bgse->bgrmts", qg, kk,
-                   preferred_element_type=jnp.float32) * (hd ** -0.5)
-    s = jnp.where(valid[:, None, None, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    a = jnp.einsum("bgrmts,bgse->btgrme", p.astype(vv.dtype), vv,
-                   preferred_element_type=jnp.float32)
+    return jnp.stack([jnp.concatenate([qg[..., 0, :], zeros], axis=-1),
+                      jnp.concatenate([zeros, qg[..., 1, :]], axis=-1)],
+                     axis=-2)
+
+
+def _diff_out(a, mixer, l0, cfg):
+    """The two maps' weighted sums ``a`` [B,T,KV/2,r,2,2hd] float32 (both
+    maps of a pair weigh the pair's two value heads side by side) to the
+    pair's output ``RMSNorm(a_0 - lambda a_1) * (1 - l0)``: float32
+    [B,T,H*hd]."""
     lam = (jnp.exp(jnp.sum(mixer["lq1"] * mixer["lk1"]))
            - jnp.exp(jnp.sum(mixer["lq2"] * mixer["lk2"])) + l0)
     diff = a[..., 0, :] - lam * a[..., 1, :]          # [B,T,g,r,2hd] float32
     var = jnp.mean(diff * diff, axis=-1, keepdims=True)
     out = diff * lax.rsqrt(var + cfg.norm_eps) \
         * mixer["subln"].astype(jnp.float32) * (1.0 - l0)
-    return out.reshape(b, t, cfg.n_heads * hd).astype(q.dtype)
+    return out.reshape(a.shape[:2] + (cfg.n_heads * cfg.head_dim,))
+
+
+def diff_attention(q, kk, vv, valid, mixer, l0, cfg):
+    """Differential attention of ``q`` [B,T,H,hd] over keys and values
+    [B,KV/2,S,2hd] (``SambaYConfig.kv_row``) that the caller has laid out
+    (a gather through a block table, a lane's ring, a ring beside a
+    chunk's own keys), masked by ``valid`` [B,T,S].
+
+    As ``transformer.paged_attention`` does, the queries of a KV pair
+    (:func:`_pair_queries`) are contracted against the pair's rows as they
+    are stored: ONE ``dot_general`` with batch dimensions (lane, KV pair),
+    float32 accumulation, no repeat and no copy.  Returns [B,T,H*hd]."""
+    s = jnp.einsum("btgrme,bgse->bgrmts", _pair_queries(q, cfg), kk,
+                   preferred_element_type=jnp.float32)
+    s = jnp.where(valid[:, None, None, None], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    a = jnp.einsum("bgrmts,bgse->btgrme", p.astype(vv.dtype), vv,
+                   preferred_element_type=jnp.float32)
+    return _diff_out(a, mixer, l0, cfg).astype(q.dtype)
 
 
 SCAN_UNROLL = 8  # scan steps a loop iteration of a prefill chunk
@@ -387,7 +399,7 @@ def _layers(params, x, cache, cfg, view):
     state = {name: list(arrays) for name, arrays in state.items()}
     x = x.astype(jnp.float32)
     win = rec = 0
-    memory = gathered = None
+    memory = None
     for i, (kind, layer) in enumerate(zip(cfg.kinds, params["layers"])):
         mixer = layer["mixer"]
         h = _layer_norm(x, layer["ln_mix"], cfg)
@@ -411,10 +423,8 @@ def _layers(params, x, cache, cfg, view):
             q, k, v = _split_qkv(mixer, h, cfg)
             pool_k = [view.paged_write(pool_k[0], k)]
             pool_v = [view.paged_write(pool_v[0], v)]
-            # gathered once: the cross layers read this layer's keys
-            gathered = view.paged_keys(pool_k[0], pool_v[0])
-            out = _attn_out(mixer, diff_attention(q, *gathered, mixer, l0,
-                                                  cfg))
+            out = _attn_out(mixer, view.attend(q, pool_k[0], pool_v[0],
+                                               mixer, l0))
         elif kind == GMU:
             out = _mm(jax.nn.silu(_mm(h, mixer["w_in"])) * memory,
                       mixer["w_out"])
@@ -422,8 +432,8 @@ def _layers(params, x, cache, cfg, view):
             b, t = h.shape[:2]
             q = (_mm(h, mixer["wq"]) + mixer["bq"]).reshape(
                 b, t, cfg.n_heads, cfg.head_dim)
-            out = _attn_out(mixer, diff_attention(q, *gathered, mixer, l0,
-                                                  cfg))
+            out = _attn_out(mixer, view.attend(q, pool_k[0], pool_v[0],
+                                               mixer, l0))
         x = x + out.astype(x.dtype)
         x = _mlp(layer, x, cfg)
     return _layer_norm(x, params["ln_f"], cfg), (pool_k, pool_v, state)
@@ -457,6 +467,15 @@ def _gather_blocks(pool, tables):
         b, pairs, width * block, wide)
 
 
+def _attend_gathered(view, q, pool_k, pool_v, mixer, l0):
+    """The paged layer through ``view.paged_keys``: every lane's whole
+    logical cache, gathered at the full layer and kept on the view for the
+    cross layers (nothing writes the pool in between)."""
+    if view.gathered is None:
+        view.gathered = view.paged_keys(pool_k, pool_v)
+    return diff_attention(q, *view.gathered, mixer, l0, view.cfg)
+
+
 class _DecodeView:
     """n lanes, one position each, at ``pos`` [n]; ``live`` [n] masks the
     lanes that are not in the tick (idle, mid-prefill, at their budget):
@@ -466,6 +485,7 @@ class _DecodeView:
         self.cfg, self.n, self.tables, self.pos, self.live = (
             cfg, n, tables, pos, live)
         self.block_size = block_size
+        self.gathered = None
         self.n_real = live.astype(jnp.int32)
         self.lane = jnp.arange(n)
 
@@ -500,6 +520,23 @@ class _DecodeView:
         return (_gather_blocks(pool_k, self.tables),
                 _gather_blocks(pool_v, self.tables), valid[:, None, :])
 
+    def attend(self, q, pool_k, pool_v, mixer, l0):
+        """Differential attention of ``q`` [n,1,H,hd] over the lanes' paged
+        caches, this tick's row written.  Where the kernel can take the
+        pool's blocks as they lie (``paged_decode.reads_in_place``: whole
+        tiles on the chip, anything interpreted) it reads each lane's
+        blocks in place up to that lane's own length, and a lane that is
+        not in the tick reads nothing; otherwise the gather of every
+        lane's whole table."""
+        if not reads_in_place(pool_k):
+            return _attend_gathered(self, q, pool_k, pool_v, mixer, l0)
+        qg = _pair_queries(q, self.cfg)[:, 0]            # [n,g,r,2,2hd]
+        a = paged_decode_attention(
+            qg.reshape(qg.shape[:2] + (-1, qg.shape[-1])), pool_k, pool_v,
+            self.tables, jnp.where(self.live, self.pos + 1, 0))
+        return _diff_out(a.reshape(qg.shape)[:, None], mixer, l0,
+                         self.cfg).astype(q.dtype)
+
 
 class _PrefillView:
     """One lane (``slot``), C positions from ``start``; those at or past
@@ -510,6 +547,7 @@ class _PrefillView:
                  block_size):
         self.cfg, self.table, self.slot, self.start = cfg, table, slot, start
         self.fresh, self.block_size = fresh, block_size
+        self.gathered = None
         self.pos = start + jnp.arange(width)
         self.real = self.pos < prompt_len
         self.end = jnp.minimum(prompt_len, start + width)  # real positions
@@ -556,6 +594,11 @@ class _PrefillView:
         valid = jnp.arange(s_len)[None, :] <= self.pos[:, None]
         return (_gather_blocks(pool_k, self.table[None]),
                 _gather_blocks(pool_v, self.table[None]), valid[None])
+
+    def attend(self, q, pool_k, pool_v, mixer, l0):
+        """A chunk is FLOPs bound at its hundreds of query rows: the
+        gather of the one lane's table."""
+        return _attend_gathered(self, q, pool_k, pool_v, mixer, l0)
 
 
 def decode_step(params, tokens, pool_k, pool_v, state, tables, lens, live,
@@ -632,6 +675,9 @@ class SambaYPrograms:
         self.donate = (2, 3, 4) if jax.default_backend() != "cpu" else ()
         self.flops_per_token = lm_flops_per_token(cfg)
         self.window = cfg.window
+        # what ``_DecodeView.attend`` will find of the pool's blocks
+        self._in_place = reads_in_place(jax.ShapeDtypeStruct(
+            (block_size, cfg.kv_row[1]), cfg.jdtype))
         self._static = dict(cfg=cfg, block_size=block_size)
         self.prefill_jit = jax.jit(
             sambay_prefill_chunk,
@@ -644,9 +690,20 @@ class SambaYPrograms:
         )
 
     def attended_positions(self, max_pos, table_width):
-        """The whole table, whatever the lanes hold: one gather of layer
-        17's blocks feeds eight reading layers."""
+        """A chunk's read: the whole table, whatever the lane holds (the
+        gather of ``_PrefillView``).  A decode tick's is :meth:`_tick_reads`."""
         return table_width * self.block_size
+
+    def _tick_reads(self, lengths, table_width):
+        """The cache positions of each lane that a decode tick's attention
+        reads, for lanes at ``lengths`` (an array) before the tick's
+        write: each lane's own length, this tick's row with it, rounded up
+        to the kernel's step (its trip count, ``paged_decode.steps_read``);
+        the whole table a lane where the tick gathers instead."""
+        if not self._in_place:
+            return [table_width * self.block_size] * len(lengths)
+        return (steps_read(lengths + 1, self.block_size)
+                * (STEP_BLOCKS * self.block_size)).tolist()
 
     def prefill(self, params, kv, chunk, table, slot, start, prompt_len,
                 fresh, key, temperature, top_k):

@@ -288,3 +288,74 @@ def test_decode_tick_compiled_for_v5e_keeps_the_cache_at_its_width(
     assert not copied, copied[:2]
     aliases = re.search(r"input_output_alias=\{(.*?)\}, entry_comp", text)
     assert aliases.group(1).count("-alias") == 2 * n_layers
+
+
+def test_sambay_decode_tick_compiled_for_v5e_reads_the_pool_in_place(
+        one_chip, monkeypatch):
+    """The reason cell's tick (Phi-4-mini-flash-reasoning's widths and 32
+    layers but for the vocabulary, 32 lanes, a table of 192 columns) as the
+    v5e's compiler leaves it: the full layer and the seven cross layers
+    are eight calls of the one kernel, which take the pool in the layout
+    the scatter leaves it (a copy would be 0.5 GB a tick); nothing has a
+    lane's whole logical cache or the gathered blocks (PR 31 had both, 251
+    MB each, for keys and for values), and the pools and every lane's
+    state are still outputs that alias their donated arguments."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from client_tpu.serve.models import sambay
+
+    n, width, block, n_blocks = 32, 192, 16, 6144
+    cfg = sambay.SambaYConfig(vocab_size=4096, max_seq=width * block)
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    _, in_block, lane_spec = cfg.state_spec
+    pool = shaped((n_blocks + 1,) + tuple(
+        block if d is None else d for d in in_block), cfg.jdtype)
+    state = {name: [shaped((n,) + tuple(shape), dtype)
+                    for shape, dtype in layers]
+             for name, layers in lane_spec.items()}
+    params = jax.tree_util.tree_map(
+        lambda a: shaped(a.shape, a.dtype),
+        jax.eval_shape(functools.partial(sambay.init_params, cfg=cfg),
+                       jax.random.PRNGKey(0)))
+    keys = jax.eval_shape(lambda: jax.random.split(jax.random.PRNGKey(0), n))
+    args = (params, shaped((n,), "int32"), [pool], [pool], state,
+            shaped((n, width), "int32"), shaped((n,), "int32"),
+            shaped((n,), "bool"), shaped((n,), "float32"),
+            shaped((n,), "int32"), shaped(keys.shape, keys.dtype))
+    tick = jax.jit(functools.partial(
+        sambay.sambay_decode_tick, cfg=cfg, n=n, block_size=block),
+        donate_argnums=(2, 3, 4))
+    # the step asks the backend whether to compile the kernel or interpret
+    # it: here it is compiled for the chip that is described
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = tick.lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    readers = cfg.kinds.count(sambay.FULL) + cfg.kinds.count(sambay.CROSS)
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) \
+        == readers == 8
+    pairs, wide = cfg.kv_row
+    shapes = {tuple(int(d) for d in dims.split(","))
+              for dims in re.findall(r"\b(?:f32|bf16)\[([\d,]+)\]", text)}
+    gathered = {s for s in shapes
+                if s[-3:] == (pairs, width * block, wide)
+                or s == (n * width, pairs, block, wide)
+                or s == (n, pairs, width, block, wide)
+                or s == (n, width, pairs, block, wide)}
+    assert not gathered, gathered
+    pool_text = rf"bf16\[{n_blocks + 1},{pairs},{block},{wide}\]"
+    copied = re.findall(
+        rf"= \(?{pool_text}[^=]* (?:copy|copy-start|slice-start)\(.*", text)
+    assert not copied, copied[:2]
+    aliases = re.search(r"input_output_alias=\{(.*?)\}, entry_comp", text)
+    assert aliases.group(1).count("-alias") == 2 + sum(
+        len(layers) for layers in state.values())

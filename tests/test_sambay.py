@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmark import reference_sambay, weights_sambay
+from client_tpu.ops import paged_decode
 from client_tpu.serve.lm import KvBlockPool, LmEngine
 from client_tpu.serve.lm.policy import chunk_plan, geometric_buckets, pad_prompt
 from client_tpu.serve.metrics import Registry
@@ -438,13 +439,16 @@ def test_pool_allocates_one_paged_layer_and_the_lanes_fixed_state():
 
 
 def test_ticks_count_context_and_window_tokens_for_both_families(params):
-    eng = _engine(params)
+    reg = Registry()
+    eng = _engine(params, registry=reg)
     try:
         prompt = _prompt(51, 21)
         assert len(_collect(eng.submit(prompt, 5)[0])) == 5
         ticks = eng.tick_trace()
     finally:
         eng.close()
+    assert reg.get("ctpu_lm_attended_positions") == \
+        ticks[-1]["attended_positions"]
     chunks = [t for t in ticks if t["kind"] == "prefill_chunk"]
     assert [(t["start"], t["width"], t["tokens"]) for t in chunks] == [
         (0, 16, 16), (16, 16, 5)]
@@ -455,6 +459,16 @@ def test_ticks_count_context_and_window_tokens_for_both_families(params):
     # the lane's length before its write
     assert [t["context_tokens"] for t in decodes][:4] == [21, 22, 23, 24]
     assert all(t["window_tokens"] == 8 * len(t["lanes"]) for t in decodes)
+    # what attention read: a chunk gathers its lane's whole table (64
+    # positions here); a decode tick reads each lane to its own length,
+    # this tick's row with it, in whole steps of the kernel
+    assert all(t["attended_tokens"] == t["attended_positions"] == 64
+               for t in chunks)
+    span = paged_decode.STEP_BLOCKS * BLOCK
+    assert [t["attended_tokens"] for t in decodes][:4] == [
+        -(-(n + 1) // span) * span for n in (21, 22, 23, 24)]
+    assert all(t["attended_positions"] == t["attended_tokens"]
+               for t in decodes)
 
     dense = tfm.TransformerConfig(vocab_size=64, d_model=32, n_layers=2,
                                   n_heads=2, n_kv_heads=1, d_ff=64,
@@ -473,6 +487,10 @@ def test_ticks_count_context_and_window_tokens_for_both_families(params):
     decodes = [t for t in ticks if t["kind"] == "decode"]
     assert [t["context_tokens"] for t in decodes][:2] == [10, 11]
     assert all("window_tokens" not in t for t in ticks)
+    # the decoder reads one width for every lane of a call
+    assert all(t["attended_tokens"]
+               == t["attended_positions"] * len(t["lanes"])
+               for t in [chunk] + decodes)
     assert eng.prefix_stats().get("enabled") is not False
 
 
