@@ -5,7 +5,10 @@ the hand-written Pallas TPU kernels for the operations where blockwise
 control over VMEM residency beats what the compiler fuses on its own —
 starting with causal flash attention (:mod:`client_tpu.ops.flash_attention`),
 the transformer family's dominant op; :mod:`client_tpu.ops.paged_decode`
-reads a decode tick's paged K/V blocks where they lie.
+reads a decode tick's paged K/V blocks where they lie (a window layer's
+from the lane's first visible position); :mod:`client_tpu.ops.grouped_matmul`
+is an expert layer's product of rows sorted by expert, each group against
+its own expert's matrix.
 """
 
 from client_tpu.ops.flash_attention import flash_attention  # noqa: F401

@@ -17,6 +17,13 @@ sum are float32 (the streaming softmax of ``client_tpu.ops.flash_attention``);
 keys, values and queries go to the matrix unit in the type they are stored
 in.  A lane of length 0 copies nothing and gets zeros.
 
+A lane may also say where its read STARTS (``starts``: a window layer's
+``length - window``): its walk then begins at the step that holds that
+position, the steps before it are neither copied nor contracted, and the
+positions of that step before the start are masked like those past the
+length.  Without ``starts`` the call lowers to the module it was before the
+argument existed: every use of it below is a Python ``None`` test.
+
 To the kernel this is plain grouped attention: ``rows`` query rows a KV
 head.  What the rows mean (differential attention's two maps, in
 ``serve/models/sambay.py``) is the caller's.
@@ -63,16 +70,25 @@ def reads_in_place(pool):
     return wide % 128 == 0 and block % (32 // pool.dtype.itemsize) == 0
 
 
-def _kernel(tables_ref, lengths_ref, q_ref, pool_k, pool_v, o_ref,
-            k_buf, v_buf, sems, m_ref, l_ref, acc_ref, at_ref, *, width,
-            block):
+def _kernel(*refs, width, block, windowed):
     """One lane.  ``at_ref`` [2] carries from lane to lane which buffer
     the next step reads and whether a step before it has already started
-    that step's copies."""
+    that step's copies.  ``windowed``: a third prefetched vector gives each
+    lane's first position."""
+    if windowed:
+        tables_ref, lengths_ref, starts_ref, *refs = refs
+    else:
+        (tables_ref, lengths_ref, *refs), starts_ref = refs, None
+    (q_ref, pool_k, pool_v, o_ref, k_buf, v_buf, sems, m_ref, l_ref, acc_ref,
+     at_ref) = refs
     lane, n = pl.program_id(0), pl.num_programs(0)
     span = STEP_BLOCKS * block
     length = lengths_ref[lane]
     trips = steps_read(length, block)
+
+    def first_step(of_lane):
+        """The step that holds ``of_lane``'s first position."""
+        return 0 if starts_ref is None else starts_ref[of_lane] // span
 
     def each_copy(of_lane, step, slot, act):
         """``act`` (``"start"`` or ``"wait"``) on the copy of every block
@@ -102,7 +118,7 @@ def _kernel(tables_ref, lengths_ref, q_ref, pool_k, pool_v, o_ref,
 
     @pl.when((trips > 0) & (at_ref[1] == 0))
     def _prime():
-        each_copy(lane, 0, at_ref[0], "start")
+        each_copy(lane, first_step(lane), at_ref[0], "start")
 
     m_ref[...] = jnp.full_like(m_ref, _NEG)
     l_ref[...] = jnp.zeros_like(l_ref)
@@ -120,7 +136,11 @@ def _kernel(tables_ref, lengths_ref, q_ref, pool_k, pool_v, o_ref,
         slot = at_ref[0]
         last = i + 1 == trips
         ahead_lane = lax.cond(last, next_lane, lambda: lane)
-        ahead_step = jnp.where(last, 0, i + 1)
+        if starts_ref is None:
+            ahead_step = jnp.where(last, 0, i + 1)
+        else:  # (no lane follows the last: clamped, and never started)
+            ahead_step = jnp.where(
+                last, first_step(jnp.minimum(ahead_lane, n - 1)), i + 1)
 
         @pl.when(ahead_lane < n)
         def _ahead():
@@ -133,7 +153,10 @@ def _kernel(tables_ref, lengths_ref, q_ref, pool_k, pool_v, o_ref,
         s = jnp.einsum("grd,gtd->grt", q, k_buf[slot],
                        preferred_element_type=jnp.float32)
         at = i * span + lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where(at < length, s, _NEG)
+        live = at < length
+        if starts_ref is not None:
+            live &= at >= starts_ref[lane]
+        s = jnp.where(live, s, _NEG)
         m = m_ref[...]
         new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         corr = jnp.exp(m - new_m)
@@ -146,12 +169,12 @@ def _kernel(tables_ref, lengths_ref, q_ref, pool_k, pool_v, o_ref,
         at_ref[0] = 1 - slot
         return _
 
-    lax.fori_loop(0, trips, step, None)
+    lax.fori_loop(first_step(lane), trips, step, None)
     # a lane of length 0 took no step: 0 / tiny
     o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
 
 
-def paged_decode_attention(q, pool_k, pool_v, tables, lengths,
+def paged_decode_attention(q, pool_k, pool_v, tables, lengths, starts=None,
                            interpret=None):
     """Softmax attention of one query position a lane over its paged cache.
 
@@ -162,6 +185,9 @@ def paged_decode_attention(q, pool_k, pool_v, tables, lengths,
         or past ``ceil(length / block)`` are never read.
       lengths: [n] int32; positions ``0 .. length - 1`` are attended.  A
         lane with 0 gets zeros.
+      starts: None, or [n] int32 under ``lengths``: positions ``start ..
+        length - 1`` are attended, and the steps of ``STEP_BLOCKS`` blocks
+        that lie wholly before ``start`` are not read.
 
     Returns [n, heads, rows, width] float32 weighted sums.
     """
@@ -172,11 +198,14 @@ def paged_decode_attention(q, pool_k, pool_v, tables, lengths,
     span = STEP_BLOCKS * block
     lane_block = pl.BlockSpec((1, heads, rows, wide),
                               lambda lane, *_: (lane, 0, 0, 0))
+    prefetched = [tables.reshape(-1), lengths] + (
+        [] if starts is None else [starts])
     return pl.pallas_call(
-        functools.partial(_kernel, width=tables.shape[1], block=block),
+        functools.partial(_kernel, width=tables.shape[1], block=block,
+                          windowed=starts is not None),
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=len(prefetched),
             grid=(n,),
             in_specs=[lane_block,
                       pl.BlockSpec(memory_space=pl.ANY),
@@ -195,5 +224,4 @@ def paged_decode_attention(q, pool_k, pool_v, tables, lengths,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(tables.reshape(-1).astype(jnp.int32), lengths.astype(jnp.int32),
-      q, pool_k, pool_v)
+    )(*(a.astype(jnp.int32) for a in prefetched), q, pool_k, pool_v)

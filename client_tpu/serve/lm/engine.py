@@ -306,17 +306,23 @@ class LmEngine:
         # lanes carry state that blocks do not rebuild, what assumes a
         # lane IS its blocks is switched off here, with the reason in the
         # stats: prefix adoption and fleet export (a block chain without
-        # the state at its end is no cache), the host swap (preemption
-        # falls back to recompute-replay, which rebuilds the state), and
-        # speculative verify (its rewind is a pointer into the lane's
-        # blocks; a recurrent state has none).
+        # the state at its end is no cache) and the host swap (preemption
+        # falls back to recompute-replay, which rebuilds the state).
+        # Speculative decoding needs the family's verify program
+        # (``make_verify``): a family without one is refused here, for its
+        # own reason (a recurrent state has no pointer to rewind; another
+        # family says why in ``no_verify``), so that no family can reach
+        # ``_verify_for`` without a program.
         self._programs = cfg.family(cfg, self.block_size)
         self._flops_per_token = self._programs.flops_per_token
         self._recurrent = self._programs.recurrent
-        if self._recurrent and speculative is not None:
+        self._no_verify = "" if hasattr(self._programs, "make_verify") else (
+            self._recurrent or getattr(self._programs, "no_verify", "")
+            or "the family has no verify program")
+        if self._no_verify and speculative is not None:
             raise ValueError(
                 "speculative decoding is not available for this model: "
-                + self._recurrent
+                + self._no_verify
             )
         if self._recurrent:
             prefix_cache, swap_block_limit = False, 0
@@ -353,6 +359,13 @@ class LmEngine:
         # one family, so not among the questions both answer); without it
         # every lane reads ``attended_positions`` of the longest
         self._tick_reads = getattr(self._programs, "_tick_reads", None)
+        # a family may count more for an entry of tick_trace(): on the host
+        # from the entry's own lengths (``tick_fields``), and on the device
+        # (``counters`` names the int32 vector its programs return beside
+        # their tokens, with the series each feeds).  The engine knows none
+        # of the names.
+        self._tick_fields = getattr(self._programs, "tick_fields", None)
+        self._counters = getattr(self._programs, "counters", ())
         self._adopt = jax.jit(_adopt)
         self._tick_jits = {}
 
@@ -402,8 +415,8 @@ class LmEngine:
         ``enabled`` False with the ``reason`` for a model that cannot have
         it)."""
         with self._cv:
-            if self._recurrent:
-                return {"enabled": False, "reason": self._recurrent}
+            if self._no_verify:
+                return {"enabled": False, "reason": self._no_verify}
             if self._spec is None:
                 return {}
             prop, acc = self._spec_proposed, self._spec_accepted
@@ -438,7 +451,11 @@ class LmEngine:
         lanes: against ``context_tokens`` on decode ticks, how much of
         what attention read was live), and on
         a ``prefill_chunk`` its bucket ``width``, the real ``tokens`` in
-        it and its ``start``."""
+        it and its ``start``.  A family may add fields of its own: what
+        its ``tick_fields`` counts on the host when the entry is written,
+        and, once the device work has completed, what its programs counted
+        on the device (its ``counters``: the vector comes to the host with
+        the tokens)."""
         with self._cv:
             return [dict(entry) for entry in self._tick_log]
 
@@ -1070,7 +1087,7 @@ class LmEngine:
         )
         t0 = time.monotonic()
         # the job's first chunk starts the lane's fixed state from zero
-        tok, job.key = self._prefill(
+        tok, job.key, *counted = self._prefill(
             self.params, self.kv, jnp.asarray(chunk),
             jnp.asarray(job.table), job.slot, jnp.int32(start),
             jnp.int32(handle.prompt_len), job.chunk_idx == 0, job.key,
@@ -1082,7 +1099,8 @@ class LmEngine:
         tokens = min(start + width, handle.prompt_len) - start
         entry = self._log_tick(
             "prefill_chunk", t0, (job.slot,), tok, [start + tokens],
-            start + width - 1, width=width, tokens=tokens, start=start,
+            start + width - 1, counted=counted, width=width, tokens=tokens,
+            start=start,
         )
         if self.registry is not None:
             self.registry.inc(
@@ -1298,7 +1316,7 @@ class LmEngine:
         # ``live``: a lane outside the batch keeps its fixed state as it is
         # (one mid-prefill carries it from chunk to chunk)
         live = np.array([i in included for i in range(n)])
-        self._tokens, self._keys = self._programs.tick(
+        self._tokens, self._keys, *counted = self._programs.tick(
             self._tick_for(n), self.params, self.kv, self._tokens,
             jnp.asarray(tables), jnp.asarray(lens), live,
             jnp.asarray(temps), jnp.asarray(topks), self._keys,
@@ -1310,6 +1328,7 @@ class LmEngine:
             lens[live], int(lens.max()),
             self._tick_reads and self._tick_reads(lens[live],
                                                   self._table_width),
+            counted=counted,
         )
         return True
 
@@ -1544,7 +1563,7 @@ class LmEngine:
                           device_s=device_s)
 
     def _log_tick(self, kind, t0, slots, result=None, lengths=None,
-                  max_pos=None, reads=None, **fields):
+                  max_pos=None, reads=None, counted=(), **fields):
         """Append one tick_trace() entry and return it.  *result* is an
         output of the program the tick dispatched at ``t0``: the
         completion observer fills in ``t_done`` and ``device_s`` when it
@@ -1556,12 +1575,19 @@ class LmEngine:
         width its attention read for it as ``attended_positions`` and that
         width over the lanes as ``attended_tokens``, or, from a family
         whose tick reads each lane to its own length, the largest and the
-        sum of those *reads*."""
+        sum of those *reads*.  *counted* holds, from a family whose programs
+        count on the device, the vector they returned: it starts for the
+        host here, beside the tokens, and ``_tick_done`` writes it into the
+        entry under the family's names."""
         entry = {
             "kind": kind, "t0": t0, "t1": time.monotonic(), "lanes": slots,
             **fields,
         }
+        for vector in counted:
+            vector.copy_to_host_async()
         if lengths is not None:
+            if self._tick_fields is not None:
+                entry.update(self._tick_fields(kind, lengths, **fields))
             entry["context_tokens"] = int(sum(lengths))
             window = self._programs.window
             if window is not None:
@@ -1585,18 +1611,33 @@ class LmEngine:
                 )
         if result is not None:
             self._observer.watch(
-                result, functools.partial(self._tick_done, entry),
+                result, functools.partial(self._tick_done, entry, counted),
                 t_dispatch_ns=int(t0 * 1e9),
             )
         return entry
 
-    def _tick_done(self, entry, t_done_ns, device_ns, _queue_ns):
+    def _tick_done(self, entry, counted, t_done_ns, device_ns, _queue_ns):
         """Observer callback: the tick's device work has completed.  The
         scheduler's own read-back of a first token may have seen that
         before the observer's thread was given the interpreter: then the
-        delivery bounds the completion."""
+        delivery bounds the completion.  What the program counted on the
+        device is on the host by now (it set out with the tokens): into the
+        entry, and into the series the family names."""
         t_done, device_s = t_done_ns / 1e9, device_ns / 1e9
+        try:
+            values = [int(v) for vector in counted for v in np.asarray(vector)]
+        except Exception:  # noqa: BLE001 - the device work failed (the
+            values = []    # observer has logged it): times, and no counts
         with self._cv:
+            for (field, series, kind, help_), value in zip(self._counters,
+                                                           values):
+                entry[field] = value
+                if series is None or self.registry is None:
+                    continue
+                if kind == "counter":
+                    self.registry.inc(series, None, value=value, help_=help_)
+                else:
+                    self.registry.set(series, None, value, help_=help_)
             late_s = max(t_done - entry.get("t_delivered", t_done), 0.0)
             entry["t_done"] = t_done - late_s
             entry["device_s"] = device_s = max(device_s - late_s, 0.0)

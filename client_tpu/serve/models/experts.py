@@ -1,0 +1,122 @@
+"""A mixture-of-experts feed-forward layer as one chip of an expert-parallel
+deployment runs it: the layer is TOLD which of the routed experts it holds.
+
+The router keeps its published width: every token is scored over ALL the
+experts (float32 sigmoid), its ``top_k`` largest are its picks, and their
+weights are normalised over the picks.  Of the (token, pick) pairs, only
+those that fall on a HELD expert are computed here; what the absent experts
+would add is another chip's part of the sum and is left out (on one chip
+the layer runs without its exchange, and nothing stands in for the absent
+chips).  Nothing is dropped under imbalance: the sorted buffer has the
+static worst-case shape of every pair, and the grouped product
+(``client_tpu.ops.grouped_matmul``) does work for the rows that are there,
+reading no matrix of a held expert that no pair hit.  The shared experts,
+which every chip computes alike, are one dense product over the stacked
+four, averaged.
+
+``Ffn(h) = sum over held picks of w_k E_k(h) + (1 / n_shared) sum_j S_j(h)``
+with ``E(h) = W_down(silu(W_gate h) * W_up h)``.
+
+The layer returns, beside its output, three int32 counts that only the
+device knows: how many held experts had a row, how many pairs fell on held
+experts, and the busiest held expert's rows.
+"""
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from client_tpu.ops.grouped_matmul import grouped_matmul
+
+
+def init_params(key, d_model, d_ff, n_experts, n_held, n_shared, dtype):
+    """The layer's tree: ``router`` [D, n_experts] over ALL experts;
+    ``w_gate_up`` [held, D, 2F] (an expert's gate columns, then its up
+    columns) and ``w_down`` [held, F, D] for the held experts, in the order
+    the layer is told them; ``shared_gate_up`` [D, 2 * n_shared * F] (every
+    shared expert's gate columns, expert after expert, then every up) and
+    ``shared_down`` [n_shared * F, D]."""
+    k = jax.random.split(key, 5)
+
+    def dense(key, shape, fan_in):
+        return jax.random.normal(key, shape, dtype) * float(fan_in ** -0.5)
+
+    return {
+        "router": dense(k[0], (d_model, n_experts), d_model),
+        "w_gate_up": dense(k[1], (n_held, d_model, 2 * d_ff), d_model),
+        "w_down": dense(k[2], (n_held, d_ff, d_model), d_ff),
+        "shared_gate_up": dense(k[3], (d_model, 2 * n_shared * d_ff), d_model),
+        "shared_down": dense(k[4], (n_shared * d_ff, d_model), d_ff),
+    }
+
+
+def route(h, router, top_k):
+    """(picks [T, top_k] int32 over all the experts, weights [T, top_k]
+    float32 summing to 1): float32 sigmoid scores, the ``top_k`` largest,
+    normalised over the picks.  The scores' product accumulates in float32
+    from operands as stored (bf16 products are exact in float32)."""
+    scores = jax.nn.sigmoid(jnp.matmul(
+        h, router, preferred_element_type=jnp.float32))
+    top, picks = lax.top_k(scores, top_k)
+    return picks.astype(jnp.int32), top / jnp.sum(top, axis=-1, keepdims=True)
+
+
+def _swiglu(gate_up):
+    gate, up = jnp.split(gate_up, 2, axis=-1)
+    return jax.nn.silu(gate) * up
+
+
+ROW_TILE = 16  # the sorted buffer's rows come in whole sublane tiles (bf16)
+
+
+def routed(h, layer, held, top_k, real):
+    """The held experts' part of the routed sum for ``h`` [T, D]: float32
+    [T, D], and the counts (experts hit, rows, busiest expert's rows).
+    ``real`` [T] masks rows that are no token (a tick's idle lanes, a
+    chunk's padding): they route nowhere.
+
+    The pairs are sorted by held expert (absent ones last) into a buffer of
+    all T x top_k pairs; ``group_sizes`` says how many rows each held
+    expert has; the two grouped products touch those rows alone.  Each
+    token then takes its own pairs' results back through the inverse of the
+    sort, a gather, and adds them in float32 under its weights: the same
+    sum a scatter-add by token would give, without its serial adds."""
+    t = h.shape[0]
+    n_experts = layer["router"].shape[-1]
+    n_held = len(held)
+    picks, weights = route(h, layer["router"], top_k)
+    # a pick's place among the held experts; n_held: held elsewhere
+    local = jnp.full((n_experts,), n_held, jnp.int32).at[
+        jnp.asarray(held, jnp.int32)].set(jnp.arange(n_held, dtype=jnp.int32))
+    group = jnp.where(real[:, None], local[picks], n_held).reshape(-1)
+    pairs = t * top_k
+    group = jnp.pad(group, (0, -pairs % ROW_TILE), constant_values=n_held)
+    order = jnp.argsort(group, stable=True)
+    group_sizes = jnp.bincount(group, length=n_held + 1)[:n_held].astype(
+        jnp.int32)
+    rows = jnp.take(h, order // top_k, axis=0, mode="clip")  # by expert
+    act = _swiglu(grouped_matmul(rows, layer["w_gate_up"], group_sizes))
+    out = grouped_matmul(act.astype(h.dtype), layer["w_down"], group_sizes)
+    # where each pair's row went: the rows of absent pairs were never
+    # written, so they are masked, not multiplied by zero
+    back = jnp.argsort(order)[:pairs].reshape(t, top_k)
+    mine = jnp.where((group[:pairs] < n_held).reshape(t, top_k, 1),
+                     jnp.take(out, back, axis=0).astype(jnp.float32), 0.0)
+    counts = jnp.stack([jnp.sum(group_sizes > 0), jnp.sum(group_sizes),
+                        jnp.max(group_sizes)]).astype(jnp.int32)
+    return jnp.sum(mine * weights[:, :, None], axis=1), counts
+
+
+def shared(h, layer, n_shared):
+    """The mean of the shared experts for ``h`` [T, D], float32: one
+    gate-and-up product over the stacked experts, one down product over
+    their stacked hidden rows (which sums the experts), over their number."""
+    act = _swiglu(h @ layer["shared_gate_up"])
+    return jnp.matmul(act, layer["shared_down"],
+                      preferred_element_type=jnp.float32) / n_shared
+
+
+def ffn(h, layer, held, top_k, n_shared, real):
+    """``routed + shared`` for ``h`` [T, D]: (float32 [T, D], counts)."""
+    out, counts = routed(h, layer, held, top_k, real)
+    return out + shared(h, layer, n_shared), counts

@@ -15,8 +15,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from client_tpu.serve.lm import KvBlockPool
-from client_tpu.serve.models import sambay
+from client_tpu.serve.lm import KvBlockPool, LmEngine
+from client_tpu.serve.models import cohere2moe, sambay
 from client_tpu.serve.models import transformer as tfm
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
@@ -30,6 +30,11 @@ HYBRID = sambay.SambaYConfig(
     vocab_size=97, d_model=64, n_layers=8, n_heads=4, n_kv_heads=2,
     head_dim=16, d_ff=128, max_seq=64, window=8, d_inner=128, d_state=4,
     d_conv=4, dt_rank=4, dtype="float32")
+# tests/test_cohere2moe.py's expert-parallel share
+MOE = cohere2moe.Cohere2MoeConfig(
+    vocab_size=97, d_model=32, n_layers=4, n_heads=4, n_kv_heads=2,
+    head_dim=16, d_ff=32, n_experts=8, top_k=2, experts_held=(1, 2, 5, 6),
+    n_shared=2, window=8, max_seq=64, dtype="float32")
 
 
 # -- the paged programs against the contiguous path ---------------------------
@@ -203,6 +208,8 @@ def _lower(fn, *args, **static):
     (DECODER, "chunk", "prefill_roofline_pct"),
     (HYBRID, "tick", "sambay_decode_roofline_pct"),
     (HYBRID, "chunk", "sambay_prefill_roofline_pct"),
+    (MOE, "tick", "cohere2moe_decode_roofline_pct"),
+    (MOE, "chunk", "cohere2moe_prefill_roofline_pct"),
 ], ids=lambda v: v if isinstance(v, str) else type(v).__name__)
 def test_family_program_lowers_under_the_name_its_metric_reads(
         cfg, program, metric):
@@ -215,11 +222,13 @@ def test_family_program_lowers_under_the_name_its_metric_reads(
         lambda: cfg.family.init_params(jax.random.PRNGKey(0), cfg))
     kv = KvBlockPool(cfg, 8, BLOCK, lanes=n)
     # a family with fixed per-lane state takes it after the pools, and says
-    # which lanes (a tick) or which lane from where (a chunk) it is for
+    # which lanes (a tick) or which lane from where (a chunk) it is for; one
+    # whose expert layers route says which lanes are in the tick
     state = (kv.lane_state,) if kv.lane_state else ()
+    named = not isinstance(programs, tfm.DecoderPrograms)
     width = cfg.max_seq // BLOCK
     if program == "tick":
-        live = (jnp.ones((n,), bool),) if state else ()
+        live = (jnp.ones((n,), bool),) if named else ()
         lowered = _lower(
             programs.make_tick(n), params, jnp.zeros((n,), jnp.int32),
             kv.pools["k"], kv.pools["v"], *state,
@@ -229,7 +238,7 @@ def test_family_program_lowers_under_the_name_its_metric_reads(
     else:
         slot, fresh = ((jnp.int32(0),), (jnp.bool_(True),)) if state else (
             (), ())
-        static = dict(cfg=cfg, block_size=BLOCK) if state else {}
+        static = dict(cfg=cfg, block_size=BLOCK) if named else {}
         lowered = _lower(
             programs.prefill_jit, params, jnp.zeros((1, 8), jnp.int32),
             kv.pools["k"], kv.pools["v"], *state,
@@ -259,20 +268,57 @@ def test_engine_imports_no_model():
     assert not [name for name in imported if "serve.models" in name]
 
 
-def test_both_families_answer_the_same_questions():
-    """Both configurations hand out an object with one attribute set;
-    ``make_verify`` (the speculative verify step rewinds by a pointer into
-    a lane's blocks) is there exactly where no recurrent state rides
-    beside the blocks."""
+def test_the_families_answer_the_same_questions():
+    """Every configuration hands out an object with one attribute set, but
+    for what a family alone can answer: ``make_verify`` (the speculative
+    verify step rewinds by a pointer into a lane's blocks) where the family
+    has the program; the expert family's reason for having none
+    (``no_verify``) and what it counts for a ``tick_trace()`` entry
+    (``counters`` on the device, ``tick_fields`` on the host)."""
     def public(obj):
         return {name for name in dir(obj) if not name.startswith("_")}
 
-    decoder, hybrid = (cfg.family(cfg, BLOCK) for cfg in (DECODER, HYBRID))
+    decoder, hybrid, moe = (cfg.family(cfg, BLOCK)
+                            for cfg in (DECODER, HYBRID, MOE))
     assert public(decoder) - public(hybrid) == {"make_verify"}
     assert not public(hybrid) - public(decoder)
+    assert public(moe) - public(hybrid) == {
+        "no_verify", "counters", "tick_fields"}
+    assert not public(hybrid) - public(moe)
     for programs in (decoder, hybrid):
         assert hasattr(programs, "make_verify") == (not programs.recurrent)
+    assert not moe.recurrent and moe.no_verify
+    assert [name for name, *_ in moe.counters] == [
+        "experts_held", "experts_hit", "expert_rows", "expert_rows_max"]
     # what the runner asks before any program exists, on the class
     assert public(DECODER.family) - public(HYBRID.family) == {"make_verify"}
-    assert HYBRID.family.generate is None
-    assert HYBRID.family.quantize_params is None
+    for cfg in (HYBRID, MOE):
+        assert cfg.family.generate is None
+        assert cfg.family.quantize_params is None
+
+
+@pytest.mark.parametrize("cfg, reason", [
+    (HYBRID, "recurrent state"), (MOE, "no verify program")],
+    ids=lambda v: v if isinstance(v, str) else type(v).__name__)
+def test_speculative_is_refused_where_the_family_has_no_verify_program(
+        cfg, reason):
+    """``LmEngine(speculative=...)`` raises at construction for a family
+    without ``make_verify``, each for its own reason, which ``spec_stats()``
+    repeats: no family reaches ``_verify_for`` without a program."""
+    params = jax.eval_shape(
+        lambda: cfg.family.init_params(jax.random.PRNGKey(0), cfg))
+    with pytest.raises(ValueError, match=reason):
+        LmEngine(params, cfg, block_size=BLOCK,
+                 speculative={"k": 2, "drafter": "ngram"})
+    eng = LmEngine(params, cfg, block_size=BLOCK)
+    try:
+        stats = eng.spec_stats()
+    finally:
+        eng.close()
+    assert stats["enabled"] is False and reason in stats["reason"]
+    decoder = LmEngine(jax.eval_shape(lambda: tfm.init_params(
+        jax.random.PRNGKey(0), DECODER)), DECODER, block_size=BLOCK)
+    try:
+        assert decoder.spec_stats() == {}
+    finally:
+        decoder.close()

@@ -359,3 +359,66 @@ def test_sambay_decode_tick_compiled_for_v5e_reads_the_pool_in_place(
     aliases = re.search(r"input_output_alias=\{(.*?)\}, entry_comp", text)
     assert aliases.group(1).count("-alias") == 2 + sum(
         len(layers) for layers in state.values())
+
+
+def test_cohere2moe_decode_tick_compiled_for_v5e_keeps_its_kernels(
+        one_chip, monkeypatch):
+    """The rag cell's tick (Command A+'s widths, one period of four layers,
+    16 of 128 experts held, 32 lanes, a table of 560 columns; a small
+    vocabulary) as the v5e's compiler leaves it: four calls of the paged
+    decode kernel, three of them with first positions, and eight of the
+    grouped expert product (gate-and-up and down a layer), which take the
+    pool and the expert stacks as they lie; nothing has a lane's gathered
+    table, and the pools are outputs that alias their donated arguments."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from client_tpu.serve.models import cohere2moe
+
+    n, width, block, n_blocks = 32, 560, 16, 12288
+    cfg = cohere2moe.Cohere2MoeConfig(vocab_size=4096, max_seq=width * block)
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    layers, in_block, _ = cfg.state_spec
+    pools = [shaped((n_blocks + 1,) + tuple(
+        block if d is None else d for d in in_block), cfg.jdtype)
+        for _ in range(layers)]
+    params = jax.tree_util.tree_map(
+        lambda a: shaped(a.shape, a.dtype),
+        jax.eval_shape(functools.partial(cohere2moe.init_params, cfg=cfg),
+                       jax.random.PRNGKey(0)))
+    keys = jax.eval_shape(lambda: jax.random.split(jax.random.PRNGKey(0), n))
+    args = (params, shaped((n,), "int32"), pools, pools,
+            shaped((n, width), "int32"), shaped((n,), "int32"),
+            shaped((n,), "bool"), shaped((n,), "float32"),
+            shaped((n,), "int32"), shaped(keys.shape, keys.dtype))
+    tick = jax.jit(functools.partial(
+        cohere2moe.cohere2moe_decode_tick, cfg=cfg, n=n, block_size=block),
+        donate_argnums=(2, 3))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = tick.lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) \
+        == layers + 2 * layers == 12
+    kv, hd = cfg.n_kv_heads, cfg.head_dim
+    shapes = {tuple(int(d) for d in dims.split(","))
+              for dims in re.findall(r"\b(?:f32|bf16)\[([\d,]+)\]", text)}
+    gathered = {s for s in shapes
+                if s[-3:] == (kv, width * block, hd)
+                or s == (n * width, kv, block, hd)
+                or s == (n, width, kv, block, hd)}
+    assert not gathered, gathered
+    pool_text = rf"bf16\[{n_blocks + 1},{kv},{block},{hd}\]"
+    copied = re.findall(
+        rf"= \(?{pool_text}[^=]* (?:copy|copy-start|slice-start)\(.*", text)
+    assert not copied, copied[:2]
+    aliases = re.search(r"input_output_alias=\{(.*?)\}, entry_comp", text)
+    assert aliases.group(1).count("-alias") == 2 * layers

@@ -37,14 +37,17 @@ LENGTHS = {
 }
 
 
-def _plain(q, pool_k, pool_v, tables, lengths):
-    """Gather a lane's blocks, softmax over its live positions, weigh."""
+def _plain(q, pool_k, pool_v, tables, lengths, starts=None):
+    """Gather a lane's blocks, softmax over its live positions (from its
+    start, where it has one), weigh."""
     out = np.zeros(q.shape, np.float32)
     for lane, length in enumerate(lengths):
         if not length:
             continue
+        first = 0 if starts is None else starts[lane]
         k, v = (np.concatenate([np.asarray(pool[b], np.float32)
-                                for b in tables[lane]], axis=1)[:, :length]
+                                for b in tables[lane]],
+                               axis=1)[:, first:length]
                 for pool in (pool_k, pool_v))
         s = np.einsum("grd,gtd->grt", np.asarray(q[lane], np.float32), k)
         p = np.exp(s - s.max(axis=-1, keepdims=True))
@@ -101,6 +104,78 @@ def test_kernel_matches_a_plain_gather_and_softmax(case, length, rows, shape):
     assert not out[2].any() and (held or not out[1].any())
     for pool, was in zip((pool_k, pool_v), before):
         np.testing.assert_array_equal(np.asarray(pool), was)
+
+
+# where the lane under test starts its read, for a length of two steps and
+# seven positions: nowhere special, the last position of the first step,
+# the first of the second, inside the third, the last position alone
+STARTS = {
+    "0": lambda span: 0,
+    "3": lambda span: 3,
+    "step-1": lambda span: span - 1,
+    "step": lambda span: span,
+    "2step+5": lambda span: 2 * span + 5,
+    "length-1": lambda span: 2 * span + 6,
+}
+
+
+@pytest.mark.parametrize("shape, rows", [("float32-tiny", 2),
+                                         ("bfloat16-cell", 16)])
+@pytest.mark.parametrize("start", list(STARTS))
+def test_kernel_with_first_positions_matches_the_gathered_form(
+        case, start, shape, rows):
+    """A window layer's read: each lane from its own first position.  The
+    steps before it are not walked (their table columns point at the trash
+    block here, which holds other values than the lane's blocks did), the
+    positions of the first step before it are masked, and the copy ahead
+    crosses from a lane's last step into the next lane's FIRST one."""
+    _, _, block, _, tol = SHAPES[shape]
+    span = STEP * block
+    q, (pool_k, pool_v), tables = case(shape, rows)
+    held = 2 * span + 7
+    first = STARTS[start](span)
+    lengths = np.array([WIDTH * block, held, 0, block + 3], np.int32)
+    starts = np.array([span + 1, first, 0, 2], np.int32)
+    want = _plain(q, pool_k, pool_v, tables, lengths, starts)
+    tables = tables.copy()
+    tables[1, :first // span * STEP] = KvBlockPool.TRASH  # never read
+    tables[0, :STEP] = KvBlockPool.TRASH
+    out = np.asarray(paged_decode.paged_decode_attention(
+        q, pool_k, pool_v, jnp.asarray(tables), jnp.asarray(lengths),
+        jnp.asarray(starts), interpret=pltpu.InterpretParams()))
+    np.testing.assert_allclose(out, want, atol=tol, rtol=tol)
+    assert not out[2].any()
+
+
+def test_without_first_positions_the_reason_cells_tick_lowers_as_before():
+    """``starts`` is optional and ``sambay.py`` passes none: its decode
+    tick has to lower to the module it lowered to before the argument
+    existed.  The digest is of the tick's lowered text at
+    ``tests/test_lm_family.py``'s tiny SambaY configuration, taken on the
+    parent commit (PR 32's tree; jax 0.9.0, the one installation): the
+    interpreted kernel is traced into that text, so a change to its body
+    shows, as does one to the rest of the tick."""
+    import hashlib
+
+    cfg = sambay.SambaYConfig(
+        vocab_size=97, d_model=64, n_layers=8, n_heads=4, n_kv_heads=2,
+        head_dim=16, d_ff=128, max_seq=64, window=8, d_inner=128, d_state=4,
+        d_conv=4, dt_rank=4, dtype="float32")
+    n, block = 2, 4
+    programs = cfg.family(cfg, block)
+    params = jax.eval_shape(
+        lambda: cfg.family.init_params(jax.random.PRNGKey(0), cfg))
+    kv = KvBlockPool(cfg, 8, block, lanes=n)
+    tick = programs.make_tick(n)
+    text = tick.func.lower(
+        params, jnp.zeros((n,), jnp.int32), kv.pools["k"], kv.pools["v"],
+        kv.lane_state, jnp.zeros((n, 16), jnp.int32),
+        jnp.zeros((n,), jnp.int32), jnp.ones((n,), bool),
+        jnp.zeros((n,), jnp.float32), jnp.zeros((n,), jnp.int32),
+        jnp.zeros((n, 2), jnp.uint32), **tick.keywords).as_text()
+    assert len(text) == 420231
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "a0befcd2ced72789bb11bfb09874dd9d0d293c84a56443500a3c10524fc39f61")
 
 
 def test_steps_read_is_the_kernels_trip_count_on_host_and_device():
