@@ -3,8 +3,9 @@ over a paged K/V cache, read where it lies.
 
 The cache is a pool of blocks ``[n_blocks + 1, heads, block, width]`` (what
 ``serve/lm/kv.KvBlockPool`` holds for a configuration whose ``state_spec``
-puts the heads outside a block's positions) and a lane's logical cache is
-its row of a block table.  The XLA form of this read gathers ``pool[tables]``
+puts the heads outside a block's positions: all three families,
+``transformer``, ``sambay`` and ``cohere2moe``, hold it so) and a lane's
+logical cache is its row of a block table.  The XLA form of this read gathers ``pool[tables]``
 into a copy as long as the table, whatever the lanes hold, and contracts the
 copy; here the pool never leaves HBM whole: grid ``(lane,)``, and a lane's
 program walks its own table in steps of ``STEP_BLOCKS`` blocks up to its own

@@ -117,7 +117,8 @@ def chain_digests(tokens, block_size, max_blocks=None):
 
 
 def _encode_block(arrays):
-    """One block's per-layer [block_size, kv_heads, head_dim] arrays ->
+    """One block's per-layer arrays (a block as the engine's pool holds
+    it, [kv_heads, block_size, head_dim] for the decoder) ->
     JSON-safe dict (dtype + shape + base64 payload per layer)."""
     return [
         {
@@ -163,7 +164,7 @@ class _PrefixStore:
 
     def put(self, row, n_blocks, block_size, host_k, host_v):
         """Insert ``n_blocks`` leading full blocks of *row* (host arrays
-        per layer, shaped [>=n_blocks, block_size, kv, hd])."""
+        per layer, shaped [>=n_blocks, ...a block as the pool holds it])."""
         row = [int(t) for t in np.asarray(row).reshape(-1)]
         n_blocks = min(int(n_blocks), len(row) // int(block_size))
         digests = chain_digests(row, block_size, n_blocks)
@@ -188,7 +189,7 @@ class _PrefixStore:
 
     def lookup(self, row, block_size, max_blocks, count_hits=True):
         """Longest stored chain for *row*: ``(covered, k_layers,
-        v_layers)`` with per-layer arrays stacked [covered, bs, kv, hd],
+        v_layers)`` with per-layer arrays stacked [covered, ...a block],
         or None on a total miss."""
         row = [int(t) for t in np.asarray(row).reshape(-1)]
         block_size = int(block_size)

@@ -354,11 +354,11 @@ class LmEngine:
         self._tokens = None
         self._keys = None
         self._prefill = self._programs.prefill  # one chunk's dispatch
-        # a family whose decode tick reads each lane to its own length
-        # says how far (``_tick_reads(lengths, table_width)``: asked of
-        # one family, so not among the questions both answer); without it
-        # every lane reads ``attended_positions`` of the longest
-        self._tick_reads = getattr(self._programs, "_tick_reads", None)
+        # how far a decode tick reads each lane (``_tick_reads(lengths,
+        # table_width)``): to its own length where the family's tick
+        # reads the blocks in place; None where every lane reads
+        # ``attended_positions`` of the longest
+        self._tick_reads = self._programs._tick_reads
         # a family may count more for an entry of tick_trace(): on the host
         # from the entry's own lengths (``tick_fields``), and on the device
         # (``counters`` names the int32 vector its programs return beside
@@ -1326,8 +1326,7 @@ class LmEngine:
         self._log_tick(
             "decode", t0, tuple(i for i, _ in active), self._tokens,
             lens[live], int(lens.max()),
-            self._tick_reads and self._tick_reads(lens[live],
-                                                  self._table_width),
+            self._tick_reads(lens[live], self._table_width),
             counted=counted,
         )
         return True

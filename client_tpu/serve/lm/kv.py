@@ -5,7 +5,10 @@ prefix).
 The fixed-lane prototype allocated per layer ``[lanes, max_seq, kv, hd]``
 — every lane pinned max-seq-len rows of HBM whether it held a 5-token
 request, a 500-token one, or nothing.  The pool here is per layer
-``[n_blocks, block_size, kv, hd]`` with a host-side free list: a request
+``[n_blocks, kv, block_size, hd]`` (a block's heads outside its positions,
+so that each head's rows lie together and ``ops/paged_decode`` reads a
+block where it lies: every family's ``state_spec`` says so) with a
+host-side free list: a request
 reserves exactly ``ceil((prompt_len + max_tokens) / block_size)`` blocks
 at admission and frees them at completion/cancel, so HBM capacity is a
 function of *aggregate live tokens*, not ``lanes * max_seq``.

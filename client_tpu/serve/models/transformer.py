@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from client_tpu.ops.paged_decode import (
+    STEP_BLOCKS, paged_decode_attention, reads_in_place, steps_read)
 from client_tpu.ops.quant import matmul as _mm
 from client_tpu.ops.sampling import accept_lane, select_token
 from client_tpu.parallel.ring_attention import (
@@ -41,6 +43,7 @@ from client_tpu.parallel.ring_attention import (
 )
 from client_tpu.serve.lm.kv import KvBlockPool
 from client_tpu.serve.lm.policy import attention_width_index, attention_widths
+from client_tpu.serve.models.sambay import _write_rows
 
 
 @dataclasses.dataclass(frozen=True)
@@ -75,8 +78,10 @@ class TransformerConfig:
         """What a lane owns, as ``serve/lm/kv.py`` asks every configuration:
         (paged layers, a block's shape with None where the block's positions
         go, per-lane fixed state).  Identical layers: every one paged, a
-        block ``[block_size, n_kv_heads, head_dim]``, nothing beside them."""
-        return self.n_layers, (None, self.n_kv_heads, self.head_dim), {}
+        block ``[n_kv_heads, block_size, head_dim]`` (heads outside a
+        block's positions: the layout ``ops/paged_decode`` reads in place),
+        nothing beside them."""
+        return self.n_layers, (self.n_kv_heads, None, self.head_dim), {}
 
     @property
     def family(self):
@@ -151,13 +156,14 @@ def _rope(x, positions, theta):
     return out.astype(x.dtype)
 
 
-def _repeat_kv(x, n_rep):
+def _repeat_kv(x, n_rep, axis=2):
     """[B,T,n_kv,hd] -> [B,T,n_kv*n_rep,hd] for the full-sequence kernels,
     which take one K/V head a query head.  ``paged_attention`` contracts a
-    KV-head group at a time, and calls it for wide prefill chunks only."""
+    KV-head group at a time, and calls it (heads on ``axis`` 1, as the paged
+    pool has them) for wide prefill chunks only."""
     if n_rep == 1:
         return x
-    return jnp.repeat(x, n_rep, axis=2)
+    return jnp.repeat(x, n_rep, axis=axis)
 
 
 def _attention_block(layer, x, cfg, positions, mesh, attn_impl):
@@ -376,8 +382,9 @@ def decode_step(params, token, cfg, cache):
 def paged_attention(q, pool_k, pool_v, tables, pos, cfg, block_size):
     """Attention of ``q`` ([B,T,H,hd], already roped) against a PAGED KV
     cache: ``pool_k``/``pool_v`` are one layer's block pools
-    ([n_blocks+1, block_size, n_kv, hd], serve/lm/kv.KvBlockPool layout)
-    and ``tables`` ([B, table_width] int32) maps each lane's logical
+    ([n_blocks+1, n_kv, block_size, hd], serve/lm/kv.KvBlockPool layout:
+    heads outside a block's positions) and ``tables`` ([B, table_width]
+    int32) maps each lane's logical
     block index to its physical pool block.  Length-masked at ``pos``
     ([B,T] logical query positions; keys at logical position j attend
     iff j <= pos), so trash-mapped rows are never read.
@@ -399,6 +406,11 @@ def paged_attention(q, pool_k, pool_v, tables, pos, cfg, block_size):
     masked score contributes an exact zero, so the result is the whole
     table's to rounding (PERF.md section 6, PR 30).  A table with one
     width has no loop at all.
+
+    This is the read of every call with more than one query row a lane (a
+    prefill chunk, a verify tick) and of a decode tick whose pool
+    ``ops/paged_decode`` cannot take as it lies; the decode tick otherwise
+    reads in place (``paged_layers``).
     """
     b, t = q.shape[:2]
     table_width = tables.shape[-1]
@@ -443,11 +455,12 @@ def _attend_columns(q, pool_k, pool_v, tables, pos, first, cfg, block_size):
 
     The queries of a KV-head group are contracted against that group's
     gathered keys and values directly: ``q`` as [B,T,n_kv,n_rep,hd]
-    against K [B,S,n_kv,hd] is ONE ``dot_general`` with batch dimensions
+    against K [B,n_kv,S,hd] (``pool[tables]``, a lane's blocks side by
+    side under each head) is ONE ``dot_general`` with batch dimensions
     (lane, KV head), n_rep x T query rows a group, scores
     [B,n_kv,n_rep,T,S] accumulated in float32 from operands at their
     stored width; mask, scale and softmax stay float32; the weighted sum
-    is the mirror contraction over V [B,S,n_kv,hd] with the weights cast
+    is the mirror contraction over V [B,n_kv,S,hd] with the weights cast
     to V's type.  So the gathered K and V are read once each as stored:
     no copy at n_heads, none in float32 (at Mistral-7B widths and 16
     lanes those were 1 GB written a layer a tick: PERF.md section 6,
@@ -470,58 +483,98 @@ def _attend_columns(q, pool_k, pool_v, tables, pos, first, cfg, block_size):
     n_kv = cfg.n_kv_heads
     n_rep = cfg.n_heads // n_kv
     s_len = tables.shape[-1] * block_size
-    kk = pool_k[tables].reshape(b, s_len, n_kv, hd)
-    vv = pool_v[tables].reshape(b, s_len, n_kv, hd)
+    # [B,W,n_kv,block,hd] -> [B,n_kv,S,hd]
+    kk = jnp.swapaxes(pool_k[tables], 1, 2).reshape(b, n_kv, s_len, hd)
+    vv = jnp.swapaxes(pool_v[tables], 1, 2).reshape(b, n_kv, s_len, hd)
     valid = first + jnp.arange(s_len)[None, None, :] <= pos[:, :, None]
     if t >= 2 * hd:
         # spelled out, not folded into the grouped einsums as n_rep 1: with
         # their size-1 axes XLA no longer fuses this softmax into one pass
-        kk, vv = _repeat_kv(kk, n_rep), _repeat_kv(vv, n_rep)
+        kk, vv = _repeat_kv(kk, n_rep, axis=1), _repeat_kv(vv, n_rep, axis=1)
         s = jnp.einsum(
-            "bqhd,bkhd->bhqk", q, kk, preferred_element_type=jnp.float32
+            "bqhd,bhkd->bhqk", q, kk, preferred_element_type=jnp.float32
         ) * (hd ** -0.5)
         s = jnp.where(valid[:, None], s, -1e30)
         m = jnp.max(s, axis=-1, keepdims=True)
         p = jnp.exp(s - m)
-        acc = jnp.einsum("bhqk,bkhd->bqhd", p.astype(vv.dtype), vv,
+        acc = jnp.einsum("bhqk,bhkd->bqhd", p.astype(vv.dtype), vv,
                          preferred_element_type=jnp.float32)
         rows = lambda x: x.transpose(0, 2, 1, 3)  # [B,H,T,1] -> [B,T,H,1]
         return rows(m), rows(jnp.sum(p, axis=-1, keepdims=True)), acc
     qg = q.reshape(b, t, n_kv, n_rep, hd)
     s = jnp.einsum(
-        "btgrd,bsgd->bgrts", qg, kk, preferred_element_type=jnp.float32
+        "btgrd,bgsd->bgrts", qg, kk, preferred_element_type=jnp.float32
     ) * (hd ** -0.5)
     s = jnp.where(valid[:, None, None], s, -1e30)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
-    acc = jnp.einsum("bgrts,bsgd->btgrd", p.astype(vv.dtype), vv,
+    acc = jnp.einsum("bgrts,bgsd->bgrtd", p.astype(vv.dtype), vv,
                      preferred_element_type=jnp.float32)
-    # [B,n_kv,n_rep,T,1] -> [B,T,H,1]
-    rows = lambda x: x.transpose(0, 3, 1, 2, 4).reshape(b, t, cfg.n_heads, 1)
-    return (rows(m), rows(jnp.sum(p, axis=-1, keepdims=True)),
-            acc.reshape(b, t, cfg.n_heads, hd))
+    # [B,n_kv,n_rep,T,x] -> [B,T,H,x]
+    rows = lambda x: x.transpose(0, 3, 1, 2, 4).reshape(
+        b, t, cfg.n_heads, x.shape[-1])
+    return rows(m), rows(jnp.sum(p, axis=-1, keepdims=True)), rows(acc)
 
 
 # -- the paged decoder: one layer loop, and the three programs the engine runs --
 
-def paged_layers(params, x, pool_k, pool_v, tables, pos, blk, slot, cfg,
-                 block_size):
-    """Every layer over the embedded ``x`` [B,T,D] against the PAGED cache:
-    the one layer loop of the three programs below, which differ in the
-    shape of ``x`` and in what they do before and after it.  ``pos`` [B,T]
-    are the logical positions, ``tables`` [B,W] the lanes' block tables,
-    and ``blk``, ``slot`` [B*T] where each position's new K/V row lands in
-    a layer's pool, lane-major: ``(table[pos // block_size], pos %
-    block_size)``, or the trash block for a position that is padding.
+def _write_blocks(pool, rows, blks):
+    """``pool[blks[j]]`` = positions ``j * block ..`` of ``rows`` [N, n_kv,
+    hd], N whole blocks, for a layer's pool [n_blocks+1, n_kv, block, hd]:
+    each block one contiguous [n_kv, block, hd] copy, where
+    ``sambay._write_rows`` (the write of everything that is no whole block:
+    N x n_kv rows of hd, the pool's minor dimension) would scatter N x n_kv
+    rows (2.2 ms a 512-wide chunk over four layers in the rag cell:
+    PERF.md section 5)."""
+    n_kv, block, hd = pool.shape[1:]
+    return pool.at[blks].set(
+        rows.reshape(-1, block, n_kv, hd).swapaxes(1, 2))
+
+
+@functools.partial(jax.jit, static_argnames="cfg")
+def _attend_in_place(q, pool_k, pool_v, tables, lengths, cfg):
+    """One query row a lane, ``q`` [n,1,H,hd], over the lane's first
+    ``lengths`` [n] positions through ``ops/paged_decode``: the blocks are
+    read where they lie, each lane to its own length, a lane of length 0
+    not at all.  The query rows of a KV head go in together, scaled in
+    their own type; operands reach the matrix unit as stored, scores and
+    softmax are float32, as in ``_attend_columns``.  Jitted, so that a
+    tick's layers trace and lower the kernel once between them: sixteen
+    times over cost the chat cell 1.2 s of every start (PERF.md section 6,
+    PR 34); the compiled tick is the same."""
+    n, hd = q.shape[0], cfg.head_dim
+    qg = q[:, 0].reshape(n, cfg.n_kv_heads, -1, hd) \
+        * jnp.asarray(hd ** -0.5, q.dtype)
+    out = paged_decode_attention(qg, pool_k, pool_v, tables, lengths)
+    return out.reshape(n, 1, cfg.n_heads, hd).astype(q.dtype)
+
+
+def paged_layers(params, x, pool_k, pool_v, tables, pos, write, cfg,
+                 block_size, lengths=None):
+    """Every layer over the embedded ``x`` [B,T,D] against the PAGED cache
+    (a layer's pool [n_blocks+1, n_kv, block_size, hd]): the one layer loop
+    of the three programs below, which differ in the shape of ``x`` and in
+    what they do before and after it.  ``pos`` [B,T] are the logical
+    positions, ``tables`` [B,W] the lanes' block tables, and ``write(pool,
+    rows)`` puts the B*T new K or V rows ([B*T, n_kv, hd], lane-major) where
+    their positions live in a layer's pool, padding in the trash block:
+    ``sambay._write_rows`` at each row's ``(table[pos // block_size], pos
+    % block_size)``, or ``_write_blocks`` for a chunk of whole blocks.
     Returns the final-normed ``x`` and the pools.
 
-    The new rows go in as ONE scatter of B*T rows at ``(blk, slot)``, then
-    ``paged_attention`` reads them back through the tables: position
-    ``pos`` attends positions ``<= pos``, all of which this call or an
-    earlier one wrote."""
+    Then attention reads the rows back through the tables: position ``pos``
+    attends positions ``<= pos``, all of which this call or an earlier one
+    wrote.  One algorithm, two reads, chosen by what the call brings: with
+    one query row a lane (the decode tick, which says so by giving each
+    lane's ``lengths`` [B], 0 for a lane that is not in the tick) and a
+    pool that ``ops/paged_decode`` can take as it lies
+    (``reads_in_place``), the kernel reads each lane's blocks in place up
+    to its own length; otherwise ``paged_attention`` gathers the table a
+    group of columns at a time."""
     b, t = x.shape[:2]
     hd = cfg.head_dim
     pool_k, pool_v = list(pool_k), list(pool_v)
+    in_place = lengths is not None and reads_in_place(pool_k[0])
     for i, layer in enumerate(params["layers"]):
         h = _rms_norm(x, layer["ln_attn"])
         q = _mm(h, layer["attn"]["wq"]).reshape(b, t, cfg.n_heads, hd)
@@ -530,11 +583,14 @@ def paged_layers(params, x, pool_k, pool_v, tables, pos, blk, slot, cfg,
         q = _rope(q, pos, cfg.rope_theta)
         k = _rope(k, pos, cfg.rope_theta)
         rows = (b * t, cfg.n_kv_heads, hd)
-        pool_k[i] = pool_k[i].at[blk, slot].set(k.reshape(rows))
-        pool_v[i] = pool_v[i].at[blk, slot].set(v.reshape(rows))
-        attn = paged_attention(
-            q, pool_k[i], pool_v[i], tables, pos, cfg, block_size
-        )
+        pool_k[i] = write(pool_k[i], k.reshape(rows))
+        pool_v[i] = write(pool_v[i], v.reshape(rows))
+        if in_place:
+            attn = _attend_in_place(
+                q, pool_k[i], pool_v[i], tables, lengths, cfg)
+        else:
+            attn = paged_attention(
+                q, pool_k[i], pool_v[i], tables, pos, cfg, block_size)
         out = _mm(
             attn.reshape(b, t, cfg.n_heads * hd), layer["attn"]["wo"]
         )
@@ -544,15 +600,19 @@ def paged_layers(params, x, pool_k, pool_v, tables, pos, blk, slot, cfg,
 
 
 def paged_decode_tick(params, tokens_full, pool_k, pool_v, tables, lens,
-                      temps, topks, keys_full, *, cfg, n, block_size):
+                      live, temps, topks, keys_full, *, cfg, n, block_size):
     """One batched decode step over the first ``n`` lanes (n is static:
     one executable per configured lane count), each lane's pending token
-    at position ``lens``, the next one chosen on the device."""
+    at position ``lens``, the next one chosen on the device.  ``live`` [n]
+    masks the lanes that are not in the tick (idle, or at their budget):
+    they write to the trash block and read nothing."""
     x = jnp.take(params["embed"], tokens_full[:n], axis=0)[:, None, :]
-    blk = tables[jnp.arange(n), lens // block_size]  # [n] physical blocks
+    blk = jnp.where(live, tables[jnp.arange(n), lens // block_size],
+                    KvBlockPool.TRASH)  # [n] physical blocks
     x, pool_k, pool_v = paged_layers(
-        params, x, pool_k, pool_v, tables, lens[:, None], blk,
-        lens % block_size, cfg, block_size)
+        params, x, pool_k, pool_v, tables, lens[:, None],
+        lambda pool, rows: _write_rows(pool, blk, lens % block_size, rows),
+        cfg, block_size, lengths=jnp.where(live, lens + 1, 0))
     logits = _mm(x[:, 0], params["lm_head"]).astype(jnp.float32)  # [n,V]
     pairs = jax.vmap(functools.partial(jax.random.split, num=2))(
         keys_full[:n]
@@ -598,8 +658,10 @@ def paged_verify_tick(params, tokens_full, pool_k, pool_v, tables, lens,
         KvBlockPool.TRASH,
     )
     x, pool_k, pool_v = paged_layers(
-        params, x, pool_k, pool_v, tables, pos, blk.reshape(-1),
-        (pos % block_size).reshape(-1), cfg, block_size)
+        params, x, pool_k, pool_v, tables, pos,
+        lambda pool, rows: _write_rows(
+            pool, blk.reshape(-1), (pos % block_size).reshape(-1), rows),
+        cfg, block_size)
     logits = _mm(x, params["lm_head"]).astype(jnp.float32)  # [n,w,V]
     keys = jax.vmap(functools.partial(jax.random.split, num=w + 1))(
         keys_full[:n]
@@ -619,20 +681,34 @@ def paged_prefill_chunk(params, chunk, pool_k, pool_v, table, start,
     """One prefill chunk ([1, C] tokens at logical positions
     start..start+C-1) written straight into the paged pool.
 
-    Positions >= prompt_len (bucket padding) write to the trash block
-    and are never attended (the length mask), so padding is inert.  The
-    returned token is the sampled/greedy first generation token — only
-    the FINAL chunk's return is meaningful (its chunk contains position
-    prompt_len - 1)."""
+    Positions >= prompt_len (bucket padding) are never attended (the
+    length mask), so padding is inert.  A chunk of whole blocks (C a
+    multiple of ``block_size``) starts on a block edge: the engine's plan
+    (``policy.chunk_plan``) starts behind whole adopted blocks and steps
+    by its widest bucket, which every multi-chunk plan's chunks have.  It
+    writes whole blocks: one wholly at or past ``prompt_len`` goes to the
+    trash block; the one that holds ``prompt_len`` is the lane's own, and
+    what lands in it past the prompt is never read and overwritten as the
+    lane decodes.  Any other width writes rows, padding's to the trash
+    block.  The returned token is the sampled/greedy first generation
+    token — only the FINAL chunk's return is meaningful (its chunk
+    contains position prompt_len - 1)."""
     c = chunk.shape[1]
     x = jnp.take(params["embed"], chunk, axis=0)  # [1,C,D]
     pos = start + jnp.arange(c)  # [C] logical positions
-    blk = jnp.where(
-        pos < prompt_len, table[pos // block_size], KvBlockPool.TRASH
-    )
+    if c % block_size:
+        blk = jnp.where(
+            pos < prompt_len, table[pos // block_size], KvBlockPool.TRASH)
+        write = lambda pool, rows: _write_rows(
+            pool, blk, pos % block_size, rows)
+    else:
+        first = pos[::block_size]  # [C / block_size] each block's first
+        blks = jnp.where(first < prompt_len, table[first // block_size],
+                         KvBlockPool.TRASH)
+        write = lambda pool, rows: _write_blocks(pool, rows, blks)
     x, pool_k, pool_v = paged_layers(
-        params, x, pool_k, pool_v, table[None], pos[None], blk,
-        pos % block_size, cfg, block_size)
+        params, x, pool_k, pool_v, table[None], pos[None], write, cfg,
+        block_size)
     last = jnp.clip(prompt_len - 1 - start, 0, c - 1)
     xsel = jnp.take(x, last[None], axis=1)  # [1,1,D]
     logits = _mm(xsel[:, 0], params["lm_head"]).astype(jnp.float32)[0]
@@ -887,9 +963,11 @@ class DecoderPrograms:
     speculative verify step; ``prefill`` and ``tick`` take the
     ``KvBlockPool`` and leave the arrays their program returned in it.
     This one is the decoder of identical layers (``TransformerConfig``): a
-    lane is its blocks, so the lane arguments (``slot``, ``fresh``,
-    ``live``: host values, which only a family that uses them sends to the
-    device) have nothing to act on.
+    lane is its blocks ([n_kv_heads, block_size, head_dim] each, the layout
+    all three families hold and ``ops/paged_decode`` reads in place), so of
+    the lane arguments (host values, which only a family that uses them
+    sends to the device) ``slot`` and ``fresh`` have nothing to act on;
+    ``live`` tells the tick which lanes read and write.
 
     On the class, what ``_LmRunner`` asks before any program exists:
     ``init_params``, and ``generate`` / ``quantize_params``, each None in a
@@ -915,6 +993,9 @@ class DecoderPrograms:
         self.donate = (2, 3) if jax.default_backend() != "cpu" else ()
         self.flops_per_token = lm_flops_per_token(cfg)
         self.window = None  # positions a window layer keeps, if any
+        # what ``paged_layers`` will find of the pool's blocks on a tick
+        self._in_place = reads_in_place(jax.ShapeDtypeStruct(
+            (block_size, cfg.head_dim), cfg.jdtype))
         self.prefill_jit = self._jit(paged_prefill_chunk)
 
     def _jit(self, program, **static):
@@ -934,6 +1015,18 @@ class DecoderPrograms:
         index = attention_width_index(max_pos, table_width, self.block_size)
         return widths[min(index, len(widths) - 1)] * self.block_size
 
+    def _tick_reads(self, lengths, table_width):
+        """The cache positions of each lane that a decode tick's attention
+        reads, for lanes at ``lengths`` (an array) before the tick's
+        write: where the tick reads in place, each lane's own length, this
+        tick's row with it, rounded up to the kernel's step (its trip
+        count, ``paged_decode.steps_read``); None where it takes
+        ``paged_attention``, whose read ``attended_positions`` gives."""
+        if not self._in_place:
+            return None
+        return (steps_read(lengths + 1, self.block_size)
+                * (STEP_BLOCKS * self.block_size)).tolist()
+
     def prefill(self, params, kv, chunk, table, slot, start, prompt_len,
                 fresh, key, temperature, top_k):
         tok, kv.pools["k"], kv.pools["v"], key = self.prefill_jit(
@@ -949,7 +1042,7 @@ class DecoderPrograms:
              keys):
         tokens, kv.pools["k"], kv.pools["v"], keys = fn(
             params, tokens, kv.pools["k"], kv.pools["v"], tables, lens,
-            temps, topks, keys,
+            jnp.asarray(live), temps, topks, keys,
         )
         return tokens, keys
 

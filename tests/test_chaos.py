@@ -1094,8 +1094,15 @@ class _AntiEntropyFixture:
         # tiers lose content a dead replica never served to a peer
         for _ in range(3):
             _collect(self.engines[0].submit(self.shared + [99], 2)[0])
-        pushed = self.tiers[0].replicate_now()
-        assert pushed >= 1, "hot chain never replicated"
+        # by this call or, where the streams took longer than its 0.2 s
+        # between scans, by the tier's own anti-entropy thread ahead of it
+        self.tiers[0].replicate_now()
+        deadline = time.monotonic() + 10
+        while (not self.tiers[0].replicated_items
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
+        assert self.tiers[0].replicated_items >= 1, \
+            "hot chain never replicated"
 
     def apply_fault(self, fault):
         dispatch_fault(fault, kill=self._kill)

@@ -798,6 +798,10 @@ class TestStickyPolicy:
             assert sorted(seq_counts) == [0, 4]  # one replica took it all
             pinned_index = seq_counts.index(4)
             servers[pinned_index].stop()
+            # stop() returns while the server winds down (a grace of 2 s, in
+            # which a request on the open connection is answered
+            # CANCELLED): a killed replica is one whose connections are gone
+            servers[pinned_index]._grpc._server.stop(grace=0).wait(5)
             with pytest.raises(SequenceRestartError):
                 client.infer("echo", _val_inputs(4), sequence_id=11)
             # restart per the contract: the sequence rebuilds on the

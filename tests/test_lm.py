@@ -15,6 +15,7 @@ import pytest
 
 import jax
 
+from client_tpu.ops import paged_decode
 from client_tpu.serve.lm import KvBlockPool, LmEngine, PrefixCache
 from client_tpu.serve.lm.policy import (
     LaneAutoscaler,
@@ -907,11 +908,21 @@ def _attended(max_pos, table_width, block):
     return block * widths[attention_width_index(max_pos, table_width, block)]
 
 
-def test_attended_positions_follow_the_longest_lane(params):
+@pytest.mark.parametrize("in_place", [True, False])
+def test_attended_positions_follow_the_lanes_own_lengths(
+        params, in_place, monkeypatch):
     """Streams of unequal lengths: every decode tick and every chunk says
-    how wide its attention read, which is the width rule on the lanes' own
-    lengths (replayed here from the entries), and the gauge follows."""
+    how far its attention read (replayed here from the entries), and the
+    gauge follows.  A chunk reads the width rule's width at its last
+    position.  A decode tick that reads the blocks in place reads each lane
+    to its own length, this tick's row with it, in whole steps of the
+    kernel; one that cannot take the pool as it lies (``in_place`` false:
+    what a chip finds at this head size) reads the width rule's width at
+    the longest lane's length, for every lane."""
+    if not in_place:
+        monkeypatch.setattr(tfm, "reads_in_place", lambda pool: False)
     block, table_width = 8, CFG.max_seq // 8
+    span = paged_decode.STEP_BLOCKS * block
     reg = Registry()
     eng = LmEngine(params, CFG, max_slots=4, lane_counts=(4,),
                    block_size=block, prefill_chunk=16, min_bucket=4,
@@ -931,59 +942,72 @@ def test_attended_positions_follow_the_longest_lane(params):
         if t["kind"] == "prefill_chunk":
             (slot,) = t["lanes"]
             length[slot] = t["context_tokens"]
-            max_pos = t["start"] + t["width"] - 1
+            reads = [_attended(t["start"] + t["width"] - 1, table_width,
+                               block)]
         else:
             assert t["kind"] == "decode"
             lens = [length[slot] for slot in t["lanes"]]
             assert t["context_tokens"] == sum(lens)
-            max_pos = max(lens)
+            if in_place:
+                reads = [-(-(n + 1) // span) * span for n in lens]
+            else:
+                reads = [_attended(max(lens), table_width, block)] * len(lens)
             for slot in t["lanes"]:
                 length[slot] += 1
-        assert t["attended_positions"] == _attended(
-            max_pos, table_width, block), t
+        assert t["attended_positions"] == max(reads), t
+        assert t["attended_tokens"] == sum(reads), t
         seen.add(t["attended_positions"])
-    # the longest stream ends at 60 of 96 positions: four widths met, never
-    # the table's own
-    assert seen == {16, 32, 48, 64}
+    # the longest prompt's chunks end at 48 of 96 positions and the longest
+    # stream at 60: four widths met where ticks gather, never the table's
+    # own; in place every lane is within the kernel's first step
+    assert seen == ({16, 32, 48, span} if in_place else {16, 32, 48, 64})
 
 
-def test_decode_tick_at_a_narrow_width_agrees_with_the_whole_table(
+def test_decode_tick_reads_in_place_what_the_loop_and_the_whole_table_give(
         params, monkeypatch):
-    """Three lanes of 5, 9 and 14 tokens in a table of 96 positions: the
-    tick reads 16 of them.  Its logits are the whole-table tick's to
-    rounding (the reductions are shorter, the terms the same) and its
-    tokens the same."""
+    """Three lanes of 5, 9 and 14 tokens in a table of 96 positions, and a
+    lane that is not in the tick: the tick reads each lane's blocks in
+    place; with a pool the kernel cannot take it reads 16 positions of
+    every lane in the loop.  The logits of either are the whole-table
+    tick's to rounding (the reductions are shorter, the terms the same)
+    and the tokens the same."""
     block, table_width = 8, CFG.max_seq // 8
-    lens = np.array([5, 9, 14], np.int32)
+    lens = np.array([5, 9, 14, 0], np.int32)
+    live = np.array([True, True, True, False])
     assert _attended(int(lens.max()), table_width, block) == 16
-    pool_k = [jax.numpy.zeros((7, block, CFG.n_kv_heads, CFG.head_dim))
+    pool_k = [jax.numpy.zeros((7, CFG.n_kv_heads, block, CFG.head_dim))
               for _ in range(CFG.n_layers)]
     pool_v = list(pool_k)
-    tables = np.zeros((3, table_width), np.int32)
-    tables[:, :2] = np.arange(1, 7).reshape(3, 2)
+    tables = np.zeros((4, table_width), np.int32)
+    tables[:3, :2] = np.arange(1, 7).reshape(3, 2)
     rng = np.random.default_rng(0)
-    for lane, n in enumerate(lens):
+    for lane, n in enumerate(lens[:3]):
         chunk = pad_prompt(rng.integers(1, 128, (1, n)), 16)
         _, pool_k, pool_v, _ = tfm.paged_prefill_chunk(
             params, chunk, pool_k, pool_v, tables[lane], np.int32(0),
             np.int32(n), jax.random.PRNGKey(lane), np.float32(0),
             np.int32(0), cfg=CFG, block_size=block)
-    products = []
-    real = tfm._mm
+    products, kernel_lengths = [], []
+    real, in_place = tfm._mm, tfm._attend_in_place
 
     def spy(x, w):
         products.append(real(x, w))
         return products[-1]
 
+    def kernel_spy(q, pool_k, pool_v, tables, lengths, cfg):
+        kernel_lengths.append(np.asarray(lengths).tolist())
+        return in_place(q, pool_k, pool_v, tables, lengths, cfg)
+
     def tick():
         """Run eagerly, so that the head's product can be seen."""
         tokens, *_ = tfm.paged_decode_tick(
-            params, jax.numpy.array([3, 5, 7]), pool_k, pool_v,
+            params, jax.numpy.array([3, 5, 7, 0]), pool_k, pool_v,
             jax.numpy.asarray(tables), jax.numpy.asarray(lens),
-            np.zeros(3, np.float32), np.zeros(3, np.int32),
-            jax.random.split(jax.random.PRNGKey(1), 3), cfg=CFG, n=3,
+            jax.numpy.asarray(live), np.zeros(4, np.float32),
+            np.zeros(4, np.int32),
+            jax.random.split(jax.random.PRNGKey(1), 4), cfg=CFG, n=4,
             block_size=block)
-        return np.asarray(tokens), np.asarray(products[-1])
+        return np.asarray(tokens)[live], np.asarray(products[-1])[live]
 
     def whole_table(q, pool_k, pool_v, tables, pos, cfg, block_size):
         _, l, acc = tfm._attend_columns(
@@ -991,12 +1015,25 @@ def test_decode_tick_at_a_narrow_width_agrees_with_the_whole_table(
         return (acc / l).astype(q.dtype)
 
     monkeypatch.setattr(tfm, "_mm", spy)
+    monkeypatch.setattr(tfm, "_attend_in_place", kernel_spy)
     tokens, logits = tick()
+    assert kernel_lengths == CFG.n_layers * [[6, 10, 15, 0]]
+    # what the engine counts for such a tick is the kernel's trip count
+    assert tfm.DecoderPrograms(CFG, block)._tick_reads(
+        lens[live], table_width) == (
+            paged_decode.steps_read(np.array(kernel_lengths[0])[live], block)
+            * paged_decode.STEP_BLOCKS * block).tolist()
+    monkeypatch.setattr(tfm, "reads_in_place", lambda pool: False)
+    assert tfm.DecoderPrograms(CFG, block)._tick_reads(
+        lens[live], table_width) is None
+    loop_tokens, loop_logits = tick()
     monkeypatch.setattr(tfm, "paged_attention", whole_table)
     whole_tokens, whole_logits = tick()
+    assert len(kernel_lengths) == CFG.n_layers
     assert logits.shape == (3, CFG.vocab_size)
+    np.testing.assert_allclose(loop_logits, whole_logits, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(logits, whole_logits, rtol=1e-5, atol=1e-5)
-    assert tokens.tolist() == whole_tokens.tolist()
+    assert tokens.tolist() == loop_tokens.tolist() == whole_tokens.tolist()
 
 
 # -- speculative decoding: draft/verify over the paged KV cache ------------

@@ -1,8 +1,10 @@
 """``transformer.paged_attention``: the grouped contraction against a plain
 float32 reference, at every table width it chooses among, and the shape of
-the decode tick it leaves behind (no repeat of the gathered cache to every
-query head, no float32 copy of it, no copy of a layer's pool) in the traced
-program and in the module the v5e's compiler makes of it."""
+the decode tick it leaves behind where the tick gathers (no repeat of the
+gathered cache to every query head, no float32 copy of it) in the traced
+program; and the chat cell's tick and chunk as the v5e's compiler leaves
+them: the tick reads the pool in place through one kernel a layer, the chunk
+writes whole blocks, neither copies a layer's pool."""
 
 import functools
 import re
@@ -45,8 +47,8 @@ def _paged_case(lengths, t, n_rep, dtype, seed, unused=KvBlockPool.TRASH):
     n_blocks = b * WIDTH
     # the trash block holds what padding wrote there: finite and large, so a
     # key that slips past the mask shows
-    pool_k = np.full((n_blocks + 1, BLOCK, N_KV, HEAD_DIM), 50.0, np.float32)
-    pool_v = np.full((n_blocks + 1, BLOCK, N_KV, HEAD_DIM), -50.0, np.float32)
+    pool_k = np.full((n_blocks + 1, N_KV, BLOCK, HEAD_DIM), 50.0, np.float32)
+    pool_v = np.full((n_blocks + 1, N_KV, BLOCK, HEAD_DIM), -50.0, np.float32)
     tables = np.full((b, WIDTH), unused, np.int32)
     free = rng.permutation(np.arange(1, n_blocks + 1))
     for lane, length in enumerate(lengths):
@@ -54,8 +56,8 @@ def _paged_case(lengths, t, n_rep, dtype, seed, unused=KvBlockPool.TRASH):
         tables[lane, :used], free = free[:used], free[used:]
         for col in range(used):
             rows = slice(col * BLOCK, (col + 1) * BLOCK)
-            pool_k[tables[lane, col]] = k[lane, rows]
-            pool_v[tables[lane, col]] = v[lane, rows]
+            pool_k[tables[lane, col]] = k[lane, rows].swapaxes(0, 1)
+            pool_v[tables[lane, col]] = v[lane, rows].swapaxes(0, 1)
     # the last t positions of each lane ask, as a verify tick's do
     pos = (lengths[:, None] - t + np.arange(t)[None, :]).astype(np.int32)
     cast = lambda a: jnp.asarray(a, dtype)
@@ -147,7 +149,7 @@ def test_paged_attention_loops_only_where_the_table_has_widths(width, group):
     a wider one is gathered a group of columns at a time, inside a loop."""
     cfg = _cfg(4, "bfloat16")
     sds = jax.ShapeDtypeStruct
-    pool = sds((9, BLOCK, N_KV, HEAD_DIM), cfg.jdtype)
+    pool = sds((9, N_KV, BLOCK, HEAD_DIM), cfg.jdtype)
     jaxpr = jax.make_jaxpr(functools.partial(
         tfm.paged_attention, cfg=cfg, block_size=BLOCK))(
             sds((2, 1, cfg.n_heads, HEAD_DIM), cfg.jdtype), pool, pool,
@@ -157,7 +159,7 @@ def test_paged_attention_loops_only_where_the_table_has_widths(width, group):
     assert "cond" not in outer
     gathered = [aval.shape for name, aval in _intermediates(jaxpr.jaxpr)
                 if name == "gather" and aval.shape[-1] == HEAD_DIM]
-    assert gathered == 2 * [(2, group or width, BLOCK, N_KV, HEAD_DIM)]
+    assert gathered == 2 * [(2, group or width, N_KV, BLOCK, HEAD_DIM)]
 
 
 # -- the decode tick's program ------------------------------------------------
@@ -167,12 +169,12 @@ def _decode_tick_args(cfg, n, table_width, block_size, n_blocks):
     sds = jax.ShapeDtypeStruct
     params = jax.eval_shape(
         lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
-    pool = [sds((n_blocks + 1, block_size, cfg.n_kv_heads, cfg.head_dim),
+    pool = [sds((n_blocks + 1, cfg.n_kv_heads, block_size, cfg.head_dim),
                 cfg.jdtype) for _ in range(cfg.n_layers)]
     return (params, sds((n,), jnp.int32), pool, pool,
             sds((n, table_width), jnp.int32), sds((n,), jnp.int32),
-            sds((n,), jnp.float32), sds((n,), jnp.int32),
-            sds((n, 2), jnp.uint32))
+            sds((n,), jnp.bool_), sds((n,), jnp.float32),
+            sds((n,), jnp.int32), sds((n, 2), jnp.uint32))
 
 
 def _intermediates(jaxpr):
@@ -208,11 +210,13 @@ def _assert_cache_kept_at_its_width(values, cfg, n, group, table):
         assert not (table in dims and size >= table * lanes_keys), label
 
 
-def test_decode_tick_never_repeats_or_widens_the_gathered_cache():
-    """At a GQA configuration (4 query heads a KV head, bf16) nothing in the
-    tick has a lane's keys once for every QUERY head, nothing of float32 is
-    as large as the gathered blocks, and the gather is a group of columns
-    wide (an eighth of the table), never the table."""
+def test_decode_tick_never_repeats_or_widens_the_gathered_cache(monkeypatch):
+    """At a GQA configuration (4 query heads a KV head, bf16) whose pool
+    the kernel cannot take as it lies (on a chip: a head of 8), nothing in
+    the tick has a lane's keys once for every QUERY head, nothing of
+    float32 is as large as the gathered blocks, and the gather is a group
+    of columns wide (an eighth of the table), never the table."""
+    monkeypatch.setattr(tfm, "reads_in_place", lambda pool: False)
     n, width, block = 4, 128, 8
     cfg = _cfg(4, "bfloat16", n_kv=2, hd=8, vocab_size=64, d_ff=64,
                n_layers=2, max_seq=width * block)
@@ -241,53 +245,104 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("n_layers", [1, 16])
-def test_decode_tick_compiled_for_v5e_keeps_the_cache_at_its_width(
-        one_chip, n_layers):
-    """The chat cell's tick (Mistral-7B widths but for the vocabulary, 16
-    lanes, a table of 2,048 positions; one layer, and the cell's sixteen) as
-    the v5e's compiler leaves it: the gather is of 256 positions a lane, no
-    operation has a lane's keys once for every query head, and no float32
-    tensor is of the gathered blocks' size.  PR 28's parent had two of the
-    first (broadcasts of 256 and 512 MB a layer) and two of the second.  Nor
-    does the loop over the table's columns cost a layer's pool its place:
-    every pool is still an output that aliases its donated argument, and
-    nothing copies one (67 MB), in place or through another memory space."""
+def _compiled_for_v5e(program, args, donate=(2, 3), **static):
+    """The text of ``program`` jitted with its pools (and a family's fixed
+    state) donated and compiled for the described chip.  A module compiled
+    for a described chip cannot be read back from the persistent cache: it
+    is kept out."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    n, width, block = 16, 128, 16
-    cfg = tfm.TransformerConfig(
-        vocab_size=4096, d_model=4096, n_layers=n_layers, n_heads=32,
-        n_kv_heads=8, d_ff=14336, max_seq=width * block, rope_theta=1e6,
-        dtype="bfloat16")
-    args = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        _decode_tick_args(cfg, n, width, block, n_blocks=2048))
-    tick = jax.jit(functools.partial(
-        tfm.paged_decode_tick, cfg=cfg, n=n, block_size=block),
-        donate_argnums=(2, 3))
-    # a module compiled for a described chip cannot be read back from the
-    # persistent cache: keep it out
+    jitted = jax.jit(functools.partial(program, **static),
+                     donate_argnums=donate)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        text = tick.lower(*args).compile().as_text()
+        return jitted.lower(*args).compile().as_text()
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
         compilation_cache.reset_cache()
-    names = {"f32": "float32", "bf16": "bfloat16"}
-    _assert_cache_kept_at_its_width(
-        ((f"{dtype}[{dims}]", names[dtype],
-          tuple(int(d) for d in dims.split(",")))
-         for dtype, dims in set(re.findall(r"\b(f32|bf16)\[([\d,]+)\]", text))),
-        cfg, n, attention_widths(width)[0] * block, width * block)
-    assert len(re.findall(r" while\(", text)) == n_layers
-    pool = rf"bf16\[2049,{block},{cfg.n_kv_heads},{cfg.head_dim}\]"
-    copied = re.findall(rf"= \(?{pool}[^=]* (?:copy|copy-start|slice-start)\(.*", text)
+
+
+def _chat_cell(n_layers, width=128, block=16):
+    """Mistral-7B's widths but for the vocabulary, a table of 2,048
+    positions over a pool of 2,048 blocks."""
+    return tfm.TransformerConfig(
+        vocab_size=4096, d_model=4096, n_layers=n_layers, n_heads=32,
+        n_kv_heads=8, d_ff=14336, max_seq=width * block, rope_theta=1e6,
+        dtype="bfloat16")
+
+
+def _assert_pools_stay_in_place(text, cfg, block, n_blocks=2048):
+    """Every pool is an output that aliases its donated argument, and
+    nothing copies or transposes one (67 MB), in place or through another
+    memory space."""
+    pool = rf"bf16\[{n_blocks + 1},{cfg.n_kv_heads},{block},{cfg.head_dim}\]"
+    copied = re.findall(
+        rf"= \(?{pool}[^=]* (?:copy|copy-start|slice-start|transpose)\(.*",
+        text)
     assert not copied, copied[:2]
     aliases = re.search(r"input_output_alias=\{(.*?)\}, entry_comp", text)
-    assert aliases.group(1).count("-alias") == 2 * n_layers
+    assert aliases.group(1).count("-alias") == 2 * cfg.n_layers
+
+
+@pytest.mark.parametrize("n_layers", [1, 16])
+def test_decode_tick_compiled_for_v5e_reads_the_pool_in_place(
+        one_chip, monkeypatch, n_layers):
+    """The chat cell's tick (16 lanes; one layer, and the cell's sixteen) as
+    the v5e's compiler leaves it: a call of the paged decode kernel a layer
+    and no loop over the table's columns (PR 30's, which gathered 256
+    positions a lane a trip); nothing has a lane's gathered blocks, in
+    either order of heads and positions; the new rows go in as a scatter of
+    rows of 128, which leaves the pool as the kernel reads it."""
+    n, width, block = 16, 128, 16
+    cfg = _chat_cell(n_layers, width, block)
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        _decode_tick_args(cfg, n, width, block, n_blocks=2048))
+    # the step asks the backend whether the kernel can take the pool as it
+    # lies: here it is compiled for the chip that is described
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _compiled_for_v5e(tfm.paged_decode_tick, args, cfg=cfg, n=n,
+                             block_size=block)
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) \
+        == n_layers
+    assert not re.findall(r" while\(", text)
+    kv, hd = cfg.n_kv_heads, cfg.head_dim
+    shapes = {tuple(int(d) for d in dims.split(","))
+              for dims in re.findall(r"\b(?:f32|bf16)\[([\d,]+)\]", text)}
+    group = attention_widths(width)[0]
+    gathered = {s for s in shapes if s[0] == n and set(s[1:]) in (
+        {kv, group * block, hd}, {kv, group, block, hd},
+        {kv, width * block, hd}, {kv, width, block, hd})}
+    assert not gathered, gathered
+    _assert_pools_stay_in_place(text, cfg, block)
+
+
+@pytest.mark.parametrize("chunk", [128, 512])
+def test_prefill_chunk_compiled_for_v5e_writes_whole_blocks(one_chip, chunk):
+    """The chat cell's chunk (one layer; its narrowest and its widest
+    bucket) as the v5e's compiler leaves it: the new K and V go in as one
+    scatter a pool whose window is a whole block, ``chunk / 16`` of them,
+    and not ``chunk x 8`` rows of 128; the read is the loop over the
+    table's column groups."""
+    width, block = 128, 16
+    cfg = _chat_cell(1, width, block)
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    params, _, pool_k, pool_v, *_ = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        _decode_tick_args(cfg, 1, width, block, n_blocks=2048))
+    args = (params, sds((1, chunk), jnp.int32), pool_k, pool_v,
+            sds((width,), jnp.int32), sds((), jnp.int32), sds((), jnp.int32),
+            sds((2,), jnp.uint32), sds((), jnp.float32), sds((), jnp.int32))
+    text = _compiled_for_v5e(tfm.paged_prefill_chunk, args, cfg=cfg,
+                             block_size=block)
+    scatters = re.findall(r" scatter\(.*update_window_dims=\{([\d,]*)\}", text)
+    assert scatters == 2 * ["1,2,3"]
+    blocks = rf"bf16\[{chunk // block},{cfg.n_kv_heads},{block},{cfg.head_dim}\]"
+    assert re.search(blocks, text)
+    assert len(re.findall(r" while\(", text)) == 1
+    _assert_pools_stay_in_place(text, cfg, block)
 
 
 def test_sambay_decode_tick_compiled_for_v5e_reads_the_pool_in_place(
@@ -300,8 +355,6 @@ def test_sambay_decode_tick_compiled_for_v5e_reads_the_pool_in_place(
     lane's whole logical cache or the gathered blocks (PR 31 had both, 251
     MB each, for keys and for values), and the pools and every lane's
     state are still outputs that alias their donated arguments."""
-    from jax.experimental.compilation_cache import compilation_cache
-
     from client_tpu.serve.models import sambay
 
     n, width, block, n_blocks = 32, 192, 16, 6144
@@ -326,20 +379,11 @@ def test_sambay_decode_tick_compiled_for_v5e_reads_the_pool_in_place(
             shaped((n, width), "int32"), shaped((n,), "int32"),
             shaped((n,), "bool"), shaped((n,), "float32"),
             shaped((n,), "int32"), shaped(keys.shape, keys.dtype))
-    tick = jax.jit(functools.partial(
-        sambay.sambay_decode_tick, cfg=cfg, n=n, block_size=block),
-        donate_argnums=(2, 3, 4))
     # the step asks the backend whether to compile the kernel or interpret
     # it: here it is compiled for the chip that is described
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        text = tick.lower(*args).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
+    text = _compiled_for_v5e(sambay.sambay_decode_tick, args,
+                             donate=(2, 3, 4), cfg=cfg, n=n, block_size=block)
     readers = cfg.kinds.count(sambay.FULL) + cfg.kinds.count(sambay.CROSS)
     assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) \
         == readers == 8
@@ -370,8 +414,6 @@ def test_cohere2moe_decode_tick_compiled_for_v5e_keeps_its_kernels(
     grouped expert product (gate-and-up and down a layer), which take the
     pool and the expert stacks as they lie; nothing has a lane's gathered
     table, and the pools are outputs that alias their donated arguments."""
-    from jax.experimental.compilation_cache import compilation_cache
-
     from client_tpu.serve.models import cohere2moe
 
     n, width, block, n_blocks = 32, 560, 16, 12288
@@ -394,18 +436,9 @@ def test_cohere2moe_decode_tick_compiled_for_v5e_keeps_its_kernels(
             shaped((n, width), "int32"), shaped((n,), "int32"),
             shaped((n,), "bool"), shaped((n,), "float32"),
             shaped((n,), "int32"), shaped(keys.shape, keys.dtype))
-    tick = jax.jit(functools.partial(
-        cohere2moe.cohere2moe_decode_tick, cfg=cfg, n=n, block_size=block),
-        donate_argnums=(2, 3))
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        text = tick.lower(*args).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
+    text = _compiled_for_v5e(cohere2moe.cohere2moe_decode_tick, args,
+                             cfg=cfg, n=n, block_size=block)
     assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) \
         == layers + 2 * layers == 12
     kv, hd = cfg.n_kv_heads, cfg.head_dim

@@ -434,7 +434,7 @@ def test_pool_allocates_one_paged_layer_and_the_lanes_fixed_state():
     pool = KvBlockPool(dense, n_blocks=4, block_size=BLOCK, registry=reg,
                        lanes=3)
     assert len(pool.pools["k"]) == 3 and pool.lane_state == {}
-    assert pool.pools["v"][0].shape == (5, BLOCK, 1, 16)
+    assert pool.pools["v"][0].shape == (5, 1, BLOCK, 16)
     assert pool.state_bytes == 0
 
 
