@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from benchmark import reference_axk1 as reference
 from benchmark import weights_axk1 as weights
+from client_tpu.ops import latent_prefill
 from client_tpu.ops.grouped_matmul import grouped_matmul
 from client_tpu.serve.lm import KvBlockPool, LmEngine
 from client_tpu.serve.metrics import Registry
@@ -364,6 +365,7 @@ def test_engine_streams_follow_the_reference_and_count():
         assert 0 <= t["experts_hit"] <= min(held, t["expert_rows"])
         assert t["expert_rows_max"] <= t["expert_rows"]
         assert t["kv_positions_read"] >= t["kv_positions_live"] > 0
+        assert ("kv_rows_rebuilt" in t) == (t["kind"] == "prefill_chunk")
         rows = len(t["lanes"]) if t["kind"] == "decode" else t["tokens"]
         assert t["expert_rows"] <= rows * CFG.top_k * 3
     for t in (t for t in ticks if t["kind"] == "decode"):
@@ -377,9 +379,11 @@ def test_engine_streams_follow_the_reference_and_count():
 
 
 def test_tick_fields_count_what_the_programs_read():
-    """``kv_positions_live`` and ``kv_positions_read`` by hand: block 4, a
-    kernel's step of 16 blocks is 64 positions, a chunk's group of 32
-    blocks 128; four layers, every one over the whole context."""
+    """``kv_positions_live``, ``kv_positions_read`` and a chunk's
+    ``kv_rows_rebuilt`` by hand: block 4, a kernel's step of 16 blocks is 64
+    positions, a chunk's group of 32 blocks 128; four layers, every one over
+    the whole context.  What a chunk reads is the chunk kernel's own trip
+    count (``latent_prefill.groups_read``) times its group."""
     programs = CFG.family(CFG, BLOCK)
     # lanes of lengths 72 and 10 attend 73 and 11 rows: two steps and one
     got = programs.tick_fields("decode", [72, 10])
@@ -391,8 +395,17 @@ def test_tick_fields_count_what_the_programs_read():
     # the chunk's last row (131) lies in the second group
     got = programs.tick_fields("prefill_chunk", [129], start=124, width=8)
     assert got == {"kv_positions_live": 4 * 129,
-                   "kv_positions_read": 4 * 256}
+                   "kv_positions_read": 4 * 256, "kv_rows_rebuilt": 4 * 256}
     assert programs.attended_positions(127, 40) == 128
+    span = latent_prefill.group_span(BLOCK)
+    for start, width in ((0, 8), (120, 8), (121, 8), (128, 4), (380, 8)):
+        trips = latent_prefill.groups_read(start + width - 1, BLOCK)
+        got = programs.tick_fields("prefill_chunk", [start + width],
+                                   start=start, width=width)
+        assert got["kv_positions_read"] == got["kv_rows_rebuilt"] \
+            == CFG.n_layers * trips * span
+        assert programs.attended_positions(start + width - 1, 40) \
+            == trips * span
     assert programs.window is None and not programs.recurrent
 
 

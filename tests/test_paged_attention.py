@@ -527,15 +527,20 @@ def test_axk1_decode_tick_compiled_for_v5e_reads_the_latent_pool_in_place(
     _assert_latent_pool_stays_in_place(text, cfg, n_blocks, block)
 
 
-def test_axk1_prefill_chunk_compiled_for_v5e_rebuilds_a_group_at_a_time(
-        one_chip, monkeypatch):
-    """The longdoc cell's chunk of 512 rows: the expanded form's loop over
-    groups of 32 table columns stays a loop (a group's rebuilt keys and
-    values, 512 positions x 64 heads, never the table's 17,152), the expert
-    layer's two grouped products are there, and the pools stay in place."""
+@pytest.mark.parametrize("chunk", [128, 512])
+def test_axk1_prefill_chunk_compiled_for_v5e_attends_in_the_kernel(
+        one_chip, monkeypatch, chunk):
+    """The longdoc cell's chunk, its narrowest and its widest bucket: a call
+    of ``ops/latent_prefill`` a layer where the expanded form's loop over
+    groups of table columns was (the one ``while`` left is the grouped
+    product's search for its group edges), the expert layer's two grouped
+    products, no rebuilt keys or values of a group (512 positions x 64
+    heads) and no scores of one outside the kernel, and the pools stay in
+    place."""
+    from client_tpu.ops import latent_prefill
     from client_tpu.serve.models import axk1
 
-    width, block, n_blocks, chunk = 1072, 16, 34304, 512
+    width, block, n_blocks = 1072, 16, 34304
     cfg, params, pools, shaped = _axk1_cell_args(one_chip)
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
     args = (params, shaped((1, chunk), "int32"), pools,
@@ -545,11 +550,21 @@ def test_axk1_prefill_chunk_compiled_for_v5e_rebuilds_a_group_at_a_time(
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     text = _compiled_for_v5e(axk1.axk1_prefill_chunk, args, donate=(2,),
                              cfg=cfg, block_size=block)
-    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) == 2
-    assert len(re.findall(r" while\(", text)) >= cfg.n_layers
-    span = axk1.GROUP_BLOCKS * block
-    shapes = {tuple(int(d) for d in dims.split(","))
-              for dims in re.findall(r"\b(?:f32|bf16)\[([\d,]+)\]", text)}
-    assert any(span in s and cfg.n_heads in s for s in shapes)
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) \
+        == cfg.n_layers + 2
+    loops = [line for line in text.splitlines() if " while(" in line]
+    assert all("searchsorted" in line for line in loops), loops[:1]
+    span = latent_prefill.group_span(block)
+    typed = {(kind, tuple(int(d) for d in dims.split(",")))
+             for kind, dims in re.findall(r"\b(f32|bf16)\[([\d,]+)\]", text)}
+    shapes = {s for _, s in typed}
+    heads = cfg.n_heads
+    assert not {s for kind, s in typed
+                if kind == "f32" and sorted(s) == sorted((heads, chunk, span))}
+    if chunk != span:   # (else a group's rebuilt rows have the queries' shape)
+        weights = {(heads, cfg.nope_dim, cfg.kv_lora_rank),
+                   (heads, cfg.kv_lora_rank, cfg.v_dim)}
+        assert not {s for s in shapes
+                    if heads in s and span in s and s not in weights}
     assert not {s for s in shapes if width * block in s}
     _assert_latent_pool_stays_in_place(text, cfg, n_blocks, block)
