@@ -14,9 +14,10 @@ single engine rollup) or a flight-recorder JSON-lines dump whose
 
 Per engine it prints tick counts by kind, the ranked per-phase table
 (seconds + percentage of covered time), the
-compute/dispatch/device_wait/host/idle attribution split, and per-model
-device share / MFU — the table the 38%-idle-link question is answered
-from.
+compute/dispatch/device_wait/host/idle attribution split, the stalls
+(count and seconds by cause, and the last records: what held a tick or
+a phase, serve/prof.py's rule), and per-model device share / MFU — the
+table the 38%-idle-link question is answered from.
 
 ``--live`` spins an in-process engine (the cnn224 headline model), runs
 a short unary workload through it, and renders its own report — the
@@ -32,7 +33,8 @@ import sys
 
 from client_tpu.serve.prof import attribute_phases
 
-__all__ = ["load_reports", "rollup_from_ticks", "render_engine", "main"]
+__all__ = ["load_reports", "rollup_from_ticks", "stalls_from_records",
+           "render_engine", "main"]
 
 
 def _engines_of(obj):
@@ -47,10 +49,25 @@ def _engines_of(obj):
     return []
 
 
-def rollup_from_ticks(ticks):
+def stalls_from_records(records):
+    """A rollup's ``stalls`` block from ``stall`` records (a flight
+    dump's): count and seconds by cause, and the last eight."""
+    by_cause = {}
+    for record in records:
+        row = by_cause.setdefault(
+            str(record.get("cause")), {"count": 0, "seconds": 0.0})
+        row["count"] += 1
+        row["seconds"] = round(
+            row["seconds"] + float(record.get("seconds", 0.0)), 6)
+    return {"by_cause": dict(sorted(by_cause.items())),
+            "last": list(records[-8:])}
+
+
+def rollup_from_ticks(ticks, stalls=()):
     """Re-roll flight-dump ``prof_tick`` records into per-engine rollup
     dicts (the ring's aggregation replayed offline; MFU needs the live
-    profiler's FLOP totals, so it is absent here)."""
+    profiler's FLOP totals, so it is absent here), each with the dump's
+    ``stall`` records of its engine."""
     by_engine = {}
     for record in ticks:
         engine = str(record.get("engine", ""))
@@ -97,6 +114,8 @@ def rollup_from_ticks(ticks):
                 for m, v in sorted(models.items())
             },
             "attribution": attribute_phases(phases, wall_s=wall),
+            "stalls": stalls_from_records([
+                r for r in stalls if str(r.get("engine", "")) == engine]),
         })
     return rollups
 
@@ -107,6 +126,7 @@ def load_reports(paths):
     a postmortem artifact that does not parse should be loud."""
     engines = []
     ticks = []
+    stalls = []
     for path in paths:
         with open(path, "r", encoding="utf-8") as f:
             text = f.read()
@@ -128,9 +148,11 @@ def load_reports(paths):
             if isinstance(record, dict):
                 if record.get("kind") == "prof_tick":
                     ticks.append(record)
+                elif record.get("kind") == "stall":
+                    stalls.append(record)
                 else:
                     engines.extend(_engines_of(record))
-    engines.extend(rollup_from_ticks(ticks))
+    engines.extend(rollup_from_ticks(ticks, stalls))
     return engines
 
 
@@ -163,6 +185,27 @@ def render_engine(rollup, out):
     for name, row in (rollup.get("phases") or {}).items():
         out.write(
             f"    {name:<18} {row['s']:>10.4f}s  {row['pct']:>6.2f}%\n"
+        )
+    stalls = rollup.get("stalls") or {}
+    if stalls.get("by_cause"):
+        out.write(
+            "  stalls: "
+            + " | ".join(
+                f"{cause} {row['count']} ({row['seconds']:.3f}s)"
+                for cause, row in stalls["by_cause"].items()
+            )
+            + "\n"
+        )
+    for record in stalls.get("last") or ():
+        tick = record.get("tick") or {}
+        out.write(
+            f"    stall {record.get('cause')}"
+            f" {record.get('seconds', 0.0):.3f}s"
+            f" phase={record.get('phase') or '-'}"
+            f" tick={tick.get('kind') or '-'}/{tick.get('width') or '-'}"
+            f" host_pause={record.get('host_pause_s', 0.0):.3f}s"
+            f" gc={record.get('gc_s', 0.0):.3f}s"
+            f" frames={'yes' if record.get('frames') else 'none'}\n"
         )
     for model, row in (rollup.get("models") or {}).items():
         bits = [

@@ -255,6 +255,10 @@ class ModelBatcher:
         self._busy = busy  # engine BusyTracker (duty-cycle metric), optional
         self._registry = registry  # engine metrics Registry (shed counters)
         self.prof = prof  # engine PhaseProfiler: one "batch" tick per group
+        # the loop's brackets: with a profiler they are phases its pulse
+        # watches (one open past prof.STALL_S is a stall record, but the
+        # gather, which waits by design), without one bare annotations
+        self._span = prof.span if prof is not None else annotation
         self.max_batch = max(int(model.max_batch_size), 1)
         self.max_queue_delay_s = max_queue_delay_s
         # Admission control: requests beyond this queue depth are shed with
@@ -534,7 +538,7 @@ class ModelBatcher:
         # for device groups), so the H2D stream keeps flowing while earlier
         # batches' completions are in flight.
         while True:
-            with annotation("batch.gather"):
+            with self._span("batch.gather"):
                 group = self._gather()
             if group is None:
                 return
@@ -544,7 +548,7 @@ class ModelBatcher:
             # keeps filling meanwhile, and _topup folds those arrivals into
             # this batch — depth and batch size grow together under load.
             sem.acquire()
-            with annotation("batch.dispatch"):
+            with self._span("batch.dispatch"):
                 self._topup(group)
                 dispatched = self._dispatch(group)
             if dispatched is None:
@@ -553,7 +557,7 @@ class ModelBatcher:
             with self._cond:
                 self._inflight += 1
             if device:
-                with annotation("batch.handoff"):
+                with self._span("batch.handoff"):
                     arrays = self._handoff_device(*dispatched)
                 if arrays is None:  # handoff failed; group already notified
                     if self._busy is not None:

@@ -78,7 +78,7 @@ from client_tpu.serve.lm.policy import (
 from client_tpu.serve.lm.prefix import PrefixCache
 from client_tpu.serve.lm.spec import LaneSpec, SpecConfig
 from client_tpu.serve.metrics import FLEET_HELP, LM_PREFIX_HELP, LM_SPEC_HELP
-from client_tpu.serve.prof import NULL_TICK, PhaseProfiler
+from client_tpu.serve.prof import NULL_PHASE, NULL_TICK, PhaseProfiler
 
 # sentinel object closing a stream's token queue
 _CLOSE = object()
@@ -291,6 +291,7 @@ class LmEngine:
         # the tick's device time (serve/_completion.py)
         self._observer = CompletionObserver(name="lm-engine-watch")
         self._device_s = 0.0  # completed, not yet in the profiler (_cv)
+        self._t_done_prev = None  # the observer's thread's: see _tick_done
 
         # continuous profiler (serve/prof.py): each scheduler pass is one
         # tick with schedule/dispatch/device-wait/delivery phase spans;
@@ -432,7 +433,22 @@ class LmEngine:
         ``n_lanes`` — the fairness/jitter evidence tests and ops read —
         and, once the tick's device work has completed, ``t_done`` and
         ``device_s`` (serve/_completion.py; a ``draft`` runs on the host
-        and has neither).  The ``prefill_chunk`` that ends a prompt also
+        and has neither).  The dispatch between ``t0`` and ``t1`` is two
+        spans, ``upload_s`` (every host array of the tick made a device
+        array: the ``upload`` phase) and ``call_s`` (the program's call
+        alone: the ``*_dispatch`` phase), so ``upload_s + call_s <= t1 -
+        t0``.  Once complete, an entry also carries ``host_pause_s``: the
+        seconds, of those its ``device_s`` counts, in which the
+        interpreter was held (serve/prof.py's pulse), so that a completion
+        stamped late does not read as a slow device without saying so (0.0
+        as a rule).  An entry the profiler's stall rule marked carries
+        ``stall`` (``upload``, ``call``, ``compile``, ``host_pause`` or
+        ``device``) and ``stall_s`` (the span's length, or the device
+        time's excess over its kind's running median); a sound run has
+        neither on any entry.  These five come from the profiler's phases
+        and its pulse: with the profiler disarmed
+        (``PhaseProfiler.arm(False)``) an entry has none of them.  The
+        ``prefill_chunk`` that ends a prompt also
         carries the first token's wait: ``t_submit``, ``t_admit`` and,
         once the token is on its stream's queue, ``t_delivered``.  All on
         ``time.monotonic()``'s clock.  Every entry that dispatched device
@@ -1036,9 +1052,40 @@ class LmEngine:
             return job.resume.cancelled
         return job.handle.placed is _CANCELLED
 
-    def _prefill_step(self):
+    def _prefill_step(self, ptick):
         """Dispatch ONE chunk of the current prefill job (outside _cv);
-        the final chunk activates the lane."""
+        the final chunk activates the lane.  The pass's phases: ``build``
+        (the job read, the chunk padded), ``upload``, ``prefill_dispatch``
+        (the program's call) and ``record``."""
+        with ptick.phase("build"):
+            chunk = self._build_chunk()
+        if chunk is None:
+            return
+        job, handle, start, width, chunk = chunk
+        t0 = time.monotonic()
+        fresh = job.chunk_idx == 0
+        with ptick.phase("upload", held=True) as upload:
+            # a family whose lanes carry state takes the lane's slot and
+            # whether this chunk starts it from zero on the device too
+            slot = job.slot
+            if self._recurrent:
+                slot, fresh = jnp.int32(slot), jnp.bool_(fresh)
+            args = (
+                jnp.asarray(chunk), jnp.asarray(job.table), slot,
+                jnp.int32(start), jnp.int32(handle.prompt_len), fresh,
+                job.key, jnp.float32(handle.temperature),
+                jnp.int32(handle.top_k),
+            )
+        with ptick.phase("prefill_dispatch", held=True) as call:
+            tok, job.key, *counted = self._prefill(self.params, self.kv,
+                                                   *args)
+        with ptick.phase("record"):
+            self._chunk_dispatched(job, handle, start, width, t0, tok,
+                                   counted, (upload, call))
+
+    def _build_chunk(self):
+        """``(job, handle, start, width, padded chunk)`` of the chunk to
+        dispatch now, or None where the job has gone."""
         with self._cv:
             # re-read under the lock: a concurrent close() may have
             # aborted and cleared the job since the caller's check
@@ -1075,22 +1122,20 @@ class LmEngine:
             handle.prompt[:, start:start + width], width,
             pad_id=0,
         )
-        t0 = time.monotonic()
-        # the job's first chunk starts the lane's fixed state from zero
-        tok, job.key, *counted = self._prefill(
-            self.params, self.kv, jnp.asarray(chunk),
-            jnp.asarray(job.table), job.slot, jnp.int32(start),
-            jnp.int32(handle.prompt_len), job.chunk_idx == 0, job.key,
-            jnp.float32(handle.temperature), jnp.int32(handle.top_k),
-        )
+        return job, handle, start, width, chunk
+
+    def _chunk_dispatched(self, job, handle, start, width, t0, tok, counted,
+                          spans):
+        """The chunk's entry; after a prompt's last, its lane activated
+        and its first token on the way to the host."""
         job.chunk_idx += 1
         # every chunk samples a token, which nothing donates onward: the
         # chunk's device work is complete when it is
         tokens = min(start + width, handle.prompt_len) - start
         entry = self._log_tick(
             "prefill_chunk", t0, (job.slot,), tok, [start + tokens],
-            start + width - 1, counted=counted, width=width, tokens=tokens,
-            start=start,
+            start + width - 1, counted=counted, spans=spans, width=width,
+            tokens=tokens, start=start,
         )
         if self.registry is not None:
             self.registry.inc(
@@ -1256,12 +1301,45 @@ class LmEngine:
                 fn = self._tick_jits[n] = self._programs.make_tick(n)
         return fn
 
-    def _decode_pass(self):
+    def _decode_pass(self, ptick):
         """One batched decode tick over the active lanes (dispatch
-        outside _cv).  Returns True if a tick ran."""
+        outside _cv).  Returns True if a tick ran.  The pass's phases:
+        ``build`` (the tick's arrays, on the host, under _cv),
+        ``upload`` (all of them to the device), ``decode_dispatch`` (the
+        program's call alone) and ``record`` (the tick's entry)."""
+        with ptick.phase("build"):
+            built = self._build_tick()
+        if built is None:
+            return False
+        n, active, tables, lens, live, temps, topks = built
+        t0 = time.monotonic()
+        with ptick.phase("upload", held=True) as upload:
+            args = (
+                jnp.asarray(tables), jnp.asarray(lens), jnp.asarray(live),
+                jnp.asarray(temps), jnp.asarray(topks),
+            )
+        with ptick.phase("decode_dispatch", held=True) as call:
+            self._tokens, self._keys, *counted = self._programs.tick(
+                self._tick_for(n), self.params, self.kv, self._tokens,
+                *args, self._keys,
+            )
+        with ptick.phase("record"):
+            self._tokens.copy_to_host_async()
+            self._inflight.append((self._tokens, tuple(active), None))
+            self._log_tick(
+                "decode", t0, tuple(i for i, _ in active), self._tokens,
+                lens[live], int(lens.max()),
+                self._tick_reads(lens[live], self._table_width),
+                counted=counted, spans=(upload, call),
+            )
+        return True
+
+    def _build_tick(self):
+        """A decode tick's arrays, on the host: ``(n, active, tables,
+        lens, live, temps, topks)``, or None with no lane to tick."""
         with self._cv:
             if self._closed:
-                return False
+                return None
             n = self._scaler.n_lanes
             # a lane drops out of the tick batch once it has dispatched
             # its full token budget (readback may still be in flight) —
@@ -1274,7 +1352,7 @@ class LmEngine:
                 and self._lanes[i].length < self._lanes[i].limit
             ]
             if not active:
-                return False
+                return None
             # lanes outside the batch (idle, or at-budget awaiting drain)
             # get a trash table + position 0: their scatter lands in the
             # trash block and their garbage token is never delivered
@@ -1297,24 +1375,10 @@ class LmEngine:
             for i, _ in active:
                 self._lanes[i].length += 1  # this tick writes position len
             self._lane_gauges_locked(active_count=len(active))
-        t0 = time.monotonic()
         # ``live``: a lane outside the batch keeps its fixed state as it is
         # (one mid-prefill carries it from chunk to chunk)
         live = np.array([i in included for i in range(n)])
-        self._tokens, self._keys, *counted = self._programs.tick(
-            self._tick_for(n), self.params, self.kv, self._tokens,
-            jnp.asarray(tables), jnp.asarray(lens), live,
-            jnp.asarray(temps), jnp.asarray(topks), self._keys,
-        )
-        self._tokens.copy_to_host_async()
-        self._inflight.append((self._tokens, tuple(active), None))
-        self._log_tick(
-            "decode", t0, tuple(i for i, _ in active), self._tokens,
-            lens[live], int(lens.max()),
-            self._tick_reads(lens[live], self._table_width),
-            counted=counted,
-        )
-        return True
+        return n, active, tables, lens, live, temps, topks
 
     def _verify_for(self, n, w):
         # memoized under _cv exactly like _tick_for: jit here only
@@ -1399,9 +1463,39 @@ class LmEngine:
         if not proposals:
             return False
         self._log_tick("draft", t_draft, tuple(sorted(proposals)))
+        with ptick.phase("build"):
+            built = self._build_verify(n, proposals)
+        if built is None:
+            return False
+        active, w, tables, lens, temps, topks, props, counts = built
+        fn = self._verify_for(n, w)
+        t0 = time.monotonic()
+        with ptick.phase("upload", held=True) as upload:
+            args = (
+                jnp.asarray(tables), jnp.asarray(lens), jnp.asarray(temps),
+                jnp.asarray(topks), self._keys, jnp.asarray(props),
+                jnp.asarray(counts),
+            )
+        with ptick.phase("verify_dispatch", held=True) as call:
+            out, self._tokens, self._keys = self._programs.verify(
+                fn, self.params, self.kv, self._tokens, *args)
+        with ptick.phase("record"):
+            self._log_tick(
+                "verify", t0, tuple(i for i, _ in active), out,
+                [lens[i] for i, _ in active], int(lens.max()) + w - 1,
+                spans=(upload, call))
+        with ptick.phase("device_wait"):
+            vals = np.asarray(out)  # [2, n]: accepted count, correction
+        self._deliver_verified(ptick, active, vals, props, counts)
+        return True
+
+    def _build_verify(self, n, proposals):
+        """A verify tick's arrays, on the host: ``(active, width, tables,
+        lens, temps, topks, props, counts)``, or None where no draft is
+        left to verify."""
         with self._cv:
             if self._closed:
-                return False
+                return None
             active = [
                 (i, self._lanes[i].gen)
                 for i in range(n)
@@ -1409,7 +1503,7 @@ class LmEngine:
                 and self._lanes[i].length < self._lanes[i].limit
             ]
             if not active:
-                return False
+                return None
             included = {i for i, _ in active}
             # gen-checked: a lane cancelled while drafting drops its
             # proposal; other active lanes ride the tick as plain decode
@@ -1419,7 +1513,7 @@ class LmEngine:
                 if i in included and self._lanes[i].gen == gen
             }
             if not drafts:
-                return False
+                return None
             max_d = max(len(toks) for toks in drafts.values())
             w = bucket_for(max_d + 1, self._verify_widths)
             props = np.zeros((n, w - 1), np.int32)
@@ -1444,21 +1538,7 @@ class LmEngine:
                 [self._lanes[i].top_k for i in range(n)], np.int32
             )
             self._lane_gauges_locked(active_count=len(active))
-        t0 = time.monotonic()
-        fn = self._verify_for(n, w)
-        with ptick.phase("verify_dispatch"):
-            out, self._tokens, self._keys = self._programs.verify(
-                fn, self.params, self.kv, self._tokens, jnp.asarray(tables),
-                jnp.asarray(lens), jnp.asarray(temps),
-                jnp.asarray(topks), self._keys, jnp.asarray(props),
-                jnp.asarray(counts),
-            )
-        self._log_tick("verify", t0, tuple(i for i, _ in active), out,
-                       [lens[i] for i, _ in active], int(lens.max()) + w - 1)
-        with ptick.phase("device_wait"):
-            vals = np.asarray(out)  # [2, n]: accepted count, correction
-        self._deliver_verified(ptick, active, vals, props, counts)
-        return True
+        return active, w, tables, lens, temps, topks, props, counts
 
     def _deliver_verified(self, ptick, active, vals, props, counts):
         """Stream one verify tick's accepted drafts + correction token
@@ -1544,11 +1624,16 @@ class LmEngine:
                           device_s=device_s)
 
     def _log_tick(self, kind, t0, slots, result=None, lengths=None,
-                  max_pos=None, reads=None, counted=(), **fields):
+                  max_pos=None, reads=None, counted=(),
+                  spans=(NULL_PHASE, NULL_PHASE), **fields):
         """Append one tick_trace() entry and return it.  *result* is an
         output of the program the tick dispatched at ``t0``: the
         completion observer fills in ``t_done`` and ``device_s`` when it
-        lands.  *lengths* are the real lengths of the lanes the program
+        lands.  *spans* are the dispatch's two closed phases, the upload
+        and the program's call: their seconds are the entry's ``upload_s``
+        and ``call_s`` (none from a disarmed profiler), and ``_tick_done``
+        hands them to the profiler's stall rule.  *lengths* are the real
+        lengths of the lanes the program
         worked on (the engine's own count): the entry carries their sum
         as ``context_tokens`` and, for a model with window layers, what
         of it a window holds as ``window_tokens``.  *max_pos* is the
@@ -1564,6 +1649,9 @@ class LmEngine:
             "kind": kind, "t0": t0, "t1": time.monotonic(), "lanes": slots,
             **fields,
         }
+        if spans[0].seconds is not None:
+            entry["upload_s"] = spans[0].seconds
+            entry["call_s"] = spans[1].seconds
         for vector in counted:
             vector.copy_to_host_async()
         if lengths is not None:
@@ -1592,18 +1680,24 @@ class LmEngine:
                 )
         if result is not None:
             self._observer.watch(
-                result, functools.partial(self._tick_done, entry, counted),
+                result,
+                functools.partial(self._tick_done, entry, counted, spans),
                 t_dispatch_ns=int(t0 * 1e9),
             )
         return entry
 
-    def _tick_done(self, entry, counted, t_done_ns, device_ns, _queue_ns):
+    def _tick_done(self, entry, counted, spans, t_done_ns, device_ns,
+                   _queue_ns):
         """Observer callback: the tick's device work has completed.  The
         scheduler's own read-back of a first token may have seen that
         before the observer's thread was given the interpreter: then the
         delivery bounds the completion.  What the program counted on the
         device is on the host by now (it set out with the tokens): into the
-        entry, and into the series the family names."""
+        entry, and into the series the family names.  Then the profiler's
+        stall rule (``PhaseProfiler.settle``): ``host_pause_s``, the
+        seconds of the device time in which the interpreter was held (this
+        thread stamps the completion only once it is given the
+        interpreter), and on a marked entry ``stall`` and ``stall_s``."""
         t_done, device_s = t_done_ns / 1e9, device_ns / 1e9
         try:
             values = [int(v) for vector in counted for v in np.asarray(vector)]
@@ -1620,9 +1714,17 @@ class LmEngine:
                 else:
                     self.registry.set(series, None, value, help_=help_)
             late_s = max(t_done - entry.get("t_delivered", t_done), 0.0)
-            entry["t_done"] = t_done - late_s
+            entry["t_done"] = t_done = t_done - late_s
             entry["device_s"] = device_s = max(device_s - late_s, 0.0)
             self._device_s += device_s
+        # completions land one at a time, on the observer's thread: the
+        # one before this is this thread's to keep
+        t_prev, self._t_done_prev = self._t_done_prev, t_done
+        marks = self.prof.settle(entry, t_done, device_s, t_prev, *spans,
+                                 inflight=len(self._inflight))
+        if marks:
+            with self._cv:
+                entry.update(marks)
 
     def _drain_one(self, ptick=NULL_TICK):
         tokens_dev, snapshot, first = self._inflight.popleft()
@@ -1901,8 +2003,9 @@ class LmEngine:
             self._admit()  # takes/releases _cv itself; no dispatch inside
         worked = False
         if self._job is not None:
-            with ptick.phase("prefill_dispatch"):
-                self._prefill_step()  # ONE chunk, outside _cv
+            # brackets its own phases, as _decode_pass and _spec_pass do:
+            # build / upload / the program's call / record
+            self._prefill_step(ptick)  # ONE chunk, outside _cv
             ptick.relabel("prefill")
             worked = True
         verified = False
@@ -1913,8 +2016,7 @@ class LmEngine:
             verified = self._spec_pass(ptick)
         ticked = verified
         if not ticked:
-            with ptick.phase("decode_dispatch"):
-                ticked = self._decode_pass()  # ONE decode tick, outside _cv
+            ticked = self._decode_pass(ptick)  # ONE decode tick, outside _cv
         if ticked:
             ptick.relabel("verify" if verified else "decode")
         worked = worked or ticked

@@ -178,6 +178,14 @@ PROF_HELP = {
         "chip's published bf16 peak; absent off-TPU)",
     "ctpu_prof_compute_share_pct":
         "Share of measured device time attributed to each model",
+    "ctpu_prof_host_pauses_total":
+        "Pauses of the interpreter the profiler's pulse timed (a wake-up "
+        "prof.PAUSE_S late: the GIL was held, or the process had no CPU)",
+    "ctpu_prof_host_pause_seconds_total":
+        "Seconds of those pauses",
+    "ctpu_prof_stalls_total":
+        "Stall records (by engine and cause: upload, call, compile, "
+        "host_pause, device, host)",
 }
 
 # Autoscaler control-loop series (written by serve/autoscale.py into the

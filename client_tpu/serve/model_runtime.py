@@ -940,6 +940,7 @@ class InferenceEngine:
         # profiler and are adopted through Model.binder so
         # /v2/debug/prof and flight dumps cover every engine.
         self.prof = PhaseProfiler(name="serve", registry=self.metrics)
+        self.prof.flight = self.flight  # a stall is noted in the ring
         # the frontends' wire-path ticks (deserialize/wait/serialize/
         # send) keep their own ring: their "wait" phase CONTAINS the
         # engine's execute ticks, so sharing a ring would double-count

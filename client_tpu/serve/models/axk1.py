@@ -543,5 +543,5 @@ class AxK1Programs:
         with annotation("lm.axk1_decode_tick"):
             tokens, kv.pools["latent"], keys, counts = fn(
                 params, tokens, kv.pools["latent"], tables, lens,
-                jnp.asarray(live), temps, topks, keys)
+                live, temps, topks, keys)
         return tokens, keys, counts

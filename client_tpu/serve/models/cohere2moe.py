@@ -518,6 +518,6 @@ class Cohere2MoePrograms:
         with annotation("lm.cohere2moe_decode_tick"):
             tokens, kv.pools["k"], kv.pools["v"], keys, counts = fn(
                 params, tokens, kv.pools["k"], kv.pools["v"], tables, lens,
-                jnp.asarray(live), temps, topks, keys,
+                live, temps, topks, keys,
             )
         return tokens, keys, counts

@@ -264,7 +264,7 @@ def lm_streaming_batched_model(name="lm_streaming_batched", runner=None,
         from the front door's TenantQoS."""
         sched = batched.scheduler
         sched.set_registry(engine.metrics)
-        sched.flight = getattr(engine, "flight", None)
+        sched.flight = sched.prof.flight = getattr(engine, "flight", None)
         if getattr(engine, "prof", None) is not None:
             # the scheduler's per-tick profiler joins the server's so
             # /v2/debug/prof and flight dumps cover the LM engine

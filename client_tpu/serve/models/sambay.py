@@ -727,6 +727,6 @@ class SambaYPrograms:
         with annotation("lm.sambay_decode_tick"):
             tokens, kv.pools["k"], kv.pools["v"], kv.lane_state, keys = fn(
                 params, tokens, kv.pools["k"], kv.pools["v"], kv.lane_state,
-                tables, lens, jnp.asarray(live), temps, topks, keys,
+                tables, lens, live, temps, topks, keys,
             )
         return tokens, keys

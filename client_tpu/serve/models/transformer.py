@@ -1043,7 +1043,7 @@ class DecoderPrograms:
              keys):
         tokens, kv.pools["k"], kv.pools["v"], keys = fn(
             params, tokens, kv.pools["k"], kv.pools["v"], tables, lens,
-            jnp.asarray(live), temps, topks, keys,
+            live, temps, topks, keys,
         )
         return tokens, keys
 
