@@ -359,12 +359,12 @@ class LmEngine:
         # reads the blocks in place; None where every lane reads
         # ``attended_positions`` of the longest
         self._tick_reads = self._programs._tick_reads
-        # a family may count more for an entry of tick_trace(): on the host
-        # from the entry's own lengths (``tick_fields``), and on the device
-        # (``counters`` names the int32 vector its programs return beside
-        # their tokens, with the series each feeds).  The engine knows none
-        # of the names.
-        self._tick_fields = getattr(self._programs, "tick_fields", None)
+        # a family counts more for an entry of tick_trace(): on the host
+        # from the entry's own lengths (``tick_fields``), and it may on the
+        # device (``counters`` names the int32 vector its programs return
+        # beside their tokens, with the series each feeds).  The engine
+        # knows none of the names.
+        self._tick_fields = self._programs.tick_fields
         self._counters = getattr(self._programs, "counters", ())
         self._adopt = jax.jit(_adopt)
         self._tick_jits = {}
@@ -1655,8 +1655,7 @@ class LmEngine:
         for vector in counted:
             vector.copy_to_host_async()
         if lengths is not None:
-            if self._tick_fields is not None:
-                entry.update(self._tick_fields(kind, lengths, **fields))
+            entry.update(self._tick_fields(kind, lengths, **fields))
             entry["context_tokens"] = int(sum(lengths))
             window = self._programs.window
             if window is not None:

@@ -57,7 +57,8 @@ from jax import lax
 from client_tpu.ops.latent_prefill import (
     group_span, groups_read, latent_prefill_attention)
 from client_tpu.ops.paged_decode import (
-    STEP_BLOCKS, paged_decode_attention, reads_in_place, steps_read)
+    paged_decode_attention, reads_in_place, step_blocks, steps_read,
+    tick_steps)
 from client_tpu.ops.sampling import select_token
 from client_tpu.serve.models import experts
 from client_tpu.serve.models.cohere2moe import COUNTERS
@@ -478,7 +479,7 @@ class AxK1Programs:
         # CPU (the test platform) has no donation support
         self.donate = (2,) if jax.default_backend() != "cpu" else ()
         self.flops_per_token = lm_flops_per_token(cfg)
-        self._tick_span = STEP_BLOCKS * block_size
+        self._tick_span = step_blocks(latent=True) * block_size
         self._static = dict(cfg=cfg, block_size=block_size)
         self.prefill_jit = jax.jit(
             axk1_prefill_chunk, static_argnames=("cfg", "block_size"),
@@ -498,8 +499,8 @@ class AxK1Programs:
         """The cache rows of each lane that a decode tick reads in a layer,
         for lanes at ``lengths`` before the tick's write: the kernel's
         whole steps."""
-        return (steps_read(np.asarray(lengths) + 1, self.block_size)
-                * self._tick_span).tolist()
+        return (steps_read(np.asarray(lengths) + 1, self.block_size,
+                           latent=True) * self._tick_span).tolist()
 
     def tick_fields(self, kind, lengths, start=None, width=None, **_):
         """What the host can count for a ``tick_trace()`` entry, over the
@@ -509,7 +510,10 @@ class AxK1Programs:
         ``kv_positions_read``, what the program's trip counts read, and on
         a chunk ``kv_rows_rebuilt``, the rows whose keys and values the
         kernel expanded from their latents (every group it walks, whole):
-        over ``tokens`` it is the price of the expanded form by start."""
+        over ``tokens`` it is the price of the expanded form by start; on a
+        decode tick ``kv_steps``, the steps the decode kernel took, and
+        ``kv_steps_full``, those on its straight-line path
+        (``paged_decode.tick_steps``)."""
         lengths = np.asarray(lengths, np.int64)
         layers = self.cfg.n_layers
         if kind == "prefill_chunk":
@@ -525,7 +529,9 @@ class AxK1Programs:
         return {"kv_positions_live": layers * int((lengths + 1).sum()),
                 "kv_positions_read": layers * sum(
                     self._tick_reads(lengths, None)),
-                "window_tokens": int(lengths.sum())}
+                "window_tokens": int(lengths.sum()),
+                **tick_steps(lengths + 1, self.block_size, layers,
+                             latent=True)}
 
     def prefill(self, params, kv, chunk, table, slot, start, prompt_len,
                 fresh, key, temperature, top_k):

@@ -45,7 +45,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from client_tpu.ops.paged_decode import (
-    STEP_BLOCKS, paged_decode_attention, reads_in_place, steps_read)
+    STEP_BLOCKS, paged_decode_attention, reads_in_place, steps_read,
+    tick_steps)
 from client_tpu.ops.sampling import select_token
 from client_tpu.serve.models import experts
 from client_tpu.serve.models.sambay import TRASH_BLOCK, _write_rows
@@ -483,7 +484,9 @@ class Cohere2MoePrograms:
         attention may see (a decode lane of length ``len``: ``len + 1`` on
         a full layer, ``min(len + 1, window)`` on a window layer; a chunk:
         the union over its rows), and ``kv_positions_read``, what the
-        program's trip counts read of them."""
+        program's trip counts read of them; on a decode tick ``kv_steps``,
+        the steps the decode kernel took, and ``kv_steps_full``, those on
+        its straight-line path (``paged_decode.tick_steps``)."""
         lengths = np.asarray(lengths, np.int64)
         w, full = self.cfg.window, self._full
         windowed = self.cfg.n_layers - full
@@ -494,12 +497,18 @@ class Cohere2MoePrograms:
             groups = (start + width - 1) // span + 1
             live = full * end + windowed * (end - behind)
             read = span * (full * groups + windowed * (groups - behind // span))
-        else:
-            seen = lengths + 1
-            live = full * seen.sum() + windowed * np.minimum(seen, w).sum()
-            read = (full * self._reads(seen).sum()
-                    + windowed * self._reads(seen, w).sum())
-        return {"kv_positions_live": int(live), "kv_positions_read": int(read)}
+            return {"kv_positions_live": int(live),
+                    "kv_positions_read": int(read)}
+        seen = lengths + 1
+        live = full * seen.sum() + windowed * np.minimum(seen, w).sum()
+        read = (full * self._reads(seen).sum()
+                + windowed * self._reads(seen, w).sum())
+        return {"kv_positions_live": int(live), "kv_positions_read": int(read),
+                **tick_steps(        # a layer after the other
+                    np.tile(seen, full + windowed), self.block_size,
+                    starts=np.concatenate(
+                        [0 * seen] * full
+                        + [np.maximum(seen - w, 0)] * windowed))}
 
     def prefill(self, params, kv, chunk, table, slot, start, prompt_len,
                 fresh, key, temperature, top_k):

@@ -42,12 +42,15 @@ import dataclasses
 import functools
 import math
 
+import numpy as np
+
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from client_tpu.ops.paged_decode import (
-    STEP_BLOCKS, paged_decode_attention, reads_in_place, steps_read)
+    STEP_BLOCKS, paged_decode_attention, reads_in_place, steps_read,
+    tick_steps)
 from client_tpu.ops.quant import matmul as _mm
 from client_tpu.ops.sampling import select_token
 from client_tpu.serve.prof import annotation
@@ -705,6 +708,18 @@ class SambaYPrograms:
             return [table_width * self.block_size] * len(lengths)
         return (steps_read(lengths + 1, self.block_size)
                 * (STEP_BLOCKS * self.block_size)).tolist()
+
+    def tick_fields(self, kind, lengths, **_):
+        """For a decode tick's ``tick_trace()`` entry where the tick reads
+        in place: ``kv_steps``, the steps the kernel took over the entry's
+        lanes and the layers that read the full cache, and
+        ``kv_steps_full``, those on its straight-line path
+        (``paged_decode.tick_steps``)."""
+        if kind != "decode" or not self._in_place:
+            return {}
+        kinds = self.cfg.kinds
+        return tick_steps(np.asarray(lengths) + 1, self.block_size,
+                          kinds.count("full") + kinds.count("cross"))
 
     def prefill(self, params, kv, chunk, table, slot, start, prompt_len,
                 fresh, key, temperature, top_k):

@@ -34,7 +34,8 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from client_tpu.ops.paged_decode import (
-    STEP_BLOCKS, paged_decode_attention, reads_in_place, steps_read)
+    STEP_BLOCKS, paged_decode_attention, reads_in_place, steps_read,
+    tick_steps)
 from client_tpu.ops.quant import matmul as _mm
 from client_tpu.ops.sampling import accept_lane, select_token
 from client_tpu.parallel.ring_attention import (
@@ -1027,6 +1028,16 @@ class DecoderPrograms:
             return None
         return (steps_read(lengths + 1, self.block_size)
                 * (STEP_BLOCKS * self.block_size)).tolist()
+
+    def tick_fields(self, kind, lengths, **_):
+        """For a decode tick's ``tick_trace()`` entry where the tick reads
+        in place: ``kv_steps``, the steps the kernel took over the entry's
+        lanes and every layer, and ``kv_steps_full``, those on its
+        straight-line path (``paged_decode.tick_steps``)."""
+        if kind != "decode" or not self._in_place:
+            return {}
+        return tick_steps(np.asarray(lengths) + 1, self.block_size,
+                          self.cfg.n_layers)
 
     def prefill(self, params, kv, chunk, table, slot, start, prompt_len,
                 fresh, key, temperature, top_k):

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 
 from benchmark import reference_axk1 as reference
 from benchmark import weights_axk1 as weights
-from client_tpu.ops import latent_prefill
+from client_tpu.ops import latent_prefill, paged_decode
 from client_tpu.ops.grouped_matmul import grouped_matmul
 from client_tpu.serve.lm import KvBlockPool, LmEngine
 from client_tpu.serve.metrics import Registry
@@ -101,9 +101,10 @@ def _paged_forward(tokens, prompt_len, chunk=8):
     """Logits at positions ``prompt_len - 1 ..`` of ``tokens`` through the
     latent cache: the prompt in chunks (expanded), then one decode step a
     token (absorbed), over a table of shuffled blocks."""
-    kv = KvBlockPool(CFG, 48, BLOCK, lanes=1)
+    held = max(40, -(-len(tokens) // BLOCK) + 2)     # table columns
+    kv = KvBlockPool(CFG, held + 8, BLOCK, lanes=1)
     table = jnp.asarray(
-        np.random.default_rng(1).permutation(48)[:40] + 1, jnp.int32)
+        np.random.default_rng(1).permutation(held + 8)[:held] + 1, jnp.int32)
     logits, pool = _prefill(tokens, prompt_len, chunk, table,
                             kv.pools["latent"])
     out = [np.asarray(logits)]
@@ -116,13 +117,15 @@ def _paged_forward(tokens, prompt_len, chunk=8):
     return np.stack(out)
 
 
-@pytest.mark.parametrize("prompt_len", [3, 8, 13, 64, 125])
+@pytest.mark.parametrize("prompt_len", [3, 8, 13, 64, 125, 253])
 def test_chunks_then_decode_agree_with_the_reference(prompt_len):
     """Prefill in chunks of 8 and then decoding through the latent cache
     against the reference's full forward, every position compared: prompts
     inside a block, at a block's and a chunk's edge, and at and past the
-    kernel's step of 64 positions (16 blocks of 4) and the chunk's group of
-    128, each decoded on across the next edge."""
+    decode kernel's step and the chunk's group, both 128 positions (32
+    blocks of 4), each decoded on across the next edge; the longest one
+    decodes across two whole steps, where the decode kernel's first step
+    takes its straight-line path (``paged_decode.full_steps``)."""
     tokens = np.random.default_rng(prompt_len).integers(
         0, CFG.vocab_size, prompt_len + 5).astype(np.int32)
     want = _reference_logits(tokens)[prompt_len - 1:]
@@ -380,17 +383,30 @@ def test_engine_streams_follow_the_reference_and_count():
 
 def test_tick_fields_count_what_the_programs_read():
     """``kv_positions_live``, ``kv_positions_read`` and a chunk's
-    ``kv_rows_rebuilt`` by hand: block 4, a kernel's step of 16 blocks is 64
-    positions, a chunk's group of 32 blocks 128; four layers, every one over
-    the whole context.  What a chunk reads is the chunk kernel's own trip
-    count (``latent_prefill.groups_read``) times its group."""
+    ``kv_rows_rebuilt`` by hand: block 4, the decode kernel's step over
+    latent rows is 32 blocks, 128 positions, and so is a chunk's group of 32
+    blocks; four layers, every one over the whole context.  What a tick
+    reads is the decode kernel's own trip count (``paged_decode.steps_read``
+    for a latent pool), what a chunk reads the chunk kernel's
+    (``latent_prefill.groups_read``) times its group."""
     programs = CFG.family(CFG, BLOCK)
-    # lanes of lengths 72 and 10 attend 73 and 11 rows: two steps and one
-    got = programs.tick_fields("decode", [72, 10])
-    assert got == {"kv_positions_live": 4 * (73 + 11),
-                   "kv_positions_read": 4 * (128 + 64),
-                   "window_tokens": 82}     # no window: the contexts' sum
-    assert programs._tick_reads(np.array([63, 64]), 40) == [64, 128]
+    # lanes of lengths 135 and 10 attend 136 and 11 rows: two steps and one
+    got = programs.tick_fields("decode", [135, 10])
+    assert got == {"kv_positions_live": 4 * (136 + 11),
+                   "kv_positions_read": 4 * (256 + 128),
+                   "window_tokens": 145,    # no window: the contexts' sum
+                   "kv_steps": 4 * 3, "kv_steps_full": 0}
+    # 400 rows: four steps a layer, of the three whole ones the first two
+    # on the kernel's straight-line path (the step ahead is whole too);
+    # 256 rows: two whole steps, the first on it
+    got = programs.tick_fields("decode", [399, 255])
+    assert (got["kv_steps"], got["kv_steps_full"]) == (4 * (4 + 2), 4 * 3)
+    assert got["kv_steps"] * 128 == got["kv_positions_read"]
+    assert programs._tick_reads(np.array([127, 128]), 40) == [128, 256]
+    for lengths in ([0], [127, 128, 129], [511, 640, 1000]):
+        reads = programs._tick_reads(np.array(lengths), 40)
+        assert reads == (paged_decode.steps_read(
+            np.array(lengths) + 1, BLOCK, latent=True) * 128).tolist()
     # a chunk of 8 rows from 124, 5 of them real: 129 rows may be seen, and
     # the chunk's last row (131) lies in the second group
     got = programs.tick_fields("prefill_chunk", [129], start=124, width=8)

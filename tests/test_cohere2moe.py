@@ -304,8 +304,16 @@ def test_tick_fields_count_what_the_kernel_reads():
     # length 70 sees from 63, the last position of the first step: both
     got = programs.tick_fields("decode", [72])
     assert got == {"kv_positions_live": 73 + 3 * 8,
-                   "kv_positions_read": 128 + 3 * 64}
+                   "kv_positions_read": 128 + 3 * 64,
+                   "kv_steps": 2 + 3 * 1, "kv_steps_full": 0}
     assert programs.tick_fields("decode", [70])["kv_positions_read"] == 512
+    # of a lane that attends 200 positions (three whole steps and a part)
+    # the full layer walks four steps, two of them on the kernel's
+    # straight-line path (the step ahead has to be whole too); a window
+    # layer walks from position 192, the partial step alone
+    got = programs.tick_fields("decode", [199, 72])
+    assert (got["kv_steps"], got["kv_steps_full"]) == (4 + 3 + 5, 2)
+    assert got["kv_steps"] * 64 == got["kv_positions_read"]
     # a chunk of 8 rows from 72, 5 of them real: the full layer may see 77
     # positions and reads groups 0 and 1; a window layer sees from 65, in
     # group 1

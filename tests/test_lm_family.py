@@ -272,12 +272,18 @@ def test_decode_tick_at_head_size_128_agrees_with_the_contiguous_path(
     if reader == "loop":
         assert not handed
         assert programs._tick_reads(lens[live], tables.shape[1]) is None
+        assert programs.tick_fields("decode", lens[live]) == {}
         return
     assert [h.tolist() for h in handed] == cfg.n_layers * [
         [n + 1 for n in TICK_LENS] + [0]]
     assert programs._tick_reads(lens[live], tables.shape[1]) == (
         paged_decode.steps_read(handed[0][live], WIDE) * STEP).tolist() == [
             STEP] * 5 + [2 * STEP] * 2
+    # the same steps on the entry, over every layer: none of these lanes
+    # holds a whole step with a whole one after it
+    assert programs.tick_fields("decode", lens[live]) == {
+        "kv_steps": cfg.n_layers * 9, "kv_steps_full": 0}
+    assert programs.tick_fields("prefill_chunk", lens[live]) == {}
 
 
 def test_decode_tick_in_place_gives_the_loops_hidden_state():
@@ -457,8 +463,7 @@ def test_the_families_answer_the_same_questions():
         name for name, *_ in moe.counters]
     assert not latent.recurrent and latent.no_verify
     assert not public(hybrid) - public(decoder)
-    assert public(moe) - public(hybrid) == {
-        "no_verify", "counters", "tick_fields"}
+    assert public(moe) - public(hybrid) == {"no_verify", "counters"}
     assert not public(hybrid) - public(moe)
     for programs in (decoder, hybrid):
         assert hasattr(programs, "make_verify") == (not programs.recurrent)

@@ -4,6 +4,8 @@ starts as NaN) against a plain gather and softmax written here, and ``sambay.dif
 kernel's result into a layer, against the whole function as PR 31 had it.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -158,19 +160,24 @@ LATENT = {
 }
 
 
-def _plain_latent(q, pool, tables, lengths, value_width):
+def _plain_latent(q, pool, tables, lengths, value_width, starts=None):
     out = np.zeros(q.shape[:-1] + (value_width,), np.float32)
     for lane, length in enumerate(lengths):
         if not length:
             continue
-        rows = np.concatenate([np.asarray(pool[b], np.float32)
-                               for b in tables[lane]], axis=1)[:, :length]
+        first = 0 if starts is None else starts[lane]
+        rows = np.concatenate([np.asarray(pool[b], np.float32) for b in
+                               tables[lane]], axis=1)[:, first:length]
         s = np.einsum("grd,gtd->grt", np.asarray(q[lane], np.float32), rows)
         p = np.exp(s - s.max(axis=-1, keepdims=True))
         out[lane] = np.einsum("grt,gtd->grd",
                               p / p.sum(axis=-1, keepdims=True),
                               rows[:, :, :value_width])
     return out
+
+
+LATENT_STEP = paged_decode.LATENT_STEP_BLOCKS
+LATENT_WIDTH = 2 * LATENT_STEP + 3  # two whole steps and a part of one
 
 
 @pytest.mark.parametrize("shape", list(LATENT))
@@ -180,20 +187,23 @@ def test_latent_kernel_reads_one_pool_as_keys_and_as_values(shape, length):
     """``pool_v`` None: every query row of a lane against ONE row a
     position, all of it as key and its first ``value_width`` columns as
     value, each lane to its own length: a lane of the whole table, the lane
-    under test (nothing, one row, across a block's and a step's edge), a
-    lane that is not in the tick and a short one, blocks permuted through
-    the pool, under the interpreter that models the copies in flight."""
+    under test (nothing, one row, across a block's and a step's edge, the
+    latent step of ``LATENT_STEP`` blocks), a lane that is not in the tick
+    and a short one, blocks permuted through the pool, under the
+    interpreter that models the copies in flight."""
     dtype, block, wide, value_width, rows, tol = LATENT[shape]
     rng = np.random.default_rng(block)
-    n_blocks = 4 * WIDTH
+    n_blocks = 4 * LATENT_WIDTH
     pool = jnp.asarray(rng.normal(size=(n_blocks + 1, 1, block, wide)), dtype)
     q = jnp.asarray(rng.normal(size=(4, 1, rows, wide)) * wide ** -0.5, dtype)
-    tables = (rng.permutation(n_blocks) + 1).reshape(4, WIDTH).astype(
-        np.int32)
-    held = LENGTHS[length](block)
+    tables = (rng.permutation(n_blocks) + 1).reshape(
+        4, LATENT_WIDTH).astype(np.int32)
+    held = {"step": LATENT_STEP * block, "step+1": LATENT_STEP * block + 1,
+            "table": LATENT_WIDTH * block}.get(length) \
+        or LENGTHS[length](block)
     tables[1, -(-held // block):] = KvBlockPool.TRASH  # never read
     tables[3, 2:] = KvBlockPool.TRASH
-    lengths = np.array([WIDTH * block, held, 0, block + 3], np.int32)
+    lengths = np.array([LATENT_WIDTH * block, held, 0, block + 3], np.int32)
     before = np.asarray(pool)
     out = np.asarray(paged_decode.paged_decode_attention(
         q, pool, None, jnp.asarray(tables), jnp.asarray(lengths),
@@ -207,14 +217,158 @@ def test_latent_kernel_reads_one_pool_as_keys_and_as_values(shape, length):
     np.testing.assert_array_equal(np.asarray(pool), before)
 
 
+# -- the full-step path --------------------------------------------------------
+
+# lengths (and first positions) of up to four lanes, from the kernel's step
+# of ``span`` positions: what a full step's straight-line body and its one
+# wait have to get right beside the loops they stand for
+FULL = {
+    # whole steps and nothing else: a lane's last step hands over from the
+    # loops' path to the next lane's full steps
+    "k-span": lambda span: ([3 * span, 2 * span, span, 4 * span], None),
+    "k-span-1": lambda span: ([3 * span - 1, 2 * span - 1, span - 1], None),
+    "k-span+1": lambda span: ([3 * span + 1, 2 * span + 1, span + 1], None),
+    "span-1-and-1": lambda span: ([span - 1, 1, 3 * span, 1], None),
+    # the copy ahead of a lane's last step crosses the empty lane
+    "empty-between-full": lambda span: ([3 * span, 0, 3 * span + 2], None),
+    "partial-then-full": lambda span: ([span // 2, 4 * span, 5], None),
+    "full-then-partial": lambda span: ([4 * span, span // 2, 3 * span], None),
+    "start-inside-a-full-step": lambda span: (
+        [4 * span + 3, 3 * span, 4 * span], [span + 5, 7, 2 * span + 1]),
+    "start-on-a-steps-edge": lambda span: (
+        [4 * span + 3, 3 * span, 4 * span], [2 * span, span, 3 * span - 1]),
+}
+FULL_STEPS = 5  # table columns, in steps
+
+
+@pytest.fixture(scope="module")
+def walks():
+    """``walk(form, windowed)``: the kernel, and the kernel with no step
+    counted full, so that every step takes the loops of a run-time trip
+    count and their wait a block (the step as the parent commit had it,
+    whole), both under the interpreter that models the copies in flight,
+    their semaphores and a scratch that starts as NaN; with their inputs
+    ``(q, pools, tables)``.  Each compiles once: a case varies lengths."""
+    made = {}
+
+    def attend(*args, **keywords):
+        return paged_decode.paged_decode_attention(
+            *args, interpret=pltpu.InterpretParams(), **keywords)
+
+    def walk(form, windowed):
+        if (form, windowed) not in made:
+            latent = form == "latent"
+            block, wide = 4, 256 if latent else 32
+            rng = np.random.default_rng(len(made))
+            width = FULL_STEPS * paged_decode.step_blocks(latent)
+            n_blocks = 4 * width
+            pools = [jnp.asarray(rng.normal(size=(
+                n_blocks + 1, 1 if latent else 2, block, wide)), "float32")
+                for _ in ("k" if latent else "kv")]
+            q = jnp.asarray(rng.normal(size=(
+                4, pools[0].shape[1], 4, wide)) * wide ** -0.5, "float32")
+            tables = jnp.asarray((rng.permutation(n_blocks) + 1).reshape(
+                4, width).astype(np.int32))
+            keywords = {"value_width": 128} if latent else {}
+            # two wrappers, so that each is traced for itself
+            full, loops = (jax.jit(functools.partial(attend, **keywords))
+                           for _ in range(2))
+            made[form, windowed] = (
+                full, loops, (q, pools[0], None if latent else pools[1],
+                              tables))
+        return made[form, windowed]
+
+    return walk
+
+
+@pytest.mark.parametrize("form", ["latent", "two-pool"])
+@pytest.mark.parametrize("case", list(FULL))
+def test_a_full_steps_straight_line_and_one_wait_give_what_the_loops_give(
+        walks, monkeypatch, case, form):
+    """BIT-EQUAL: the order of the products and of the running softmax is
+    the loops', and the one wait against a whole half of the buffer is held
+    by the modelled semaphore (a byte short and the contraction would meet
+    the NaN the scratch starts as, or the step before's rows)."""
+    latent = form == "latent"
+    span = paged_decode.step_blocks(latent) * 4
+    lengths, starts = FULL[case](span)
+    lengths = np.array(lengths + [0] * (4 - len(lengths)), np.int32)
+    if starts is not None:
+        starts = np.array(starts + [0] * (4 - len(starts)), np.int32)
+    full, loops, (q, pool_k, pool_v, tables) = walks(form, starts is not None)
+    args = (q, pool_k, pool_v, tables, jnp.asarray(lengths)) + (
+        () if starts is None else (jnp.asarray(starts),))
+    assert paged_decode.full_steps(lengths, 4, starts, latent).any()
+    got = np.asarray(full(*args))
+    with monkeypatch.context() as patch:
+        patch.setattr(paged_decode, "full_steps",
+                      lambda lengths, *_: lengths * 0)
+        want = np.asarray(loops(*args))
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, want)
+    plain = _plain_latent(
+        q, pool_k, np.asarray(tables), lengths, 128, starts) if latent \
+        else _plain(q, pool_k, pool_v, np.asarray(tables), lengths, starts)
+    np.testing.assert_allclose(got, plain, atol=2e-5, rtol=2e-5)
+
+
+def test_full_steps_is_the_kernels_branch_on_host_and_device(monkeypatch):
+    """Every step but the last of those that lie whole under the length,
+    from the step that holds a lane's first position; and the kernel asks
+    this function, once a lane, with the lane's own length and start."""
+    span = STEP * 16
+    assert [int(paged_decode.full_steps(n, 16)) for n in (
+        0, 1, span, 2 * span - 1, 2 * span, 3 * span + 1)] == [
+            0, 0, 0, 0, 1, 2]
+    np.testing.assert_array_equal(
+        paged_decode.full_steps(jnp.array([0, 2 * span, 5 * span + 7]), 16),
+        [0, 1, 4])
+    np.testing.assert_array_equal(
+        paged_decode.full_steps(
+            np.array([5 * span + 7, 5 * span + 7, 5 * span, 3]), 16,
+            np.array([span - 1, span, 4 * span + 9, 0])), [4, 3, 0, 0])
+    # no more than the steps the kernel takes, whatever the lane holds
+    lengths = np.arange(0, 6 * span, 37)
+    for starts in (None, lengths // 3):
+        first = 0 if starts is None else starts // span
+        taken = paged_decode.steps_read(lengths, 16) - first
+        full = paged_decode.full_steps(lengths, 16, starts)
+        assert (full >= 0).all() and (full <= np.maximum(taken - 1, 0)).all()
+    asked = []
+    real = paged_decode.full_steps
+    monkeypatch.setattr(
+        paged_decode, "full_steps",
+        lambda *args, **kw: asked.append(args) or real(*args, **kw))
+    rng = np.random.default_rng(0)
+    pool = jnp.asarray(rng.normal(size=(9, 1, 4, 32)), "float32")
+    q = jnp.asarray(rng.normal(size=(2, 1, 2, 32)), "float32")
+    tables = jnp.asarray(np.arange(8, dtype=np.int32).reshape(2, 4) + 1)
+    for starts in ((), (jnp.array([2, 0]),)):
+        asked.clear()
+        paged_decode.paged_decode_attention(
+            q, pool, pool, tables, jnp.array([16, 3]), *starts)
+        (length, block, start, latent), = asked
+        assert block == 4 and (start is None) == (not starts) and not latent
+        assert isinstance(length, jax.core.Tracer)
+    # a latent pool's step is twice as long, for the host as for the kernel
+    assert paged_decode.step_blocks(latent=True) == 2 * STEP
+    assert int(paged_decode.full_steps(4 * span, 16, latent=True)) == 1
+    assert int(paged_decode.steps_read(4 * span + 1, 16, latent=True)) == 3
+    asked.clear()
+    paged_decode.paged_decode_attention(
+        q, pool, None, tables, jnp.array([16, 3]), value_width=32)
+    assert asked[0][3] is True
+
+
 def test_without_first_positions_the_reason_cells_tick_lowers_as_before():
     """``starts`` is optional and ``sambay.py`` passes none: its decode
     tick has to lower to the module it lowered to before the argument
     existed.  The digest is of the tick's lowered text at
-    ``tests/test_lm_family.py``'s tiny SambaY configuration, taken on the
-    parent commit (PR 32's tree; jax 0.9.0, the one installation): the
-    interpreted kernel is traced into that text, so a change to its body
-    shows, as does one to the rest of the tick."""
+    ``tests/test_lm_family.py``'s tiny SambaY configuration (jax 0.9.0, the
+    one installation): the interpreted kernel is traced into that text, so
+    a change to its body shows, as does one to the rest of the tick.  Taken
+    anew in PR 38, whose full-step path changed the kernel's body for every
+    caller (420,231 characters before it)."""
     import hashlib
 
     cfg = sambay.SambaYConfig(
@@ -233,9 +387,9 @@ def test_without_first_positions_the_reason_cells_tick_lowers_as_before():
         jnp.zeros((n,), jnp.int32), jnp.ones((n,), bool),
         jnp.zeros((n,), jnp.float32), jnp.zeros((n,), jnp.int32),
         jnp.zeros((n, 2), jnp.uint32), **tick.keywords).as_text()
-    assert len(text) == 420231
+    assert len(text) == 645403
     assert hashlib.sha256(text.encode()).hexdigest() == (
-        "a0befcd2ced72789bb11bfb09874dd9d0d293c84a56443500a3c10524fc39f61")
+        "6c6df9f18776fa170393a19eb99ab242c190f2610d8250bbcb15a2f644253dbf")
 
 
 def test_steps_read_is_the_kernels_trip_count_on_host_and_device():

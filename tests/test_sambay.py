@@ -470,6 +470,14 @@ def test_ticks_count_context_and_window_tokens_for_both_families(params):
         -(-(n + 1) // span) * span for n in (21, 22, 23, 24)]
     assert all(t["attended_positions"] == t["attended_tokens"]
                for t in decodes)
+    # the steps behind those reads, over the layers that read the full
+    # cache: none on the kernel's straight-line path at these lengths
+    calls = CFG.kinds.count("full") + CFG.kinds.count("cross")
+    assert all(t["kv_steps"] * span == calls * t["attended_tokens"]
+               and t["kv_steps_full"] == 0 for t in decodes)
+    assert not any("kv_steps" in t for t in chunks)
+    assert eng._programs.tick_fields("decode", [4 * span + 1, 2 * span]) == {
+        "kv_steps": calls * (5 + 3), "kv_steps_full": calls * (3 + 1)}
 
     dense = tfm.TransformerConfig(vocab_size=64, d_model=32, n_layers=2,
                                   n_heads=2, n_kv_heads=1, d_ff=64,
@@ -492,6 +500,9 @@ def test_ticks_count_context_and_window_tokens_for_both_families(params):
     assert all(t["attended_tokens"]
                == t["attended_positions"] * len(t["lanes"])
                for t in [chunk] + decodes)
+    # and its ticks read in place on the CPU: a step a lane a layer
+    assert all((t["kv_steps"], t["kv_steps_full"])
+               == (dense.n_layers * len(t["lanes"]), 0) for t in decodes)
     assert eng.prefix_stats().get("enabled") is not False
 
 
