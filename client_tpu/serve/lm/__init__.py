@@ -1,8 +1,10 @@
 """Continuous-batching LM serving subsystem.
 
 The engine knows no model: a family's programs live in the family's own
-module (``serve/models/transformer.py``, ``sambay.py``) and its
-configuration hands them out (``cfg.family``).  Four pillars:
+module under ``serve/models`` (``transformer.py``, ``sambay.py``,
+``cohere2moe.py``, ``axk1.py``, ``sdar.py``) and its configuration hands
+them out (``cfg.family``), with the pools a lane owns (``cfg.state_spec``).
+What the package holds:
 
 - **prompt-length bucketing** (:mod:`.policy`) — prompts pad to a small
   geometric set of prefill widths so the compiled prefill-executable
@@ -29,7 +31,18 @@ configuration hands them out (``cfg.family``).  Four pillars:
   exhaustion with a strictly higher-priority tenant waiting, the
   lowest-priority lane swaps its KV to a bounded host-side store (or
   drops it for recompute), its stream pausing — not erroring — until
-  blocks free up, byte-exact with an unpreempted run on the swap path.
+  blocks free up, byte-exact with an unpreempted run on the swap path;
+- **speculative decoding** (:mod:`.spec`) — a drafter and an adaptive
+  draft length a lane, verified by the family's verify program where it
+  has one (a family without says why, and is refused);
+- **the block tick** (:class:`.engine.LmEngine`) — a family may say that
+  a lane's tick holds a BLOCK of positions (``block``; generation by
+  diffusion over blocks, ``serve/models/sdar.py``): most of a lane's
+  ticks then deliver nothing and do not advance it, one delivers the
+  block's tokens at once, one advances the lane by the block; the family's
+  static schedule (``advance``) tells the host which, so ticks are
+  dispatched ahead as for a token a tick, and ``pass_trace()`` keeps the
+  order in which a stream's positions were fixed.
 
 Per-lane sampling (temperature / top-k via per-lane RNG keys inside the
 jitted tick) removes the old "greedy only" limitation.

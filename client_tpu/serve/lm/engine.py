@@ -108,7 +108,8 @@ def _adopt(tokens, keys, slot, tok, key):
 class _Lane:
     __slots__ = ("gen", "active", "queue", "remaining", "produced",
                  "length", "limit", "tenant", "temperature", "top_k",
-                 "table", "blocks", "prompt", "tokens", "handle", "spec")
+                 "table", "blocks", "prompt", "tokens", "handle", "spec",
+                 "masks", "fixed")
 
     def __init__(self, table_width):
         self.gen = 0        # bumped on every (re)assignment and cancel
@@ -127,6 +128,11 @@ class _Lane:
         self.tokens = []    # delivered generation tokens (recompute replay)
         self.handle = None  # the submit() handle streaming on this lane
         self.spec = None    # LaneSpec when speculative decoding is on
+        # a family whose tick holds a block of positions a lane: the masked
+        # positions its block at ``length`` still holds, and for each
+        # delivered token the state its block was in when it was fixed
+        self.masks = 0
+        self.fixed = []
 
 
 class _Handle:
@@ -196,7 +202,8 @@ class _Swapped:
     __slots__ = ("handle", "queue", "tenant", "prompt", "prompt_len",
                  "produced", "remaining", "length", "limit", "temperature",
                  "top_k", "tokens", "token", "key", "host",
-                 "n_blocks", "written_blocks", "cancelled", "t_swap")
+                 "n_blocks", "written_blocks", "cancelled", "t_swap",
+                 "masks", "fixed")
 
     def __init__(self, lane, n_blocks, written_blocks, token, key, host):
         self.handle = lane.handle
@@ -211,7 +218,9 @@ class _Swapped:
         self.temperature = lane.temperature
         self.top_k = lane.top_k
         self.tokens = list(lane.tokens)
-        self.token = token          # input token for the next decode tick
+        self.masks = lane.masks
+        self.fixed = list(lane.fixed)
+        self.token = token          # the lane array's row: the next input
         self.key = key              # RNG carry at the preemption point
         self.host = host
         self.n_blocks = int(n_blocks)
@@ -237,7 +246,7 @@ class LmEngine:
                  min_bucket=16, readback_depth=8, eos_id=None,
                  check_prompt=None, registry=None,
                  tenant_lane_share=0.75, scale_up_after=3,
-                 scale_down_after=50, tick_log_len=8192,
+                 scale_down_after=50, tick_log_len=8192, pass_log_len=256,
                  prefix_cache=True, min_prefix_blocks=1,
                  tenant_priority=None, swap_block_limit=None, fleet=None,
                  speculative=None):
@@ -283,6 +292,7 @@ class LmEngine:
             down_after=scale_down_after,
         )
         self._tick_log = deque(maxlen=int(tick_log_len))
+        self._pass_log = deque(maxlen=int(pass_log_len))  # pass_trace()
         # dispatched, not yet read back: (tokens, lanes' (slot, gen), the
         # tick_trace() entry if these are a prompt's first token)
         self._inflight = deque()
@@ -314,6 +324,17 @@ class LmEngine:
         # family says why in ``no_verify``), so that no family can reach
         # ``_verify_for`` without a program.
         self._programs = cfg.family(cfg, self.block_size)
+        # positions a lane's tick holds: one, and the tick yields that
+        # position's token; or a family's block of them (``block``), whose
+        # passes the family's static schedule tells apart (``advance``):
+        # most deliver nothing and do not advance the lane, one delivers
+        # the block's tokens at once, one advances the lane by the block.
+        # A chunk's edge is then a block's edge, and so is a pool block's.
+        self._block = getattr(self._programs, "block", 1)
+        if any(width % self._block for width in self.buckets):
+            raise ValueError(
+                f"prefill widths {self.buckets} are not whole blocks of "
+                f"{self._block} positions")
         self._flops_per_token = self._programs.flops_per_token
         self._recurrent = self._programs.recurrent
         self._no_verify = "" if hasattr(self._programs, "make_verify") else (
@@ -470,9 +491,26 @@ class LmEngine:
         its ``tick_fields`` counts on the host when the entry is written,
         and, once the device work has completed, what its programs counted
         on the device (its ``counters``: the vector comes to the host with
-        the tokens)."""
+        the tokens).  The ``decode`` entry of a family whose tick holds a
+        block of positions a lane (``context_tokens``: the blocks' first
+        positions) also says what its passes were: ``block_rows`` (positions
+        in the tick), ``denoise_lanes`` and ``commit_lanes`` (lanes by the
+        pass they ran), ``masked_rows`` (positions still masked, whose
+        logits the pass used) and ``tokens_out`` (tokens its readback
+        delivers)."""
         with self._cv:
             return [dict(entry) for entry in self._tick_log]
+
+    def pass_trace(self):
+        """For a family whose tick holds a block of positions a lane: the
+        last ``pass_log_len`` streams that ended, oldest first, each
+        ``{"prompt": ids (an array), "tokens": delivered ids, "fixed_at":
+        for each delivered token, how many of its block's positions were
+        unmasked when the pass ran that fixed it}``: tokens with one
+        ``fixed_at`` in one block were fixed by one pass, and a block's state
+        at any pass follows.  Empty for every other family."""
+        with self._cv:
+            return [dict(entry) for entry in self._pass_log]
 
     def prefix_stats(self):
         """Prefix-cache counters ({} when the cache is disabled or the
@@ -674,7 +712,10 @@ class LmEngine:
             )
         if self.swap_block_limit is None:
             self.swap_block_limit = self.kv.n_blocks
-        self._tokens = jnp.zeros((self.max_slots,), jnp.int32)
+        # the lane array: a lane's next input token, or its block's state
+        self._tokens = (
+            jnp.zeros((self.max_slots,), jnp.int32) if self._block == 1
+            else self._programs.lane_state(self.max_slots))
         self._keys = jnp.zeros((self.max_slots, 2), jnp.uint32)
         self._thread = threading.Thread(
             target=self._loop, name="lm-engine", daemon=True
@@ -690,10 +731,16 @@ class LmEngine:
         lane.gen += 1  # in-flight ticks for this lane drop on drain
         if close_queue:
             lane.queue.put(_CLOSE)
+            if self._block > 1 and lane.tokens:
+                self._pass_log.append({
+                    "prompt": lane.prompt[0],
+                    "tokens": list(lane.tokens),
+                    "fixed_at": lane.fixed[:len(lane.tokens)],
+                })
         lane.table[:] = KvBlockPool.TRASH
         written, lane.length = lane.length, 0
         prompt, lane.prompt = lane.prompt, None
-        lane.tokens = []
+        lane.tokens, lane.fixed = [], []
         lane.handle = None
         lane.spec = None
         blocks, lane.blocks = lane.blocks, None
@@ -872,6 +919,7 @@ class LmEngine:
         lane.top_k = entry.top_k
         lane.prompt = entry.prompt
         lane.tokens = list(entry.tokens)
+        lane.masks, lane.fixed = entry.masks, list(entry.fixed)
         lane.handle = entry.handle
         # drafter state rebuilds from the prompt; the adaptive-k window
         # restarts (a resume is rare — one extra window to re-disable an
@@ -1181,7 +1229,13 @@ class LmEngine:
                 lane.temperature = handle.temperature
                 lane.top_k = handle.top_k
                 lane.prompt = handle.prompt
-                lane.tokens = []
+                lane.tokens, lane.fixed = [], []
+                if self._block > 1:
+                    # the prefill stored the prompt's whole blocks; its
+                    # tail is the known head of the first generated block
+                    lane.length = self._programs.stored(handle.prompt_len)
+                    lane.masks = self._programs.masks(
+                        lane.length, handle.prompt_len)
                 lane.handle = handle
                 lane.spec = (
                     LaneSpec(self._spec, handle.prompt[0])
@@ -1213,7 +1267,7 @@ class LmEngine:
             # streams (everything up to `produced` was already delivered)
             self._tokens, self._keys = self._adopt(
                 self._tokens, self._keys, jnp.int32(job.slot),
-                jnp.int32(resume.token), jnp.asarray(resume.key),
+                jnp.asarray(resume.token), jnp.asarray(resume.key),
             )
             return
         # install the first token + RNG carry into the lane arrays and
@@ -1222,11 +1276,13 @@ class LmEngine:
         self._tokens, self._keys = self._adopt(
             self._tokens, self._keys, jnp.int32(job.slot), tok, job.key
         )
-        tok.copy_to_host_async()
         with self._cv:
             entry["t_submit"] = handle.t_submit
             entry["t_admit"] = handle.t_admit
-        self._inflight.append((tok, snapshot, entry))
+        if self._block > 1:
+            return  # what the chunk yields is the lane's first block: no token
+        tok.copy_to_host_async()
+        self._inflight.append((tok, snapshot, entry, None))
 
     def _export_prefix(self, export):
         """Publish freshly prefilled full prompt blocks into the fleet
@@ -1262,17 +1318,7 @@ class LmEngine:
                     nfull = entry.length // self.block_size
                     if not nfull:
                         continue
-                    row = entry.prompt[0]
-                    if entry.produced > 1:
-                        # the written sequence is prompt + every delivered
-                        # token except the last (which is the NEXT tick's
-                        # input): exactly `length` tokens
-                        row = np.concatenate([
-                            row,
-                            np.asarray(
-                                entry.tokens[:entry.produced - 1], np.int32
-                            ),
-                        ])
+                    row = self._written_row(entry)
                     exports.append((row, nfull, entry.host))
         for row, nfull, host in exports:
             fleet.export_prefix(
@@ -1288,6 +1334,18 @@ class LmEngine:
             )
         self.close()
         return len(exports)
+
+    @staticmethod
+    def _written_row(entry):
+        """The ids whose K/V a parked stream had written: the prompt and
+        the delivered tokens up to its ``length`` (every delivered token
+        but the last, which is the NEXT tick's input; of a family whose
+        tick holds a block, the blocks that were committed: none yet of a
+        prompt shorter than a block, whose replay then holds one id and
+        stores nothing)."""
+        return np.concatenate([
+            entry.prompt[0], np.asarray(entry.tokens, np.int32),
+        ])[:max(entry.length, 1)]
 
     def _tick_for(self, n):
         # memoized under _cv: decode_executables() iterates this dict
@@ -1311,7 +1369,7 @@ class LmEngine:
             built = self._build_tick()
         if built is None:
             return False
-        n, active, tables, lens, live, temps, topks = built
+        n, active, tables, lens, live, temps, topks, takes, passes = built
         t0 = time.monotonic()
         with ptick.phase("upload", held=True) as upload:
             args = (
@@ -1325,18 +1383,22 @@ class LmEngine:
             )
         with ptick.phase("record"):
             self._tokens.copy_to_host_async()
-            self._inflight.append((self._tokens, tuple(active), None))
+            self._inflight.append((self._tokens, tuple(active), None, takes))
             self._log_tick(
                 "decode", t0, tuple(i for i, _ in active), self._tokens,
-                lens[live], int(lens.max()),
+                lens[live], int(lens.max()) + self._block - 1,
                 self._tick_reads(lens[live], self._table_width),
-                counted=counted, spans=(upload, call),
+                counted=counted, spans=(upload, call), **passes,
             )
         return True
 
     def _build_tick(self):
         """A decode tick's arrays, on the host: ``(n, active, tables,
-        lens, live, temps, topks)``, or None with no lane to tick."""
+        lens, live, temps, topks, takes, passes)``, or None with no lane to
+        tick.  ``takes`` and ``passes`` are a block family's (None and
+        empty otherwise): for each lane of ``active`` the first position of
+        its block whose token the tick's readback delivers (the block's
+        length: none), and what the tick's entry says of its passes."""
         with self._cv:
             if self._closed:
                 return None
@@ -1349,7 +1411,7 @@ class LmEngine:
                 (i, self._lanes[i].gen)
                 for i in range(n)
                 if self._lanes[i].active
-                and self._lanes[i].length < self._lanes[i].limit
+                and self._unspent_locked(self._lanes[i])
             ]
             if not active:
                 return None
@@ -1372,13 +1434,56 @@ class LmEngine:
             topks = np.array(
                 [self._lanes[i].top_k for i in range(n)], np.int32
             )
-            for i, _ in active:
-                self._lanes[i].length += 1  # this tick writes position len
+            takes, passes = None, {}
+            if self._block == 1:
+                for i, _ in active:
+                    self._lanes[i].length += 1  # the tick writes position len
+            else:
+                takes, passes = self._advance_blocks_locked(active)
             self._lane_gauges_locked(active_count=len(active))
         # ``live``: a lane outside the batch keeps its fixed state as it is
         # (one mid-prefill carries it from chunk to chunk)
         live = np.array([i in included for i in range(n)])
-        return n, active, tables, lens, live, temps, topks
+        return n, active, tables, lens, live, temps, topks, takes, passes
+
+    def _unspent_locked(self, lane):
+        """Whether a lane has a tick left to dispatch inside its budget:
+        dispatch-ahead must never write past the lane's block reservation.
+        A lane of one position a tick: until it has written its last.  A
+        lane of a block a tick: while its block holds a mask, and for the
+        commit only if a block follows inside the budget."""
+        if self._block == 1:
+            return lane.length < lane.limit
+        return lane.masks > 0 or lane.length + self._block < lane.limit
+
+    def _advance_blocks_locked(self, active):
+        """A block family's lanes through the pass this tick runs for each
+        (the family's static schedule, ``advance``): ``(takes, passes)`` as
+        ``_build_tick`` returns them."""
+        takes = []
+        passes = {"block_rows": self._block * len(active), "denoise_lanes": 0,
+                  "commit_lanes": 0, "masked_rows": 0, "tokens_out": 0}
+        for i, _ in active:
+            lane = self._lanes[i]
+            start, masked = lane.length, lane.masks
+            kind, lane.length, lane.masks, delivers = self._programs.advance(
+                start, masked)
+            passes[kind + "_lanes"] += 1
+            passes["masked_rows"] += masked
+            # the block's positions under the prompt's end were known
+            first = self._block - self._programs.masks(
+                start, lane.prompt.shape[1])
+            takes.append(first if delivers else self._block)
+            if delivers:
+                passes["tokens_out"] += (
+                    min(start + self._block, lane.limit) - start - first)
+            if self.registry is not None:
+                self.registry.inc(
+                    "ctpu_lm_block_passes_total", {"kind": kind},
+                    help_="Passes over a lane's diffusion block, by kind "
+                          "(denoise: fixes masked positions; commit: stores "
+                          "the finished block's keys and values)")
+        return takes, passes
 
     def _verify_for(self, n, w):
         # memoized under _cv exactly like _tick_for: jit here only
@@ -1726,41 +1831,47 @@ class LmEngine:
                 entry.update(marks)
 
     def _drain_one(self, ptick=NULL_TICK):
-        tokens_dev, snapshot, first = self._inflight.popleft()
+        tokens_dev, snapshot, first, takes = self._inflight.popleft()
         with ptick.phase("device_wait"):
             # the host-side materialization is where async dispatch pays:
             # this np.asarray blocks until the tick's device work lands
-            vals = np.asarray(tokens_dev).reshape(-1)
+            vals = np.asarray(tokens_dev)
         delivered = 0
         with ptick.phase("deliver"), self._cv:
-            for slot_idx, gen in snapshot:
+            for k, (slot_idx, gen) in enumerate(snapshot):
                 lane = self._lanes[slot_idx]
                 if not lane.active or lane.gen != gen:
                     continue  # cancelled/finished lane: stale tick token
-                # full ticks carry one token PER LANE (index by slot);
-                # single-lane prefill entries carry exactly one value
-                token = (
-                    int(vals[slot_idx]) if vals.size > 1 else int(vals[0])
-                )
-                if first is not None:
-                    # stamped before the put: the consumer may run, and
-                    # send the token off, before this thread runs again
-                    first["t_delivered"] = time.monotonic()
-                lane.queue.put(token)
-                lane.produced += 1
-                lane.tokens.append(token)  # recompute-replay history
-                delivered += 1
-                if self.registry is not None:
-                    self.registry.inc(
-                        "ctpu_lm_tokens_total",
-                        help_="Tokens streamed by the LM engine",
-                    )
-                done = (
-                    lane.produced >= lane.remaining
-                    or (self.eos_id is not None and token == self.eos_id)
-                )
-                if done:
-                    self._retire_lane_locked(lane)
+                if takes is None:
+                    # full ticks carry one token PER LANE (index by slot);
+                    # single-lane prefill entries carry exactly one value
+                    tokens = (int(vals[slot_idx]) if vals.size > 1
+                              else int(vals.reshape(-1)[0]),)
+                else:
+                    # a block's tokens, in position order, from the tick
+                    # that removed its last mask; none from any other
+                    tokens, fixed = self._programs.delivered(
+                        vals[slot_idx], takes[k])
+                    lane.fixed.extend(fixed)
+                for token in tokens:
+                    if first is not None:
+                        # stamped before the put: the consumer may run, and
+                        # send the token off, before this thread runs again
+                        first["t_delivered"] = time.monotonic()
+                    lane.queue.put(token)
+                    lane.produced += 1
+                    lane.tokens.append(token)  # recompute-replay history
+                    delivered += 1
+                    if self.registry is not None:
+                        self.registry.inc(
+                            "ctpu_lm_tokens_total",
+                            help_="Tokens streamed by the LM engine",
+                        )
+                    if (lane.produced >= lane.remaining
+                            or (self.eos_id is not None
+                                and token == self.eos_id)):
+                        self._retire_lane_locked(lane)
+                        break  # what the block holds past the budget drops
         self._account(ptick, delivered)
 
     # -- preemption / swap -------------------------------------------------
@@ -1794,7 +1905,7 @@ class LmEngine:
         # issued before this read, and nobody re-allocates them until
         # the release below
         host = self.kv.read_blocks(blocks) if use_swap else None
-        token = int(np.asarray(self._tokens)[slot])
+        token = np.asarray(self._tokens)[slot].copy()
         key = np.asarray(self._keys)[slot].copy()
         with self._cv:
             lane = self._lanes[slot]
@@ -1867,12 +1978,8 @@ class LmEngine:
                 else:
                     # recompute: the replay chain is prompt + delivered
                     # tokens, so cached generated-token blocks match too
-                    row = np.concatenate([
-                        entry.prompt[0],
-                        np.asarray(entry.tokens[:entry.produced - 1],
-                                   np.int32),
-                    ])
-                    cap = (entry.length - 1) // self.block_size
+                    row = self._written_row(entry)
+                    cap = (len(row) - 1) // self.block_size
                 matched_blocks, matched_nodes = [], []
                 if self.prefix is not None and cap:
                     matched_blocks, matched_nodes = self.prefix.match(
@@ -1899,7 +2006,7 @@ class LmEngine:
                     job = _PrefillJob(
                         handle, slot, blocks, table,
                         chunk_plan(
-                            entry.length, self.buckets,
+                            len(row), self.buckets,
                             start=len(matched_blocks) * self.block_size,
                         ),
                         None,
@@ -1952,7 +2059,7 @@ class LmEngine:
         # thread: the next decode pass dispatches strictly after this)
         self._tokens, self._keys = self._adopt(
             self._tokens, self._keys, jnp.int32(slot),
-            jnp.int32(entry.token), jnp.asarray(entry.key),
+            jnp.asarray(entry.token), jnp.asarray(entry.key),
         )
 
     def _loop(self):

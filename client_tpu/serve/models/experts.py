@@ -2,8 +2,9 @@
 deployment runs it: the layer is TOLD which of the routed experts it holds.
 
 The router keeps its published width: every token is scored over ALL the
-experts (float32 sigmoid), its ``top_k`` largest are its picks, and their
-weights are normalised over the picks.  Of the (token, pick) pairs, only
+experts (float32: a sigmoid of each logit, or where a family's configuration
+asks for it a softmax over them), its ``top_k`` largest are its picks, and
+their weights are normalised over the picks.  Of the (token, pick) pairs, only
 those that fall on a HELD expert are computed here; what the absent experts
 would add is another chip's part of the sum and is left out (on one chip
 the layer runs without its exchange, and nothing stands in for the absent
@@ -52,13 +53,17 @@ def init_params(key, d_model, d_ff, n_experts, n_held, n_shared, dtype):
     }
 
 
-def route(h, router, top_k):
+def route(h, router, top_k, score="sigmoid"):
     """(picks [T, top_k] int32 over all the experts, weights [T, top_k]
-    float32 summing to 1): float32 sigmoid scores, the ``top_k`` largest,
-    normalised over the picks.  The scores' product accumulates in float32
-    from operands as stored (bf16 products are exact in float32)."""
-    scores = jax.nn.sigmoid(jnp.matmul(
-        h, router, preferred_element_type=jnp.float32))
+    float32 summing to 1): float32 scores, the ``top_k`` largest, normalised
+    over the picks.  ``score`` is the family's: ``"sigmoid"`` of each logit,
+    or ``"softmax"`` over all the experts' (the probabilities, so that the
+    picks' weights are ``p_k`` over the sum of the picked ``p``).  The
+    logits' product accumulates in float32 from operands as stored (bf16
+    products are exact in float32)."""
+    logits = jnp.matmul(h, router, preferred_element_type=jnp.float32)
+    scores = {"sigmoid": jax.nn.sigmoid,
+              "softmax": jax.nn.softmax}[score](logits)
     top, picks = lax.top_k(scores, top_k)
     return picks.astype(jnp.int32), top / jnp.sum(top, axis=-1, keepdims=True)
 
@@ -71,12 +76,13 @@ def _swiglu(gate_up):
 ROW_TILE = 16  # the sorted buffer's rows come in whole sublane tiles (bf16)
 
 
-def routed(h, layer, held, top_k, real, scale=None):
+def routed(h, layer, held, top_k, real, scale=None, score="sigmoid"):
     """The held experts' part of the routed sum for ``h`` [T, D]: float32
     [T, D], and the counts (experts hit, rows, busiest expert's rows).
     ``real`` [T] masks rows that are no token (a tick's idle lanes, a
     chunk's padding): they route nowhere.  ``scale``, where a family has a
-    routed scaling factor, multiplies the picks' normalised weights.
+    routed scaling factor, multiplies the picks' normalised weights;
+    ``score`` is ``route``'s.
 
     The pairs are sorted by held expert (absent ones last) into a buffer of
     all T x top_k pairs; ``group_sizes`` says how many rows each held
@@ -87,7 +93,7 @@ def routed(h, layer, held, top_k, real, scale=None):
     t = h.shape[0]
     n_experts = layer["router"].shape[-1]
     n_held = len(held)
-    picks, weights = route(h, layer["router"], top_k)
+    picks, weights = route(h, layer["router"], top_k, score)
     if scale is not None:
         weights = weights * scale
     # a pick's place among the held experts; n_held: held elsewhere

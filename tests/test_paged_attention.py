@@ -568,3 +568,67 @@ def test_axk1_prefill_chunk_compiled_for_v5e_attends_in_the_kernel(
                     if heads in s and span in s and s not in weights}
     assert not {s for s in shapes if width * block in s}
     _assert_latent_pool_stays_in_place(text, cfg, n_blocks, block)
+
+
+def test_sdar_block_tick_compiled_for_v5e_reads_the_pool_in_place(
+        one_chip, monkeypatch):
+    """The fixedgen cell's tick (SDAR-30B-A3B-Chat's widths, two of its
+    seven layers, all 128 experts held, 32 lanes of a block of four, a
+    table of 160 columns over a pool of 5,120 blocks; a small vocabulary)
+    as the v5e's compiler leaves it: a call of the paged decode kernel a
+    layer, which takes the block's 4 x 8 = 32 query rows a KV head under
+    one lane length, and two of the grouped expert product; nothing has a
+    lane's gathered table, the pools are outputs that alias their donated
+    arguments, and the lanes' block states come back in their own shape."""
+    from client_tpu.serve.models import sdar
+
+    n, width, block, n_blocks = 32, 160, 16, 5120
+    cfg = sdar.SdarConfig(vocab_size=4096, n_layers=2, mask_id=4000,
+                          max_seq=width * block)
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    layers, blocks, _ = cfg.state_spec
+    pools = [shaped((n_blocks + 1,) + tuple(
+        block if d is None else d for d in blocks["k"]), cfg.jdtype)
+        for _ in range(layers)]
+    params = jax.tree_util.tree_map(
+        lambda a: shaped(a.shape, a.dtype),
+        jax.eval_shape(functools.partial(sdar.init_params, cfg=cfg),
+                       jax.random.PRNGKey(0)))
+    keys = jax.eval_shape(lambda: jax.random.split(jax.random.PRNGKey(0), n))
+    args = (params, shaped((n, 3, cfg.block_length), "int32"), pools, pools,
+            shaped((n, width), "int32"), shaped((n,), "int32"),
+            shaped((n,), "bool"), shaped((n,), "float32"),
+            shaped((n,), "int32"), shaped(keys.shape, keys.dtype))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _compiled_for_v5e(sdar.sdar_block_tick, args, cfg=cfg, n=n,
+                             block_size=block)
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) \
+        == 3 * layers
+    kv, hd = cfg.n_kv_heads, cfg.head_dim
+    rows = cfg.block_length * cfg.n_heads // kv
+    assert re.search(rf"f32\[{n},{kv},{rows},{hd}\]", text)  # the kernel's out
+    shapes = {tuple(int(d) for d in dims.split(","))
+              for dims in re.findall(r"\b(?:f32|bf16)\[([\d,]+)\]", text)}
+    gathered = {s for s in shapes
+                if s[-3:] == (kv, width * block, hd)
+                or s == (n * width, kv, block, hd)
+                or s == (n, width, kv, block, hd)}
+    assert not gathered, gathered
+    # A pool of this cell is 84 MB a layer, which fits the v5e's 128 MiB of
+    # VMEM, and the compiler's memory-space assignment takes the VALUES' pool
+    # of every layer after the first there for the scatter and the kernel's
+    # read, and copies it back (`copy-start(%pool_v_...)`, then one of the
+    # scatter's result: 0.57 ms of a tick's 16.3 on the chip, PERF.md
+    # section 5).  The keys' pools stay in HBM, and no pool is gathered or
+    # sliced.
+    pool_text = rf"bf16\[{n_blocks + 1},{kv},{block},{hd}\]"
+    copied = re.findall(
+        rf"= \(?{pool_text}[^=]* (?:copy|copy-start|slice-start)\((\S+)", text)
+    assert all("pool_v" in c or "fusion" in c for c in copied), copied[:2]
+    assert len(copied) <= 2 * (layers - 1)
+    aliases = re.search(r"input_output_alias=\{(.*?)\}, entry_comp", text)
+    assert aliases.group(1).count("-alias") == 2 * layers
