@@ -29,10 +29,22 @@ import jax.numpy as jnp
 from jax.experimental.pallas.ops.tpu.megablox import gmm
 
 # (rows, K, columns) of a tile on the chip: 128 rows fill the matrix unit's
-# height, the whole K of an expert spares the accumulation loop, and 512
-# columns make a step's slice of an expert's matrix 4 MB at K 4096, long
-# enough for its copy to hide a grid step's overhead
+# height, the whole K of an expert (up to 4096) spares the accumulation loop,
+# and 512 columns where an expert's whole width does not fit VMEM
 TILE = (128, 4096, 512)
+
+# the scoped VMEM a Pallas kernel compiles under when it names none, as gmm
+# does: 16 MiB on the v5e, whose compiler refuses blocks past it ("Scoped
+# allocation with size 16.39M and limit 16.00M")
+VMEM_BUDGET = 16 * 2**20
+
+
+def vmem_bytes(tm, tk, tn, itemsize=2):
+    """What a grid step of gmm holds in VMEM: the row block [tm, tk], the
+    expert's slice [tk, tn] and the output block [tm, tn], each twice (the
+    block in hand and the next one's copy), and the float32 accumulator
+    [tm, tn]."""
+    return 2 * itemsize * (tm * tk + tk * tn + tm * tn) + 4 * tm * tn
 
 
 def _whole(limit, size):
@@ -44,17 +56,25 @@ def _whole(limit, size):
     return min(limit, size)
 
 
-def tiling(m, k, n):
-    """The tile for an [m, k] x [k, n] group product: ``TILE`` cut to the
-    shape.  The kernel wants the rows in whole tiles; a K or a width that
-    its tile does not divide it PADS, every expert's whole matrix in every
-    call (at K 7168 under a tile of 4096, 805 MB a layer a tick), so the
-    tile is the largest of whole lanes that divides: 4096 at K 4096, 3584
-    at K 7168."""
+def tiling(m, k, n, itemsize=2):
+    """The tile for an [m, k] x [k, n] group product of ``itemsize``-byte
+    operands.  The kernel wants the rows in whole tiles; a K or a width
+    that its tile does not divide it PADS, every expert's whole matrix in
+    every call (at K 7168 under a tile of 4096, 805 MB a layer a tick), so
+    K's tile is the largest of whole lanes that divides: 4096 at K 4096,
+    3584 at K 7168.  A grid step copies one [tk, tn] slice of an expert's
+    matrix and pays a fixed cost before the next copy starts, so where
+    VMEM holds an expert's whole width the tile takes it, one step a visit
+    (the sdar family's K 2048 and 768); elsewhere 512 columns, which a
+    wider slice short of the whole did not beat on the chip (PERF.md
+    section 6, PR 41)."""
     tm = min(TILE[0], m)
     while m % tm:
         tm //= 2
-    return tm, _whole(TILE[1], k), _whole(TILE[2], n)
+    tk = _whole(TILE[1], k)
+    if vmem_bytes(tm, tk, n, itemsize) <= VMEM_BUDGET:
+        return tm, tk, n
+    return tm, tk, _whole(TILE[2], n)
 
 
 def grouped_matmul(x, w, group_sizes):
@@ -69,5 +89,6 @@ def grouped_matmul(x, w, group_sizes):
     m, k = x.shape
     return gmm(x, w, group_sizes.astype(jnp.int32),
                preferred_element_type=x.dtype,
-               tiling=tiling(m, k, w.shape[-1]),
+               tiling=tiling(m, k, w.shape[-1],
+                             max(x.dtype.itemsize, w.dtype.itemsize)),
                interpret=jax.default_backend() != "tpu")

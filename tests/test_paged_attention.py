@@ -632,3 +632,40 @@ def test_sdar_block_tick_compiled_for_v5e_reads_the_pool_in_place(
     assert len(copied) <= 2 * (layers - 1)
     aliases = re.search(r"input_output_alias=\{(.*?)\}, entry_comp", text)
     assert aliases.group(1).count("-alias") == 2 * layers
+
+
+# (K, N, groups) of each expert cell's two products: rag, longdoc, fixedgen
+GROUPED_PRODUCTS = [(4096, 8192, 16), (4096, 4096, 16), (7168, 4096, 12),
+                    (2048, 7168, 12), (2048, 1536, 128), (768, 2048, 128)]
+
+
+@pytest.mark.parametrize("k,n,groups", GROUPED_PRODUCTS)
+def test_grouped_matmul_compiled_for_v5e_at_its_tile_and_budget(
+        one_chip, monkeypatch, k, n, groups):
+    """An expert cell's grouped product at 256 rows as the v5e's compiler
+    takes it: ``grouped_matmul`` at the tile ``tiling`` picks compiles to
+    one kernel (the fixedgen cell's gate-and-up at 14.5 MiB of blocks), and
+    where the tile is narrower than the whole width, gmm at the narrowest
+    column tile that divides and whose blocks pass ``VMEM_BUDGET`` is
+    refused for VMEM: the budget is the compiler's own."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from client_tpu.ops.grouped_matmul import (
+        VMEM_BUDGET, grouped_matmul, tiling, vmem_bytes)
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+
+    args = (shaped((256, k), "bfloat16"), shaped((groups, k, n), "bfloat16"),
+            shaped((groups,), "int32"))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _compiled_for_v5e(grouped_matmul, args, donate=())
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) == 1
+    tm, tk, tn = tiling(256, k, n)
+    if tn < n:
+        over = next(t for t in range(128, n + 1, 128)
+                    if n % t == 0 and vmem_bytes(tm, tk, t) > VMEM_BUDGET)
+        with pytest.raises(Exception, match="vmem"):
+            _compiled_for_v5e(functools.partial(
+                gmm, preferred_element_type=jnp.bfloat16,
+                tiling=(tm, tk, over), interpret=False), args, donate=())
