@@ -2,8 +2,9 @@
 
 The engine knows no model: a family's programs live in the family's own
 module under ``serve/models`` (``transformer.py``, ``sambay.py``,
-``cohere2moe.py``, ``axk1.py``, ``sdar.py``) and its configuration hands
-them out (``cfg.family``), with the pools a lane owns (``cfg.state_spec``).
+``cohere2moe.py``, ``axk1.py``, ``sdar.py``, ``longcat.py``) and its
+configuration hands them out (``cfg.family``), with the pools a lane owns
+(``cfg.state_spec``).
 What the package holds:
 
 - **prompt-length bucketing** (:mod:`.policy`) — prompts pad to a small

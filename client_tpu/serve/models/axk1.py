@@ -39,9 +39,12 @@ For layer ``i`` and ``h = RMSNorm(x)``:
 
 The step is written once (``_layers``) over a cache view, as
 ``cohere2moe._layers`` is: ``_DecodeView`` absorbed at (n, 1),
-``_PrefillView`` expanded at (1, C).  The programs ``axk1_decode_tick`` and
-``axk1_prefill_chunk`` are jitted under those names so that a device trace
-tells them apart, and return the expert layers' counts (``COUNTERS``).
+``_PrefillView`` expanded at (1, C).  The attention sublayer (``mla``) is
+one function that the ``longcat_flash`` family calls too, with the
+configuration's LoRA scales (``MlaShape``: 1 here) and rotary settings.  The
+programs ``axk1_decode_tick`` and ``axk1_prefill_chunk`` are jitted under
+those names so that a device trace tells them apart, and return the expert
+layers' counts (``COUNTERS``).
 """
 
 import dataclasses
@@ -68,8 +71,40 @@ from client_tpu.serve.prof import annotation
 LANE_TILE = 128  # the cache row is padded to whole tiles of the minor axis
 
 
+class MlaShape:
+    """What ``mla`` and the cache views read of a configuration beyond its
+    widths (``n_heads``, ``q_lora_rank``, ``kv_lora_rank``, ``nope_dim``,
+    ``rope_dim``, ``v_dim``, ``norm_eps``, the rotary's ``rope_theta`` and
+    ``rope_factor``; YaRN's other fields where ``rope_factor`` is over 1):
+    the stored row, the softmax's scale, and the LoRA gains, by which the
+    query's and the latent's normed outputs are multiplied in float32 before
+    the cast (1: nothing is multiplied)."""
+
+    q_gain = 1.0
+    kv_gain = 1.0
+
+    @property
+    def row_width(self):
+        """The cache row as stored: latent, rotary key, zeros up to whole
+        tiles."""
+        used = self.kv_lora_rank + self.rope_dim
+        return -(-used // LANE_TILE) * LANE_TILE
+
+    @property
+    def value_width(self):
+        """The row's leading columns that are values: the latent, in whole
+        tiles (the columns past it meet zero rows of ``w_uv``)."""
+        return -(-self.kv_lora_rank // LANE_TILE) * LANE_TILE
+
+    @property
+    def softmax_scale(self):
+        m = 0.1 * self.mscale_all_dim * math.log(self.rope_factor) + 1.0 \
+            if self.rope_factor > 1 else 1.0
+        return (self.nope_dim + self.rope_dim) ** -0.5 * m * m
+
+
 @dataclasses.dataclass(frozen=True)
-class AxK1Config:
+class AxK1Config(MlaShape):
     vocab_size: int = 20480          # the rows of embedding and head held
     d_model: int = 7168
     n_layers: int = 5
@@ -112,25 +147,6 @@ class AxK1Config:
     @property
     def jdtype(self):
         return jnp.dtype(self.dtype)
-
-    @property
-    def row_width(self):
-        """The cache row as stored: latent, rotary key, zeros up to whole
-        tiles."""
-        used = self.kv_lora_rank + self.rope_dim
-        return -(-used // LANE_TILE) * LANE_TILE
-
-    @property
-    def value_width(self):
-        """The row's leading columns that are values: the latent, in whole
-        tiles (the columns past it meet zero rows of ``w_uv``)."""
-        return -(-self.kv_lora_rank // LANE_TILE) * LANE_TILE
-
-    @property
-    def softmax_scale(self):
-        m = 0.1 * self.mscale_all_dim * math.log(self.rope_factor) + 1.0 \
-            if self.rope_factor > 1 else 1.0
-        return (self.nope_dim + self.rope_dim) ** -0.5 * m * m
 
     @property
     def state_spec(self):
@@ -246,12 +262,16 @@ def lm_flops_per_token(cfg, context=0):
 
 # -- the layer's parts ------------------------------------------------------------
 
-def _rms_norm(x, scale, cfg):
+def _rms_norm(x, scale, cfg, gain=1.0):
     """RMSNorm of ``x`` with float32 statistics, in the activations' type:
-    what the matrix products read."""
+    what the matrix products read.  A ``gain`` other than 1 multiplies the
+    float32 normed values before the cast."""
     x32 = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-    return (x32 * lax.rsqrt(var + cfg.norm_eps)).astype(cfg.jdtype) * scale
+    normed = x32 * lax.rsqrt(var + cfg.norm_eps)
+    if gain != 1.0:
+        normed = normed * gain
+    return normed.astype(cfg.jdtype) * scale
 
 
 def _rope(x, pos, cfg):
@@ -283,6 +303,34 @@ def _dense_ffn(h, layer):
 
 # -- the step, over a cache view -------------------------------------------------
 
+def mla(h, layer, pool, cfg, view):
+    """Multi-head latent attention of the normed ``h`` [B,T,D] over one
+    paged layer of latent rows: the sublayer's output (float32 [B,T,D],
+    before the residual add) and the pool after this step's rows are
+    written.  ``layer`` holds ``w_qa``, ``ln_q``, ``w_qb``, ``w_kva``,
+    ``ln_kv``, ``w_uk``, ``w_uv``, ``w_o``; ``cfg.q_gain`` and
+    ``cfg.kv_gain`` multiply the query's and the latent's norms (the
+    latent's gain is in the stored row, so both forms of attention read
+    it)."""
+    b, t = h.shape[:2]
+    heads, nope = cfg.n_heads, cfg.nope_dim
+    c, pad = cfg.kv_lora_rank, cfg.row_width - cfg.kv_lora_rank - cfg.rope_dim
+    c_q = _rms_norm(h @ layer["w_qa"], layer["ln_q"], cfg, cfg.q_gain)
+    q = c_q @ layer["w_qb"]
+    q_nope = q[..., :heads * nope].reshape(b, t, heads, nope)
+    q_pe = _rope(q[..., heads * nope:].reshape(b, t, heads, -1),
+                 view.pos, cfg)
+    kv = h @ layer["w_kva"]
+    c_kv = _rms_norm(kv[..., :c], layer["ln_kv"], cfg, cfg.kv_gain)
+    k_pe = _rope(kv[..., None, c:], view.pos, cfg)[:, :, 0]
+    row = jnp.concatenate(
+        [c_kv, k_pe, jnp.zeros((b, t, pad), c_kv.dtype)], axis=-1)
+    pool = view.paged_write(pool, row)
+    mixed = view.attend(q_nope, q_pe, pool, layer)
+    return jnp.matmul(mixed.astype(h.dtype), layer["w_o"],
+                      preferred_element_type=jnp.float32), pool
+
+
 def _layers(params, x, pool, cfg, view):
     """Every layer over the embedded ``x`` [B,T,D]: the final norm's output,
     the pool after, and the expert layers' counts summed.  The residual
@@ -290,26 +338,12 @@ def _layers(params, x, pool, cfg, view):
     type."""
     pool = list(pool)
     b, t = x.shape[:2]
-    heads, nope = cfg.n_heads, cfg.nope_dim
-    c, pad = cfg.kv_lora_rank, cfg.row_width - cfg.kv_lora_rank - cfg.rope_dim
     x = x.astype(jnp.float32)
     counts = jnp.zeros((3,), jnp.int32)
     for i, layer in enumerate(params["layers"]):
-        h = _rms_norm(x, layer["ln_attn"], cfg)
-        c_q = _rms_norm(h @ layer["w_qa"], layer["ln_q"], cfg)
-        q = c_q @ layer["w_qb"]
-        q_nope = q[..., :heads * nope].reshape(b, t, heads, nope)
-        q_pe = _rope(q[..., heads * nope:].reshape(b, t, heads, -1),
-                     view.pos, cfg)
-        kv = h @ layer["w_kva"]
-        c_kv = _rms_norm(kv[..., :c], layer["ln_kv"], cfg)
-        k_pe = _rope(kv[..., None, c:], view.pos, cfg)[:, :, 0]
-        row = jnp.concatenate(
-            [c_kv, k_pe, jnp.zeros((b, t, pad), c_kv.dtype)], axis=-1)
-        pool[i] = view.paged_write(pool[i], row)
-        mixed = view.attend(q_nope, q_pe, pool[i], layer)
-        x = x + jnp.matmul(mixed.astype(h.dtype), layer["w_o"],
-                           preferred_element_type=jnp.float32)
+        out, pool[i] = mla(_rms_norm(x, layer["ln_attn"], cfg), layer,
+                           pool[i], cfg, view)
+        x = x + out
         h2 = _rms_norm(x, layer["ln_ffn"], cfg)
         if i < cfg.first_dense:
             out = _dense_ffn(h2, layer["ffn"])
@@ -400,24 +434,26 @@ class _PrefillView:
                              layer["w_uk"], layer["w_uv"])[None]
 
 
-def decode_step(params, tokens, pool, tables, lens, live, cfg, block_size):
+def decode_step(params, tokens, pool, tables, lens, live, cfg, block_size,
+                layers=_layers):
     """One token a lane for the lanes of ``tokens`` [n], each at position
-    ``lens`` [n]: float32 logits [n,V], the pool after, the counts."""
+    ``lens`` [n]: float32 logits [n,V], the pool after, the counts.
+    ``layers`` is the family's layer stack over a cache view."""
     view = _DecodeView(cfg, tables, lens, live, block_size)
     x = jnp.take(params["embed"], tokens, axis=0)[:, None, :]
-    x, pool, counts = _layers(params, x, pool, cfg, view)
+    x, pool, counts = layers(params, x, pool, cfg, view)
     return _head(params, x[:, 0]), pool, counts
 
 
 def prefill_step(params, chunk, pool, table, start, prompt_len, cfg,
-                 block_size):
+                 block_size, layers=_layers):
     """``chunk`` [1,C] of a prompt at positions ``start`` ..: float32 logits
     [V] at the prompt's last position (meaningful in the chunk that holds
     it), the pool after, the counts."""
     c = chunk.shape[1]
     view = _PrefillView(cfg, c, table, start, prompt_len, block_size)
     x = jnp.take(params["embed"], chunk, axis=0)
-    x, pool, counts = _layers(params, x, pool, cfg, view)
+    x, pool, counts = layers(params, x, pool, cfg, view)
     last = jnp.clip(prompt_len - 1 - start, 0, c - 1)
     xsel = lax.dynamic_index_in_dim(x[0], last, 0, keepdims=True)
     return _head(params, xsel)[0], pool, counts
@@ -454,7 +490,9 @@ class AxK1Programs:
     empty); there is no verify program yet, which ``no_verify`` says.  Its
     programs return the expert layers' counts beside their tokens, named by
     ``counters``; ``tick_fields`` adds what the host can count of the cache
-    rows a dispatch may see and does read."""
+    rows a dispatch may see and does read, over every paged layer.  Another
+    latent family (``longcat.LongcatPrograms``) names its own programs,
+    annotations, counters and parameters and keeps the rest."""
 
     recurrent = ""
     no_verify = (
@@ -467,6 +505,11 @@ class AxK1Programs:
     init_params = staticmethod(init_params)
     generate = None         # no contiguous cache: the engine alone serves it
     quantize_params = None  # no int8 weights
+    # (chunk, tick): the programs, jitted under their own names, and the
+    # annotations their dispatches run under; FLOPs a token of the family
+    _programs = (axk1_prefill_chunk, axk1_decode_tick)
+    _annotations = ("lm.axk1_prefill_chunk", "lm.axk1_decode_tick")
+    _token_flops = staticmethod(lm_flops_per_token)
 
     def __init__(self, cfg, block_size):
         self.cfg, self.block_size = cfg, block_size
@@ -478,14 +521,16 @@ class AxK1Programs:
                 "rows: the decode tick reads the latent blocks in place")
         # CPU (the test platform) has no donation support
         self.donate = (2,) if jax.default_backend() != "cpu" else ()
-        self.flops_per_token = lm_flops_per_token(cfg)
+        self.flops_per_token = self._token_flops(cfg)
+        self._layers = cfg.state_spec[0]         # paged latent layers
         self._tick_span = step_blocks(latent=True) * block_size
         self._static = dict(cfg=cfg, block_size=block_size)
+        chunk, tick = self._programs
         self.prefill_jit = jax.jit(
-            axk1_prefill_chunk, static_argnames=("cfg", "block_size"),
+            chunk, static_argnames=("cfg", "block_size"),
             donate_argnums=self.donate)
         self._tick_jit = jax.jit(
-            axk1_decode_tick, static_argnames=("cfg", "n", "block_size"),
+            tick, static_argnames=("cfg", "n", "block_size"),
             donate_argnums=self.donate)
 
     def attended_positions(self, max_pos, table_width):
@@ -504,7 +549,7 @@ class AxK1Programs:
 
     def tick_fields(self, kind, lengths, start=None, width=None, **_):
         """What the host can count for a ``tick_trace()`` entry, over the
-        entry's lanes and every layer: ``kv_positions_live``, the rows
+        entry's lanes and every paged layer: ``kv_positions_live``, the rows
         attention may see (a decode lane of length ``len``: ``len + 1``; a
         chunk: every row up to its last real one),
         ``kv_positions_read``, what the program's trip counts read, and on
@@ -515,7 +560,7 @@ class AxK1Programs:
         ``kv_steps_full``, those on its straight-line path
         (``paged_decode.tick_steps``)."""
         lengths = np.asarray(lengths, np.int64)
-        layers = self.cfg.n_layers
+        layers = self._layers
         if kind == "prefill_chunk":
             live = int(lengths[0])                     # start + real tokens
             read = layers * int(
@@ -535,7 +580,7 @@ class AxK1Programs:
 
     def prefill(self, params, kv, chunk, table, slot, start, prompt_len,
                 fresh, key, temperature, top_k):
-        with annotation("lm.axk1_prefill_chunk"):
+        with annotation(self._annotations[0]):
             tok, kv.pools["latent"], key, counts = self.prefill_jit(
                 params, chunk, kv.pools["latent"], table, start, prompt_len,
                 key, temperature, top_k, **self._static)
@@ -546,7 +591,7 @@ class AxK1Programs:
 
     def tick(self, fn, params, kv, tokens, tables, lens, live, temps, topks,
              keys):
-        with annotation("lm.axk1_decode_tick"):
+        with annotation(self._annotations[1]):
             tokens, kv.pools["latent"], keys, counts = fn(
                 params, tokens, kv.pools["latent"], tables, lens,
                 live, temps, topks, keys)

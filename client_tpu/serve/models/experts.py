@@ -20,9 +20,19 @@ its single shared expert, ``axk1``, gets that from the same code).
 S_j(h)`` with ``E(h) = W_down(silu(W_gate h) * W_up h)``; ``scale`` is a
 family's routed scaling factor, and without one nothing is multiplied.
 
+Three options serve a router of the ``longcat_flash`` kind, and without them
+nothing is traced that was not before: a selection ``bias`` over the router's
+slots, added to the scores to choose the picks while the weights stay the
+scores; ``normalize=False``, the picks' weights left as scored; and ``n_zero``
+zero-compute slots after the routed experts, whose picks return their input:
+a zero pick never enters the sorted buffer (it is masked as a pair of an
+absent expert is), and every chip adds ``scale * w * h`` for its own rows in
+float32, as it would a shared expert.
+
 The layer returns, beside its output, three int32 counts that only the
 device knows: how many held experts had a row, how many pairs fell on held
-experts, and the busiest held expert's rows.
+experts, and the busiest held expert's rows; with zero slots a fourth, the
+zero picks of real rows.
 """
 
 import jax
@@ -53,19 +63,27 @@ def init_params(key, d_model, d_ff, n_experts, n_held, n_shared, dtype):
     }
 
 
-def route(h, router, top_k, score="sigmoid"):
-    """(picks [T, top_k] int32 over all the experts, weights [T, top_k]
-    float32 summing to 1): float32 scores, the ``top_k`` largest, normalised
-    over the picks.  ``score`` is the family's: ``"sigmoid"`` of each logit,
-    or ``"softmax"`` over all the experts' (the probabilities, so that the
-    picks' weights are ``p_k`` over the sum of the picked ``p``).  The
-    logits' product accumulates in float32 from operands as stored (bf16
-    products are exact in float32)."""
+def route(h, router, top_k, score="sigmoid", bias=None, normalize=True):
+    """(picks [T, top_k] int32 over all the router's slots, weights [T,
+    top_k] float32): float32 scores, the ``top_k`` largest, normalised over
+    the picks.  ``score`` is the family's: ``"sigmoid"`` of each logit, or
+    ``"softmax"`` over all the slots' (the probabilities, so that the
+    picks' weights are ``p_k`` over the sum of the picked ``p``).  A
+    ``bias`` [slots] chooses the picks as the largest of ``scores + bias``
+    and leaves their weights the scores; ``normalize=False`` leaves the
+    weights unnormalised.  The logits' product accumulates in float32 from
+    operands as stored (bf16 products are exact in float32)."""
     logits = jnp.matmul(h, router, preferred_element_type=jnp.float32)
     scores = {"sigmoid": jax.nn.sigmoid,
               "softmax": jax.nn.softmax}[score](logits)
-    top, picks = lax.top_k(scores, top_k)
-    return picks.astype(jnp.int32), top / jnp.sum(top, axis=-1, keepdims=True)
+    if bias is None:
+        top, picks = lax.top_k(scores, top_k)
+    else:
+        _, picks = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        top = jnp.take_along_axis(scores, picks, axis=-1)
+    if normalize:
+        top = top / jnp.sum(top, axis=-1, keepdims=True)
+    return picks.astype(jnp.int32), top
 
 
 def _swiglu(gate_up):
@@ -76,13 +94,17 @@ def _swiglu(gate_up):
 ROW_TILE = 16  # the sorted buffer's rows come in whole sublane tiles (bf16)
 
 
-def routed(h, layer, held, top_k, real, scale=None, score="sigmoid"):
+def routed(h, layer, held, top_k, real, scale=None, score="sigmoid",
+           bias=None, normalize=True, n_zero=0):
     """The held experts' part of the routed sum for ``h`` [T, D]: float32
-    [T, D], and the counts (experts hit, rows, busiest expert's rows).
-    ``real`` [T] masks rows that are no token (a tick's idle lanes, a
-    chunk's padding): they route nowhere.  ``scale``, where a family has a
-    routed scaling factor, multiplies the picks' normalised weights;
-    ``score`` is ``route``'s.
+    [T, D], and the counts (experts hit, rows, busiest expert's rows; with
+    ``n_zero`` the zero picks of real rows).  ``real`` [T] masks rows that
+    are no token (a tick's idle lanes, a chunk's padding): they route
+    nowhere.  ``scale``, where a family has a routed scaling factor,
+    multiplies the picks' weights; ``score``, ``bias`` and ``normalize`` are
+    ``route``'s.  ``n_zero`` slots follow the router's routed experts
+    (``layer["router"]``'s last columns): a pick of one adds its weight
+    times the row.
 
     The pairs are sorted by held expert (absent ones last) into a buffer of
     all T x top_k pairs; ``group_sizes`` says how many rows each held
@@ -91,13 +113,14 @@ def routed(h, layer, held, top_k, real, scale=None, score="sigmoid"):
     sort, a gather, and adds them in float32 under its weights: the same
     sum a scatter-add by token would give, without its serial adds."""
     t = h.shape[0]
-    n_experts = layer["router"].shape[-1]
+    n_slots = layer["router"].shape[-1]
     n_held = len(held)
-    picks, weights = route(h, layer["router"], top_k, score)
+    picks, weights = route(h, layer["router"], top_k, score, bias, normalize)
     if scale is not None:
         weights = weights * scale
-    # a pick's place among the held experts; n_held: held elsewhere
-    local = jnp.full((n_experts,), n_held, jnp.int32).at[
+    # a pick's place among the held experts; n_held: held elsewhere, or a
+    # zero slot, which no expert computes
+    local = jnp.full((n_slots,), n_held, jnp.int32).at[
         jnp.asarray(held, jnp.int32)].set(jnp.arange(n_held, dtype=jnp.int32))
     group = jnp.where(real[:, None], local[picks], n_held).reshape(-1)
     pairs = t * top_k
@@ -115,7 +138,13 @@ def routed(h, layer, held, top_k, real, scale=None, score="sigmoid"):
                      jnp.take(out, back, axis=0).astype(jnp.float32), 0.0)
     counts = jnp.stack([jnp.sum(group_sizes > 0), jnp.sum(group_sizes),
                         jnp.max(group_sizes)]).astype(jnp.int32)
-    return jnp.sum(mine * weights[:, :, None], axis=1), counts
+    out = jnp.sum(mine * weights[:, :, None], axis=1)
+    if not n_zero:
+        return out, counts
+    zero = (picks >= n_slots - n_zero) & real[:, None]
+    w_zero = jnp.sum(jnp.where(zero, weights, 0.0), axis=1, keepdims=True)
+    return (out + w_zero * h.astype(jnp.float32),
+            jnp.concatenate([counts, jnp.sum(zero, dtype=jnp.int32)[None]]))
 
 
 def shared(h, layer, n_shared):

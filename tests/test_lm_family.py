@@ -19,7 +19,7 @@ import jax.numpy as jnp
 
 from client_tpu.ops import paged_decode
 from client_tpu.serve.lm import KvBlockPool, LmEngine
-from client_tpu.serve.models import axk1, cohere2moe, sambay
+from client_tpu.serve.models import axk1, cohere2moe, longcat, sambay
 from client_tpu.serve.models import transformer as tfm
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
@@ -45,6 +45,12 @@ LATENT = axk1.AxK1Config(
     d_dense=48, d_ff=16, n_experts=8, top_k=2, experts_held=(1, 2, 5, 6),
     n_shared=1, rope_factor=4.0, rope_original=32, beta_fast=4.0,
     max_seq=64, dtype="float32")
+# tests/test_longcat.py's double layers: two latent pools a layer, zero slots
+SHORTCUT = longcat.LongcatConfig(
+    vocab_size=97, d_model=32, n_layers=2, n_heads=4, q_lora_rank=16,
+    kv_lora_rank=24, nope_dim=8, rope_dim=8, v_dim=8, d_dense=48, d_ff=16,
+    n_experts=32, n_zero=16, top_k=6, experts_held=tuple(range(1, 32, 2)),
+    rope_theta=10000.0, max_seq=64, dtype="float32")
 
 
 # -- the paged programs against the contiguous path ---------------------------
@@ -386,6 +392,8 @@ def _lower(fn, *args, **static):
     (MOE, "chunk", "cohere2moe_prefill_roofline_pct"),
     (LATENT, "tick", "axk1_decode_roofline_pct"),
     (LATENT, "chunk", "axk1_prefill_roofline_pct"),
+    (SHORTCUT, "tick", "longcat_decode_roofline_pct"),
+    (SHORTCUT, "chunk", "longcat_prefill_roofline_pct"),
 ], ids=lambda v: v if isinstance(v, str) else type(v).__name__)
 def test_family_program_lowers_under_the_name_its_metric_reads(
         cfg, program, metric):

@@ -634,9 +634,106 @@ def test_sdar_block_tick_compiled_for_v5e_reads_the_pool_in_place(
     assert aliases.group(1).count("-alias") == 2 * layers
 
 
-# (K, N, groups) of each expert cell's two products: rag, longdoc, fixedgen
+def _longcat_cell_args(one_chip, width=544, block=16, n_blocks=17408):
+    """The dialog cell's shapes (LongCat-Flash's widths, ONE of its four
+    double layers, 16 of 512 experts held beside 256 zero slots, a table of
+    544 columns over a pool of 17,408 blocks; a small vocabulary): (cfg,
+    params, the two latent pools of the double layer, a shaper)."""
+    from client_tpu.serve.models import longcat
+
+    cfg = longcat.LongcatConfig(vocab_size=4096, n_layers=1,
+                                max_seq=width * block)
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    layers, blocks, _ = cfg.state_spec
+    assert (layers, list(blocks)) == (2, ["latent"])
+    pools = [shaped((n_blocks + 1,) + tuple(
+        block if d is None else d for d in blocks["latent"]), cfg.jdtype)
+        for _ in range(layers)]
+    params = jax.tree_util.tree_map(
+        lambda a: shaped(a.shape, a.dtype),
+        jax.eval_shape(functools.partial(longcat.init_params, cfg=cfg),
+                       jax.random.PRNGKey(0)))
+    return cfg, params, pools, shaped
+
+
+def _assert_both_latent_pools_stay_in_place(text, cfg, n_blocks, block):
+    pool_text = rf"bf16\[{n_blocks + 1},1,{block},{cfg.row_width}\]"
+    copied = re.findall(
+        rf"= \(?{pool_text}[^=]* (?:copy|copy-start|slice-start)\(.*", text)
+    assert not copied, copied[:2]
+    aliases = re.search(r"input_output_alias=\{(.*?)\}, entry_comp", text)
+    assert aliases.group(1).count("-alias") == cfg.state_spec[0] == 2
+
+
+def test_longcat_decode_tick_compiled_for_v5e_reads_both_latent_pools(
+        one_chip, monkeypatch):
+    """The dialog cell's tick (32 lanes) as the v5e's compiler leaves it: a
+    call of the paged decode kernel for each of a double layer's two
+    attention sublayers, each over its own pool read in place, and the two
+    grouped expert products of its shortcut branch; nothing with a lane's
+    gathered table."""
+    from client_tpu.serve.models import longcat
+
+    n, width, block, n_blocks = 32, 544, 16, 17408
+    cfg, params, pools, shaped = _longcat_cell_args(one_chip)
+    keys = jax.eval_shape(lambda: jax.random.split(jax.random.PRNGKey(0), n))
+    args = (params, shaped((n,), "int32"), pools,
+            shaped((n, width), "int32"), shaped((n,), "int32"),
+            shaped((n,), "bool"), shaped((n,), "float32"),
+            shaped((n,), "int32"), shaped(keys.shape, keys.dtype))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _compiled_for_v5e(longcat.longcat_decode_tick, args, donate=(2,),
+                             cfg=cfg, n=n, block_size=block)
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) \
+        == 2 + 2
+    shapes = {tuple(int(d) for d in dims.split(","))
+              for dims in re.findall(r"\b(?:f32|bf16)\[([\d,]+)\]", text)}
+    gathered = {s for s in shapes
+                if width * block in s or s[:2] == (n, width)
+                or s[:1] == (n * width,)}
+    assert not gathered, gathered
+    _assert_both_latent_pools_stay_in_place(text, cfg, n_blocks, block)
+
+
+def test_longcat_prefill_chunk_compiled_for_v5e_attends_in_the_kernel(
+        one_chip, monkeypatch):
+    """The dialog cell's widest chunk (512 rows): a call of
+    ``ops/latent_prefill`` for each attention sublayer and the shortcut
+    branch's two grouped products; no scores of a group of the table
+    outside the kernel, and both pools stay in place."""
+    from client_tpu.ops import latent_prefill
+    from client_tpu.serve.models import longcat
+
+    chunk, width, block, n_blocks = 512, 544, 16, 17408
+    cfg, params, pools, shaped = _longcat_cell_args(one_chip)
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    args = (params, shaped((1, chunk), "int32"), pools,
+            shaped((width,), "int32"), shaped((), "int32"),
+            shaped((), "int32"), shaped(key.shape, key.dtype),
+            shaped((), "float32"), shaped((), "int32"))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _compiled_for_v5e(longcat.longcat_prefill_chunk, args,
+                             donate=(2,), cfg=cfg, block_size=block)
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) \
+        == 2 + 2
+    span = latent_prefill.group_span(block)
+    typed = {(kind, tuple(int(d) for d in dims.split(",")))
+             for kind, dims in re.findall(r"\b(f32|bf16)\[([\d,]+)\]", text)}
+    assert not {s for kind, s in typed if kind == "f32"
+                and sorted(s) == sorted((cfg.n_heads, chunk, span))}
+    assert not {s for _, s in typed if width * block in s}
+    _assert_both_latent_pools_stay_in_place(text, cfg, n_blocks, block)
+
+
+# (K, N, groups) of each expert cell's two products: rag, longdoc,
+# fixedgen, dialog
 GROUPED_PRODUCTS = [(4096, 8192, 16), (4096, 4096, 16), (7168, 4096, 12),
-                    (2048, 7168, 12), (2048, 1536, 128), (768, 2048, 128)]
+                    (2048, 7168, 12), (2048, 1536, 128), (768, 2048, 128),
+                    (6144, 4096, 16), (2048, 6144, 16)]
 
 
 @pytest.mark.parametrize("k,n,groups", GROUPED_PRODUCTS)
