@@ -505,6 +505,7 @@ class AxK1Programs:
     init_params = staticmethod(init_params)
     generate = None         # no contiguous cache: the engine alone serves it
     quantize_params = None  # no int8 weights
+    serving_params = None   # served as published
     # (chunk, tick): the programs, jitted under their own names, and the
     # annotations their dispatches run under; FLOPs a token of the family
     _programs = (axk1_prefill_chunk, axk1_decode_tick)

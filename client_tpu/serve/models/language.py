@@ -120,6 +120,9 @@ class _LmRunner:
             # int8 weight-only serving (client_tpu.ops.quant): ~2x weight
             # capacity per chip, same decode programs via the _mm dispatch
             self.params = family.quantize_params(self.params)
+        if family.serving_params is not None:
+            # once, here: the layout the family's compiled programs read
+            self.params = family.serving_params(self.params)
 
     def check_prompt(self, n_prompt_tokens):
         """Reject prompts the KV cache cannot hold with a clear 400 instead

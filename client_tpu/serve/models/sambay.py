@@ -672,6 +672,7 @@ class SambaYPrograms:
     init_params = staticmethod(init_params)
     generate = None         # no contiguous cache: the engine alone serves it
     quantize_params = None  # no int8 weights
+    serving_params = None   # served as published
 
     def __init__(self, cfg, block_size):
         self.cfg, self.block_size = cfg, block_size

@@ -36,7 +36,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from client_tpu.ops.paged_decode import (
     STEP_BLOCKS, paged_decode_attention, reads_in_place, steps_read,
     tick_steps)
-from client_tpu.ops.quant import matmul as _mm
+from client_tpu.ops.quant import is_quantized, matmul as _mm
 from client_tpu.ops.sampling import accept_lane, select_token
 from client_tpu.parallel.ring_attention import (
     plain_attention,
@@ -168,15 +168,32 @@ def _repeat_kv(x, n_rep, axis=2):
     return jnp.repeat(x, n_rep, axis=axis)
 
 
+def _qkv(h, attn, cfg):
+    """The attention's q, k and v of ``h`` [B,T,D], each [B,T,heads,hd].
+    A layer holds each projection in one of two forms, and this reads
+    whichever it finds: under ``wq_t`` (``wk_t``, ``wv_t``) the serving
+    layout of ``serving_params``, [out, in], contracted on its dimension 1
+    as the compiled product reads it; under ``wq`` the published [in, out]
+    or an int8 pair (``ops.quant``)."""
+    b, t = h.shape[:2]
+
+    def project(name, heads):
+        w = attn.get(name + "_t")
+        y = (_mm(h, attn[name]) if w is None else
+             lax.dot_general(h, w, (((h.ndim - 1,), (1,)), ((), ()))))
+        return y.reshape(b, t, heads, cfg.head_dim)
+
+    return (project("wq", cfg.n_heads), project("wk", cfg.n_kv_heads),
+            project("wv", cfg.n_kv_heads))
+
+
 def _attention_block(layer, x, cfg, positions, mesh, attn_impl):
     """Full-sequence causal self-attention sublayer; returns (x, (k, v)) so
     prefill can capture the per-layer KV blocks for the cache."""
     b, t, _ = x.shape
     hd = cfg.head_dim
     h = _rms_norm(x, layer["ln_attn"])
-    q = _mm(h, layer["attn"]["wq"]).reshape(b, t, cfg.n_heads, hd)
-    k = _mm(h, layer["attn"]["wk"]).reshape(b, t, cfg.n_kv_heads, hd)
-    v = _mm(h, layer["attn"]["wv"]).reshape(b, t, cfg.n_kv_heads, hd)
+    q, k, v = _qkv(h, layer["attn"], cfg)
     q = _rope(q, positions, cfg.rope_theta)
     k = _rope(k, positions, cfg.rope_theta)
     n_rep = cfg.n_heads // cfg.n_kv_heads
@@ -276,8 +293,6 @@ def forward(params, tokens, cfg, mesh=None, attn_impl="plain",
     """
     b, t = tokens.shape
     if mesh is not None:
-        from client_tpu.ops.quant import is_quantized
-
         if is_quantized(params["lm_head"]):
             # the int8 pallas_call has no partitioning rule; GSPMD would
             # silently gather sharded activations into it (same hazard the
@@ -349,9 +364,7 @@ def decode_step(params, token, cfg, cache):
     for i, layer in enumerate(params["layers"]):
         hd = cfg.head_dim
         h = _rms_norm(x, layer["ln_attn"])
-        q = _mm(h, layer["attn"]["wq"]).reshape(b, 1, cfg.n_heads, hd)
-        k = _mm(h, layer["attn"]["wk"]).reshape(b, 1, cfg.n_kv_heads, hd)
-        v = _mm(h, layer["attn"]["wv"]).reshape(b, 1, cfg.n_kv_heads, hd)
+        q, k, v = _qkv(h, layer["attn"], cfg)
         q = _rope(q, pos[:, None], cfg.rope_theta)
         k = _rope(k, pos[:, None], cfg.rope_theta)
         # write this step's k/v at position `pos` (same for all batch rows in
@@ -579,9 +592,7 @@ def paged_layers(params, x, pool_k, pool_v, tables, pos, write, cfg,
     in_place = lengths is not None and reads_in_place(pool_k[0])
     for i, layer in enumerate(params["layers"]):
         h = _rms_norm(x, layer["ln_attn"])
-        q = _mm(h, layer["attn"]["wq"]).reshape(b, t, cfg.n_heads, hd)
-        k = _mm(h, layer["attn"]["wk"]).reshape(b, t, cfg.n_kv_heads, hd)
-        v = _mm(h, layer["attn"]["wv"]).reshape(b, t, cfg.n_kv_heads, hd)
+        q, k, v = _qkv(h, layer["attn"], cfg)
         q = _rope(q, pos, cfg.rope_theta)
         k = _rope(k, pos, cfg.rope_theta)
         rows = (b * t, cfg.n_kv_heads, hd)
@@ -804,7 +815,11 @@ def quantize_params(params):
 
     def q_layer(layer):
         out = {
-            "attn": {k: quantize_int8(w) for k, w in layer["attn"].items()},
+            # a projection in the serving layout (``serving_params``) is
+            # quantized as published: the kernel reads [in, out]
+            "attn": {k.removesuffix("_t"): quantize_int8(
+                         w.T if k.endswith("_t") else w)
+                     for k, w in layer["attn"].items()},
             "ln_attn": layer["ln_attn"],
             "ln_mlp": layer["ln_mlp"],
         }
@@ -822,6 +837,30 @@ def quantize_params(params):
         "ln_f": params["ln_f"],
         "lm_head": quantize_int8(params["lm_head"]),
     }
+
+
+def serving_params(params):
+    """The serving layout of the decoder's params, made once where params
+    enter serving (``language._LmRunner``): each layer's ``wq``, ``wk`` and
+    ``wv`` held as [out, in] under ``wq_t``, ``wk_t`` and ``wv_t``.  The
+    compiled products read a projection with its contracting dimension
+    minor; handed the published [in, out], a tick or a chunk copies all
+    three of every layer into that layout on each call.  An int8 pair
+    stays as it is: its kernel reads [in, out].  ``_qkv`` reads either
+    form, so the serial path and training (which keeps the published tree)
+    are unchanged.
+
+    Takes the params over: a layer is replaced in the params' own list as
+    its copy is made, so the published projections are let go a layer at
+    a time and not all of them at the end."""
+    layers = params.get("layers", [])  # a runner may be given none
+    for i, layer in enumerate(layers):
+        attn = dict(layer["attn"])
+        for name in ("wq", "wk", "wv"):
+            if name in attn and not is_quantized(attn[name]):
+                attn[name + "_t"] = attn.pop(name).T
+        layers[i] = {**layer, "attn": attn}
+    return params
 
 
 def stack_pipeline_params(params, n_stages):
@@ -972,8 +1011,9 @@ class DecoderPrograms:
     ``live`` tells the tick which lanes read and write.
 
     On the class, what ``_LmRunner`` asks before any program exists:
-    ``init_params``, and ``generate`` / ``quantize_params``, each None in a
-    family that has no contiguous serial path or no int8 weights."""
+    ``init_params``, and ``generate`` / ``quantize_params`` /
+    ``serving_params``, each None in a family that has no contiguous serial
+    path, no int8 weights or serves its weights as published."""
 
     # why a lane's cache cannot be rebuilt from its blocks ("" = it can):
     # the engine switches off what assumes it can, and a family that sets
@@ -982,6 +1022,7 @@ class DecoderPrograms:
     init_params = staticmethod(init_params)
     generate = staticmethod(generate)
     quantize_params = staticmethod(quantize_params)
+    serving_params = staticmethod(serving_params)
 
     def __init__(self, cfg, block_size):
         self.cfg, self.block_size = cfg, block_size

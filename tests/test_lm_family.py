@@ -484,6 +484,7 @@ def test_the_families_answer_the_same_questions():
     for cfg in (HYBRID, MOE, LATENT):
         assert cfg.family.generate is None
         assert cfg.family.quantize_params is None
+        assert cfg.family.serving_params is None
 
 
 @pytest.mark.parametrize("cfg, reason", [

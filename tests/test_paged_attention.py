@@ -167,8 +167,9 @@ def test_paged_attention_loops_only_where_the_table_has_widths(width, group):
 def _decode_tick_args(cfg, n, table_width, block_size, n_blocks):
     """Shapes of one ``paged_decode_tick`` call (no arrays: nothing runs)."""
     sds = jax.ShapeDtypeStruct
-    params = jax.eval_shape(
-        lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
+    # as the runner hands them to the engine: in the serving layout
+    params = jax.eval_shape(lambda: tfm.serving_params(
+        tfm.init_params(jax.random.PRNGKey(0), cfg)))
     pool = [sds((n_blocks + 1, cfg.n_kv_heads, block_size, cfg.head_dim),
                 cfg.jdtype) for _ in range(cfg.n_layers)]
     return (params, sds((n,), jnp.int32), pool, pool,
@@ -286,6 +287,22 @@ def _assert_pools_stay_in_place(text, cfg, block, n_blocks=2048):
     assert aliases.group(1).count("-alias") == 2 * cfg.n_layers
 
 
+def _assert_projections_stay_in_place(text, cfg):
+    """No attention projection of any layer (the parameters, or a bitcast
+    view of one) is what a ``copy``, ``copy-start`` or ``transpose``
+    takes: the products read the serving layout as it lies.  Handed the
+    published [in, out], the chat cell's call copied wq, wk and wv of
+    every layer into [out, in]."""
+    weights = re.findall(
+        r"(%params__layers___\d+___attn____w\w+?__(?:\.\d+)?) = ", text)
+    assert len(weights) == 4 * cfg.n_layers, weights[:4]
+    views = set(weights) | set(re.findall(
+        rf"(%[\w.\-]+) = \S+ bitcast\((?:{'|'.join(weights)})\)", text))
+    taken = set(re.findall(
+        r" (?:copy|copy-start|transpose)\((%[\w.\-]+)[,)]", text))
+    assert not taken & views, sorted(taken & views)[:3]
+
+
 @pytest.mark.parametrize("n_layers", [1, 16])
 def test_decode_tick_compiled_for_v5e_reads_the_pool_in_place(
         one_chip, monkeypatch, n_layers):
@@ -317,6 +334,7 @@ def test_decode_tick_compiled_for_v5e_reads_the_pool_in_place(
         {kv, width * block, hd}, {kv, width, block, hd})}
     assert not gathered, gathered
     _assert_pools_stay_in_place(text, cfg, block)
+    _assert_projections_stay_in_place(text, cfg)
 
 
 @pytest.mark.parametrize("chunk", [128, 512])
@@ -343,6 +361,7 @@ def test_prefill_chunk_compiled_for_v5e_writes_whole_blocks(one_chip, chunk):
     assert re.search(blocks, text)
     assert len(re.findall(r" while\(", text)) == 1
     _assert_pools_stay_in_place(text, cfg, block)
+    _assert_projections_stay_in_place(text, cfg)
 
 
 def test_sambay_decode_tick_compiled_for_v5e_reads_the_pool_in_place(
