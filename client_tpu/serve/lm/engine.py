@@ -14,6 +14,18 @@ now the stall per pass is bounded by one fixed-width chunk whose shape
 comes from a small geometric bucket set (``policy.chunk_plan``), so the
 compile set is bounded too.
 
+The host runs ahead of the device: a pass dispatches, then reads back
+the oldest dispatched results only as far as the device time still
+queued behind them covers the host's own next pass
+(``_drain_ahead``).  Each dispatched tick and chunk is priced at the
+running median of its kind's and width's device times, and the cover
+is the host's recent pass time (everything but its waits on the
+device) times a margin.  A request admitted now therefore starts its
+first chunk behind about that much device work, not behind a fixed
+count of ticks.  ``readback_depth`` caps the results in flight (0:
+every pass reads back all it dispatched); until a kind's device times
+have landed, the cap alone decides.
+
 Static shapes everywhere (TPU-first):
 
 - decode ticks run at one of a few precompiled lane counts
@@ -56,6 +68,7 @@ content whose producing dispatch already ordered before the adopter's.
 
 import functools
 import queue
+import statistics
 import threading
 import time
 from collections import OrderedDict, deque
@@ -83,6 +96,22 @@ from client_tpu.serve.prof import NULL_PHASE, NULL_TICK, PhaseProfiler
 # sentinel object closing a stream's token queue
 _CLOSE = object()
 
+# How far the host dispatches ahead (``_drain_ahead``).  A tick or chunk
+# is priced at the median of the last AHEAD_PRICE_OVER device times of
+# its kind and width, once AHEAD_PRICE_NEEDS of them have landed: a
+# shape's first device time can hold its compile.
+AHEAD_PRICE_OVER, AHEAD_PRICE_NEEDS = 32, 3
+# The host's time for a pass is taken over the last AHEAD_COVER_OVER
+# passes that dispatched work, once AHEAD_COVER_NEEDS have, at the
+# quantile AHEAD_COVER_AT: a chunk's pass, which comes every few passes,
+# sets it, and a few interpreter pauses in the window do not (a pause
+# of 60-140 ms costs the device the time the queue does not cover,
+# where eight ticks in flight hid it).
+AHEAD_COVER_OVER, AHEAD_COVER_NEEDS, AHEAD_COVER_AT = 64, 8, 0.9
+# The queue kept is that time AHEAD_MARGIN times over, for the passes
+# slower than the quantile.
+AHEAD_MARGIN = 2.0
+
 # placed-marker for a handle cancelled while its prefill job was in
 # flight (chunks dispatch outside _cv); the job step sees it, frees the
 # reservation and closes the queue
@@ -96,7 +125,21 @@ _LANE_HELP = {
         "read (of max_seq: the table width its longest lane reached, or "
         "that lane's own read where a tick reads each lane to its length)"
     ),
+    "ctpu_lm_ahead_drains_total": (
+        "Scheduler passes whose read-back went below readback_depth "
+        "because the device time queued ahead covered the host"
+    ),
+    "ctpu_lm_ahead_seconds": (
+        "Priced device time dispatched and not yet read back, after the "
+        "last pass's read-back"
+    ),
 }
+
+
+def _price_key(entry):
+    """What a tick_trace() entry's device time is priced by: its kind and
+    a chunk's width or a tick's lane count."""
+    return entry["kind"], entry.get("width", entry["n_lanes"])
 
 
 def _adopt(tokens, keys, slot, tok, key):
@@ -237,6 +280,12 @@ class LmEngine:
     ids and finally :data:`CLOSE`.  ``cancel(handle)`` releases a stream
     early.  Device state (KV pool, lane arrays, scheduler thread)
     allocates lazily on the first submit so an idle engine pins no HBM.
+
+    ``readback_depth`` is the most dispatched results the scheduler
+    keeps in flight, not the depth it runs at: it reads back further
+    while the priced device time queued behind the oldest result still
+    covers its own next pass (module docstring), and 0 reads back
+    everything every pass (serial).
     """
 
     CLOSE = _CLOSE
@@ -294,8 +343,22 @@ class LmEngine:
         self._tick_log = deque(maxlen=int(tick_log_len))
         self._pass_log = deque(maxlen=int(pass_log_len))  # pass_trace()
         # dispatched, not yet read back: (tokens, lanes' (slot, gen), the
-        # tick_trace() entry if these are a prompt's first token)
+        # tick_trace() entry if these are a prompt's first token, a block
+        # family's takes, the priced device time the readback completes:
+        # its program's and that of any chunk dispatched since the entry
+        # before it; None where a price was not known yet)
         self._inflight = deque()
+        # priced device time of chunks dispatched since the last entry of
+        # _inflight (None: one was unpriced); the scheduler thread's
+        self._loose_s = 0.0
+        # (kind, width or lanes) -> its last device times, from the
+        # completion observer's thread (_cv)
+        self._device_times = {}
+        # the host's own seconds for each recent pass that dispatched
+        # work (the scheduler thread's), and the current pass's seconds
+        # blocked on the device
+        self._host_s = deque(maxlen=AHEAD_COVER_OVER)
+        self._waited_s = 0.0
         self._thread = None  # started lazily on the first submit
         # every tick's result is watched to completion: its instant gives
         # the tick's device time (serve/_completion.py)
@@ -497,7 +560,12 @@ class LmEngine:
         in the tick), ``denoise_lanes`` and ``commit_lanes`` (lanes by the
         pass they ran), ``masked_rows`` (positions still masked, whose
         logits the pass used) and ``tokens_out`` (tokens its readback
-        delivers)."""
+        delivers).  How far the host ran ahead (``_drain_ahead``): a
+        ``prefill_chunk`` carries ``ahead_s``, the priced device time
+        dispatched ahead of it and not yet read back when it was
+        dispatched (absent while a program in that queue had no price
+        yet), and a ``decode`` entry ``inflight``, the results its pass
+        left dispatched and not read back."""
         with self._cv:
             return [dict(entry) for entry in self._tick_log]
 
@@ -1185,6 +1253,7 @@ class LmEngine:
             start + width - 1, counted=counted, spans=spans, width=width,
             tokens=tokens, start=start,
         )
+        self._price(entry)
         if self.registry is not None:
             self.registry.inc(
                 "ctpu_lm_prefill_chunks_total",
@@ -1282,7 +1351,7 @@ class LmEngine:
         if self._block > 1:
             return  # what the chunk yields is the lane's first block: no token
         tok.copy_to_host_async()
-        self._inflight.append((tok, snapshot, entry, None))
+        self._enqueue(tok, snapshot, entry, None)
 
     def _export_prefix(self, export):
         """Publish freshly prefilled full prompt blocks into the fleet
@@ -1361,14 +1430,15 @@ class LmEngine:
 
     def _decode_pass(self, ptick):
         """One batched decode tick over the active lanes (dispatch
-        outside _cv).  Returns True if a tick ran.  The pass's phases:
+        outside _cv).  Returns the tick's tick_trace() entry, or None where
+        no tick ran.  The pass's phases:
         ``build`` (the tick's arrays, on the host, under _cv),
         ``upload`` (all of them to the device), ``decode_dispatch`` (the
         program's call alone) and ``record`` (the tick's entry)."""
         with ptick.phase("build"):
             built = self._build_tick()
         if built is None:
-            return False
+            return None
         n, active, tables, lens, live, temps, topks, takes, passes = built
         t0 = time.monotonic()
         with ptick.phase("upload", held=True) as upload:
@@ -1383,14 +1453,15 @@ class LmEngine:
             )
         with ptick.phase("record"):
             self._tokens.copy_to_host_async()
-            self._inflight.append((self._tokens, tuple(active), None, takes))
-            self._log_tick(
+            entry = self._log_tick(
                 "decode", t0, tuple(i for i, _ in active), self._tokens,
                 lens[live], int(lens.max()) + self._block - 1,
                 self._tick_reads(lens[live], self._table_width),
                 counted=counted, spans=(upload, call), **passes,
             )
-        return True
+            self._price(entry)
+            self._enqueue(self._tokens, tuple(active), None, takes)
+        return entry
 
     def _build_tick(self):
         """A decode tick's arrays, on the host: ``(n, active, tables,
@@ -1590,7 +1661,10 @@ class LmEngine:
                 [lens[i] for i, _ in active], int(lens.max()) + w - 1,
                 spans=(upload, call))
         with ptick.phase("device_wait"):
+            t_wait = time.monotonic()
             vals = np.asarray(out)  # [2, n]: accepted count, correction
+            self._waited_s += time.monotonic() - t_wait
+        self._loose_s = 0.0  # all that was dispatched has completed
         self._deliver_verified(ptick, active, vals, props, counts)
         return True
 
@@ -1821,6 +1895,12 @@ class LmEngine:
             entry["t_done"] = t_done = t_done - late_s
             entry["device_s"] = device_s = max(device_s - late_s, 0.0)
             self._device_s += device_s
+            key = _price_key(entry)
+            times = self._device_times.get(key)
+            if times is None:
+                times = self._device_times[key] = deque(
+                    maxlen=AHEAD_PRICE_OVER)
+            times.append(device_s)
         # completions land one at a time, on the observer's thread: the
         # one before this is this thread's to keep
         t_prev, self._t_done_prev = self._t_done_prev, t_done
@@ -1830,12 +1910,77 @@ class LmEngine:
             with self._cv:
                 entry.update(marks)
 
+    def _price(self, entry):
+        """A tick or chunk was dispatched: price its device time, which
+        the next entry of _inflight completes, and write on a chunk's
+        entry ``ahead_s``, the priced device time queued ahead of it."""
+        queued = self._queued_s()
+        with self._cv:
+            times = self._device_times.get(_price_key(entry), ())
+            price = (statistics.median(times)
+                     if len(times) >= AHEAD_PRICE_NEEDS else None)
+            if entry["kind"] == "prefill_chunk" and queued is not None:
+                entry["ahead_s"] = queued
+        if price is None or self._loose_s is None:
+            self._loose_s = None
+        else:
+            self._loose_s += price
+
+    def _enqueue(self, tokens, snapshot, first, takes):
+        """A dispatched program's readback joins _inflight, with the
+        priced device time that its completion completes."""
+        self._inflight.append((tokens, snapshot, first, takes,
+                               self._loose_s))
+        self._loose_s = 0.0
+
+    def _queued_s(self):
+        """Priced device time dispatched and not yet read back, or None
+        while a program in it has no price yet."""
+        queued = self._loose_s
+        for *_, price in self._inflight:
+            if queued is None or price is None:
+                return None
+            queued += price
+        return queued
+
+    def _drain_ahead(self, ptick, ticked):
+        """Read back after a pass: every result where no tick ran, down
+        to ``readback_depth`` results, and then on while what would stay
+        queued behind the oldest result is more device time than the
+        host's cover: ``AHEAD_MARGIN`` times the ``AHEAD_COVER_AT``
+        quantile of its last ``AHEAD_COVER_OVER`` passes.  What is left
+        queued when the host leaves is thus more than the cover where
+        enough was dispatched, and ahead of what the next pass
+        dispatches at most the cover and one result."""
+        cap = self.depth if ticked else 0
+        while len(self._inflight) > cap:
+            self._drain_one(ptick)
+        queued = self._queued_s() if ticked else None
+        if queued is None or len(self._host_s) < AHEAD_COVER_NEEDS:
+            return  # no price or no cover yet: the cap alone
+        passes = sorted(self._host_s)
+        cover = AHEAD_MARGIN * passes[int(AHEAD_COVER_AT * (len(passes) - 1))]
+        drained = False
+        while self._inflight and queued - self._inflight[0][-1] > cover:
+            queued -= self._inflight[0][-1]
+            self._drain_one(ptick)
+            drained = True
+        if self.registry is not None:
+            if drained:
+                self.registry.inc(
+                    "ctpu_lm_ahead_drains_total",
+                    help_=_LANE_HELP["ctpu_lm_ahead_drains_total"])
+            self.registry.set("ctpu_lm_ahead_seconds", None, queued,
+                              help_=_LANE_HELP["ctpu_lm_ahead_seconds"])
+
     def _drain_one(self, ptick=NULL_TICK):
-        tokens_dev, snapshot, first, takes = self._inflight.popleft()
+        tokens_dev, snapshot, first, takes, _ = self._inflight.popleft()
         with ptick.phase("device_wait"):
             # the host-side materialization is where async dispatch pays:
             # this np.asarray blocks until the tick's device work lands
+            t_wait = time.monotonic()
             vals = np.asarray(tokens_dev)
+            self._waited_s += time.monotonic() - t_wait
         delivered = 0
         with ptick.phase("deliver"), self._cv:
             for k, (slot_idx, gen) in enumerate(snapshot):
@@ -2099,6 +2244,7 @@ class LmEngine:
     def _loop_pass(self, ptick):
         """One scheduler pass (the former _loop_inner body); returns
         False when the engine closed and the loop must stop."""
+        t_pass, self._waited_s = time.monotonic(), 0.0
         if self._preempt is not None:
             with ptick.phase("preempt"):
                 self._preempt_step()  # device copies outside _cv
@@ -2120,17 +2266,23 @@ class LmEngine:
             # / device_wait / deliver); False falls through to the plain
             # decode tick — the never-slower path
             verified = self._spec_pass(ptick)
-        ticked = verified
-        if not ticked:
-            ticked = self._decode_pass(ptick)  # ONE decode tick, outside _cv
+        tick = None
+        if not verified:
+            tick = self._decode_pass(ptick)  # ONE decode tick, outside _cv
+        ticked = verified or tick is not None
         if ticked:
             ptick.relabel("verify" if verified else "decode")
         worked = worked or ticked
         with self._cv:
             if self._closed:
                 return False
-        while len(self._inflight) > (self.depth if ticked else 0):
-            self._drain_one(ptick)
+        self._drain_ahead(ptick, ticked)
+        if tick is not None:
+            with self._cv:
+                tick["inflight"] = len(self._inflight)
+        if worked:
+            self._host_s.append(
+                time.monotonic() - t_pass - self._waited_s)
         if not worked and not self._inflight:
             with self._cv:
                 if self._closed:
